@@ -10,8 +10,9 @@ module owns the two read paths:
 - **HLO text** (:func:`parse_hlo_module`) — ``jit(f).lower(...).compile()
   .as_text()`` is stable, line-oriented HLO: one instruction per line,
   shapes spelled ``f32[64,33]{1,0}``, per-op ``metadata={...
-  source_file=... source_line=N}`` tracing each op back to the Python
-  that built it, and the module header carrying ``input_output_alias``
+  stack_frame_id=N}`` tracing each op — through the module's stack-frame
+  tables — back to the Python that built it, operands referenced by
+  name, and the module header carrying ``input_output_alias``
   (the donation ground truth) and ``entry_computation_layout``.  The
   parser extracts exactly what the auditor consumes — opcodes, result/
   operand shapes with byte sizes, source attribution, aliasing — and
@@ -144,9 +145,16 @@ _INSN_RE = re.compile(
 )
 _META_RE = re.compile(
     r'metadata=\{[^}]*?op_name="(?P<op_name>[^"]*)"'
-    r'(?:[^}]*?source_file="(?P<source_file>[^"]*)")?'
-    r"(?:[^}]*?source_line=(?P<source_line>\d+))?"
+    r"(?:[^}]*?stack_frame_id=(?P<frame>\d+))?"
 )
+_OPERAND_REF_RE = re.compile(r"%([\w.\-]+)")
+# The module-level provenance index jax 0.9.0 prints in place of per-op
+# source_file/source_line: three numbered tables, one row per line.
+_FILE_NAME_RE = re.compile(r'^(\d+) "(.*)"$')
+_FILE_LOCATION_RE = re.compile(
+    r"^(\d+) \{file_name_id=(\d+) function_name_id=\d+ line=(\d+)"
+)
+_STACK_FRAME_RE = re.compile(r"^(\d+) \{file_location_id=(\d+) ")
 _ALIAS_ENTRY_RE = re.compile(
     r"\{(?P<out>[\d,\s]*)\}:\s*\((?P<param>\d+),\s*\{(?P<pidx>[\d,\s]*)\},"
     r"\s*(?P<kind>may-alias|must-alias)\)"
@@ -207,22 +215,67 @@ def _operand_section(line: str, open_idx: int) -> str:
     return line[open_idx + 1 :]
 
 
+def parse_stack_frames(text: str) -> Dict[int, Tuple[str, int]]:
+    """``stack_frame_id`` -> (source file, line) of the frame's own
+    location — the Python that built the op.  jax 0.9.0 prints this once
+    per module as the ``FileNames`` / ``FileLocations`` / ``StackFrames``
+    tables instead of repeating ``source_file=`` on every instruction."""
+    files: Dict[int, str] = {}
+    locations: Dict[int, Tuple[int, int]] = {}
+    frames: Dict[int, int] = {}
+    table = ""
+    for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations", "StackFrames"):
+            table = line
+        elif not line:
+            table = ""
+        elif table == "FileNames":
+            m = _FILE_NAME_RE.match(line)
+            if m:
+                files[int(m.group(1))] = m.group(2)
+        elif table == "FileLocations":
+            m = _FILE_LOCATION_RE.match(line)
+            if m:
+                locations[int(m.group(1))] = (int(m.group(2)), int(m.group(3)))
+        elif table == "StackFrames":
+            m = _STACK_FRAME_RE.match(line)
+            if m:
+                frames[int(m.group(1))] = int(m.group(2))
+    out: Dict[int, Tuple[str, int]] = {}
+    for frame, loc in frames.items():
+        file_id, lineno = locations.get(loc, (0, 0))
+        out[frame] = (files.get(file_id, ""), lineno)
+    return out
+
+
 def parse_hlo_ops(text: str) -> List[HloOp]:
     """Every instruction in an HLO module dump, in order.
 
-    Operand shapes come from the operand list between the opcode's
+    Operands are the ``%name`` references between the opcode's
     parentheses (attribute text after the closing paren — ``to_apply``,
-    ``metadata``, constant literals — never contributes shapes).
+    ``metadata``, constant literals — never contributes): jax 0.9.0 prints
+    them by name only (``all-reduce(%fusion.9)``), so each reference
+    resolves to the result shapes of the instruction that defined it
+    earlier in the same computation.
     """
+    frames = parse_stack_frames(text)
     ops: List[HloOp] = []
+    defined: Dict[str, List[Shape]] = {}
     for line in text.splitlines():
         m = _INSN_RE.match(line)
         if m is None:
+            if line.rstrip().endswith("{"):
+                defined = {}  # a new computation: names are scoped to it
             continue
         opcode = m.group("opcode")
         results = parse_shapes(m.group("shape"))
         open_idx = line.index("(", m.end() - 1)
-        operands = parse_shapes(_operand_section(line, open_idx))
+        operands = [
+            sh
+            for ref in _OPERAND_REF_RE.findall(_operand_section(line, open_idx))
+            for sh in defined.get(ref, ())
+        ]
+        defined[m.group("name")] = results
         op = HloOp(
             name=m.group("name"), opcode=opcode,
             results=results, operands=operands,
@@ -230,8 +283,10 @@ def parse_hlo_ops(text: str) -> List[HloOp]:
         meta = _META_RE.search(line)
         if meta is not None:
             op.op_name = meta.group("op_name") or ""
-            op.source_file = meta.group("source_file") or ""
-            op.source_line = int(meta.group("source_line") or 0)
+            if meta.group("frame"):
+                op.source_file, op.source_line = frames.get(
+                    int(meta.group("frame")), ("", 0)
+                )
         ops.append(op)
     return ops
 

@@ -1900,17 +1900,15 @@ def build_injection(which: str) -> ProgramBundle:
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from ddlpc_tpu.utils.compat import shard_map
-
     if which == "extra-collective":
         # An extra live psum smuggled around the real update program: the
         # census gains one all-reduce the closed form does not know.
         bundle = build_program("int8_simulate/update_step")
         mesh = _mesh_for(bundle.arm)
         base = bundle.fn
-        extra = shard_map(
+        extra = jax.shard_map(
             lambda x: lax.psum(x, "data"), mesh=mesh,
-            in_specs=(P(),), out_specs=P(), check=False,
+            in_specs=(P(),), out_specs=P(), check_vma=False,
         )
 
         @jax.jit

@@ -107,14 +107,15 @@ MODULE_TIERS: Dict[str, str] = {
     # autoscaler/cache never pay a jax import is the point of the tier.
     "ddlpc_tpu.serve.autoscale": HOST,
     "ddlpc_tpu.serve.cache": HOST,
-    # utils: wire/fsio are stdlib; native needs numpy; compat IS the jax
-    # shim layer.
+    # utils: wire/fsio are stdlib; native needs numpy; compat configures
+    # jax itself.  compile_cache reaches jax lazily so the jax-free serve
+    # tier's server entry point can import it.
     "ddlpc_tpu.utils": STDLIB,
     "ddlpc_tpu.utils.wire": STDLIB,
     "ddlpc_tpu.utils.fsio": STDLIB,
     "ddlpc_tpu.utils.native": HOST,
     "ddlpc_tpu.utils.compat": JAX,
-    "ddlpc_tpu.utils.backend_probe": JAX,
+    "ddlpc_tpu.utils.compile_cache": STDLIB,
     # the accelerator tier
     "ddlpc_tpu.data": JAX,
     "ddlpc_tpu.data.datasets": JAX,

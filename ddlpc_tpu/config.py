@@ -280,10 +280,10 @@ class TrainConfig:
     # few counter updates per optimizer step (measured inside PR 6's
     # <=2% traced-step bar).
     perf_accounting: bool = True
-    # Peak FLOP/s per device for the MFU denominator; 0 = auto (device
-    # kind lookup, falling back to the v5e peak with
-    # ddlpc_peak_flops_assumed=1 so numbers stay comparable with the
-    # committed bench tables).
+    # Peak FLOP/s per device for the MFU denominator; 0 = look the device
+    # kind up in obs/flops._PEAK_BY_DEVICE_KIND (an unknown accelerator
+    # raises; only the CPU test meshes assume the v5e peak, flagged by
+    # ddlpc_peak_flops_assumed=1).
     peak_flops_per_device: float = 0.0
 
 
@@ -420,6 +420,17 @@ class CompressionConfig:
     # points; simulate transport only (the ring's flatten/concat transport
     # is inherently whole-tree and rejects bucket_mb > 0).
     bucket_mb: float = 0.0
+
+    def __post_init__(self) -> None:
+        if self.codec_backend == "pallas" and self.mode == "float16":
+            # Measured on the chip (TPU v5e, libtpu 0.0.34): the encode
+            # kernel's f32 -> f16 store fails to legalize in Mosaic
+            # ('tpu.pack_subelements'); the int8/int16 wires compile.
+            raise ValueError(
+                "codec_backend='pallas' cannot run mode='float16': Mosaic "
+                "on TPU v5e refuses the kernel's float16 wire store — use "
+                "codec_backend='xla' for the fp16 codec, or mode='int8'"
+            )
 
 
 @dataclass(frozen=True)

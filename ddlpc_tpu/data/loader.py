@@ -68,8 +68,8 @@ def _warn_native_fallback() -> None:
     if not _warned_native_fallback:
         _warned_native_fallback = True
         warnings.warn(
-            "native batch kernel unavailable (csrc/libdwbatch.so missing and "
-            "not buildable — is g++ installed?); ShardedLoader falls back to "
+            "native batch kernel unavailable (`make -C csrc libdwbatch.so` "
+            "failed — is g++ installed?); ShardedLoader falls back to "
             "the single-threaded numpy gather path (byte-identical, slower). "
             "Run `make -C csrc batch` to build it, or set "
             "DataConfig.native_gather=false to silence this.",
@@ -90,13 +90,11 @@ def _aliases_host_storage(arrays, spans) -> bool:
     copies (TPU HBM) → the slot is reusable once the transfer completes;
     aliased → the slot's storage is handed to the array and the ring
     refills with a fresh allocation (the pre-ring behavior — correctness
-    first).  Unverifiable shards count as aliased."""
+    first).  A backend that cannot report the pointer raises: guessing
+    "aliased" would silently re-allocate the ring every batch."""
     for ga in arrays:
         for shard in ga.addressable_shards:
-            try:
-                p = shard.data.unsafe_buffer_pointer()
-            except Exception:
-                return True
+            p = shard.data.unsafe_buffer_pointer()
             if any(lo <= p < hi for lo, hi in spans):
                 return True
     return False
@@ -135,6 +133,9 @@ class _HostRing:
         self._alloc = alloc
         self._cv = lockcheck.condition("_HostRing._cv")
         self._slots = [alloc() for _ in range(nslots)]  # guarded-by: _cv
+        # Slots handed over to an aliasing upload and re-allocated; stays 0
+        # where uploads are real copies (chip_smoke.py asserts that on TPU).
+        self.retired = 0  # guarded-by: _cv
 
     def acquire(self) -> _Slot:
         with self._cv:
@@ -146,6 +147,7 @@ class _HostRing:
         if retire:
             slot = self._alloc(reuse_scratch_from=slot)
         with self._cv:
+            self.retired += bool(retire)
             self._slots.append(slot)
             self._cv.notify()
 
@@ -526,8 +528,8 @@ class DeviceCachedLoader(_EpochSampler):
     """Whole-dataset-on-HBM loader: upload once, gather batches on device.
 
     For corpora that fit HBM (ISPRS scale: 127 × 512²×3 fp32 ≈ 400 MB) the
-    per-epoch host→device re-upload is the bottleneck — on a tunneled or
-    DCN-attached host it can be 30-60× the step's compute time.  This
+    per-epoch host→device re-upload is the bottleneck — on a slow or
+    DCN-attached host link it can be many times the step's compute.  This
     loader uploads the tile arrays ONCE (replicated), then every
     super-batch is a compiled on-device ``take`` resharded onto the data
     axis; epochs cost zero host-link bytes.

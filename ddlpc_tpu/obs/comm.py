@@ -392,7 +392,6 @@ def make_comm_probe(
         sync_gradients,
         sync_gradients_scatter,
     )
-    from ddlpc_tpu.utils.compat import shard_map
 
     axis_size = mesh.shape[data_axis]
     use_scatter = bool(scatter) and axis_size > 1
@@ -415,12 +414,12 @@ def make_comm_probe(
 
     out_spec = P(data_axis) if use_scatter else P()
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(),),
             out_specs=out_spec,
-            check=False,
+            check_vma=False,
         )
     )
 

@@ -45,16 +45,12 @@ import threading
 import time
 from typing import Dict, Optional, Tuple
 
-# TPU v5e (v5 lite) peak dense bf16 FLOP/s per chip — the roofline
-# denominator used across the repo (bench.py, docs/PERF.md).  Used as the
-# ASSUMED peak whenever the backend's device kind is not in the table
-# (e.g. the CPU test meshes) so MFU numbers stay comparable with the
-# committed bench tables; ``ddlpc_peak_flops_assumed`` says so.
-V5E_PEAK_FLOPS = 197e12
-
-# Known accelerator peaks (dense bf16 FLOP/s per chip), keyed by substrings
-# of ``jax.Device.device_kind``.  Deliberately short: entries are added
-# when a backend is actually measured against (docs/PERF.md discipline).
+# Peak dense bf16 FLOP/s per chip — the ONE table behind every MFU this
+# repo prints (the Trainer's gauges, bench.py, chip_smoke.py), keyed by
+# substrings of ``jax.Device.device_kind``.  Source: Google Cloud TPU
+# documentation, per-chip bf16 peaks ("TPU v5e": 197 TFLOP/s; the chip
+# reports itself as "TPU v5 lite").  A device that is not here is an error
+# (:func:`device_peak_flops`), not a default.
 _PEAK_BY_DEVICE_KIND = (
     ("v5 lite", 197e12),
     ("v5e", 197e12),
@@ -62,6 +58,11 @@ _PEAK_BY_DEVICE_KIND = (
     ("v4", 275e12),
     ("v3", 123e12),
 )
+
+# The peak the CPU test meshes ASSUME (they have no peak of their own), so
+# their MFU gauges stay on the flagship chip's scale;
+# ``ddlpc_peak_flops_assumed`` says so.
+V5E_PEAK_FLOPS = 197e12
 
 
 # --------------------------------------------------------------------------
@@ -208,26 +209,37 @@ def conv_step_flops(
     return sync_period * per_micro
 
 
+def device_peak_flops(device) -> float:
+    """Peak dense bf16 FLOP/s of a ``jax.Device`` from the one table;
+    an unknown ``device_kind`` raises."""
+    kind = device.device_kind.lower()
+    for sub, peak in _PEAK_BY_DEVICE_KIND:
+        if sub in kind:
+            return peak
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {device.device_kind!r} "
+        f"(platform {device.platform!r}): add it to "
+        f"obs/flops._PEAK_BY_DEVICE_KIND with its source, or set "
+        f"TrainConfig.peak_flops_per_device"
+    )
+
+
 def resolve_peak_flops(configured: float = 0.0) -> Tuple[float, bool]:
     """(peak FLOP/s per device, assumed?) for the MFU denominator.
 
     ``configured`` > 0 wins (``TrainConfig.peak_flops_per_device``).
-    Otherwise the backend's device kind is looked up; unknown kinds (CPU
-    test meshes, new accelerators) fall back to the v5e peak with
-    ``assumed=True`` so the gauge stays comparable with the committed
-    bench tables rather than fabricating a per-host number."""
+    Otherwise the backend's device kind is looked up in the one table.
+    Only the CPU platform (the test meshes) may miss it: it gets the v5e
+    peak with ``assumed=True``.  An unknown accelerator raises — an MFU
+    against somebody else's peak is not a measurement."""
     if configured and configured > 0:
         return float(configured), False
-    try:
-        import jax
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:
-        kind = ""
-    for sub, peak in _PEAK_BY_DEVICE_KIND:
-        if sub in kind:
-            return peak, False
-    return V5E_PEAK_FLOPS, True
+    device = jax.devices()[0]
+    if device.platform == "cpu":
+        return V5E_PEAK_FLOPS, True
+    return device_peak_flops(device), False
 
 
 def restart_gap_seconds(workdir: str, now: Optional[float] = None) -> float:

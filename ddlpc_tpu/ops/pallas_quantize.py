@@ -42,9 +42,11 @@ _BLOCK_ROWS = 256  # 256×1024 fp32 = 1 MiB per VMEM block
 
 
 def default_interpret() -> bool:
-    """Run the kernel via the Pallas interpreter off-TPU (CPU test meshes,
-    GPU hosts) — Mosaic lowering exists only for real TPU backends."""
-    return jax.default_backend() != "tpu"
+    """Run the kernel via the Pallas interpreter on the CPU platform only
+    (the test meshes).  Every other platform compiles it for real — a
+    backend Mosaic cannot target fails there, loudly, instead of timing the
+    interpreter under the kernel's name."""
+    return jax.default_backend() == "cpu"
 
 
 def _fq_kernel(scale_ref, seed_ref, x_ref, out_ref, *, levels: float, stochastic: bool):

@@ -49,9 +49,7 @@ def halo_exchange(
     """
     if halo <= 0:
         return x
-    from ddlpc_tpu.utils.compat import axis_size
-
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if x.shape[spatial_axis] < halo:
         raise ValueError(
             f"local spatial extent {x.shape[spatial_axis]} smaller than halo "

@@ -33,20 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ddlpc_tpu.config import ParallelConfig
 
 
-def _distributed_client_active() -> bool:
-    """True if jax.distributed.initialize() already ran in this process.
-
-    Deliberately does NOT call jax.process_count() — that initializes the XLA
-    backend, after which jax.distributed.initialize() refuses to run.
-    """
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:
-        return False
-
-
 def initialize_distributed(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -62,7 +48,10 @@ def initialize_distributed(
     bare call suffices.  No-op when neither arguments nor environment request
     a multi-process run, so single-process users may call it unconditionally.
     """
-    if _distributed_client_active():
+    # is_initialized() reads the distributed client's state only; it does
+    # not initialize the XLA backend (jax.process_count() would, after which
+    # jax.distributed.initialize() refuses to run).
+    if jax.distributed.is_initialized():
         return
     coordinator_address = coordinator_address or os.environ.get(
         "COORDINATOR_ADDRESS"

@@ -81,7 +81,6 @@ from ddlpc_tpu.parallel.train_step import (
     loss_from_logits,
     make_train_step,
 )
-from ddlpc_tpu.utils.compat import shard_map
 
 PyTree = Any
 
@@ -471,11 +470,11 @@ class PipelineTrainStep:
                     out, new_stats = apply_blocks(params, stats, x, carry, blocks)
                     return out, new_stats
 
-                return jax.jit(shard_map(
+                return jax.jit(jax.shard_map(
                     body, mesh=mesh_s,
                     in_specs=(P(), P(), P(data_axis)),
                     out_specs=(P(data_axis), P()),
-                    check=False,
+                    check_vma=False,
                 ))
 
             def make_bwd(blocks=blocks, first=first, mesh_s=mesh_s):
@@ -508,12 +507,12 @@ class PipelineTrainStep:
 
                 dcin_spec = P() if first else P(data_axis)
                 return jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         body, mesh=mesh_s,
                         in_specs=(P(), P(), P(data_axis), P(data_axis),
                                   P(data_axis)),
                         out_specs=(dcin_spec, P(data_axis)),
-                        check=False,
+                        check_vma=False,
                     ),
                     donate_argnums=(4,),
                 )
@@ -555,13 +554,13 @@ class PipelineTrainStep:
 
                 dcin_spec = P() if first else P(data_axis)
                 return jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         body, mesh=mesh_s,
                         in_specs=(P(), P(), P(data_axis), P(data_axis),
                                   P(data_axis)),
                         out_specs=(P(data_axis), P(data_axis), dcin_spec,
                                    P(), P(data_axis)),
-                        check=False,
+                        check_vma=False,
                     ),
                     donate_argnums=(4,),
                 )
@@ -613,12 +612,12 @@ class PipelineTrainStep:
                             self.tx, params, lvl, data_axis
                         )
                         param_specs = P()
-                    sharded = shard_map(
+                    sharded = jax.shard_map(
                         body, mesh=mesh_s,
                         in_specs=(param_specs, opt_specs, P(data_axis),
                                   P(), P()),
                         out_specs=(param_specs, opt_specs, P(), P(), P()),
-                        check=False,
+                        check_vma=False,
                     )
                     return sharded(params, opt_state, gacc, stats, step)
 

@@ -34,7 +34,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ddlpc_tpu.config import CompressionConfig, ExperimentConfig
 from ddlpc_tpu.models.layers import group_labels
-from ddlpc_tpu.utils.compat import shard_map
 from ddlpc_tpu.ops.losses import nll_correct_valid, softmax_cross_entropy_sum
 from ddlpc_tpu.ops.metrics import confusion_from_logits
 from ddlpc_tpu.parallel.grad_sync import sync_gradients, sync_gradients_scatter
@@ -532,12 +531,12 @@ def make_train_step(
 
     donate = (0,) if donate_state else ()
     if level == "off":
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(P(), P(None, data_axis), P(None, data_axis)),
             out_specs=(P(), P()),
-            check=False,
+            check_vma=False,
         )
         return jax.jit(sharded, donate_argnums=donate)
 
@@ -545,12 +544,12 @@ def make_train_step(
         # Specs depend on the state's (chunked) structure — build them at
         # trace time from the avals; shard_map composes under jit.
         specs = _zero_state_specs(state, tx, data_axis, level)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(specs, P(None, data_axis), P(None, data_axis)),
             out_specs=(specs, P()),
-            check=False,
+            check_vma=False,
         )
         return sharded(state, images, labels)
 
@@ -858,12 +857,12 @@ def make_update_step(
             # zero3 needs no canonical param shapes here.
             opt_specs = zero.opt_partition_specs(tx, params, level, data_axis)
             param_specs = P(data_axis) if level == "zero3" else P()
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(param_specs, opt_specs, P()),
             out_specs=(param_specs, opt_specs),
-            check=False,
+            check_vma=False,
         )
         return sharded(params, opt_state, grads)
 
@@ -926,12 +925,12 @@ def make_eval_step(
             "pixel_count": lax.psum(count, data_axis),
         }
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(), P(data_axis), P(data_axis)),
         out_specs=P(),
-        check=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 

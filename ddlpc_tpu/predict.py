@@ -28,6 +28,7 @@ from ddlpc_tpu.serve.engine import (  # noqa: F401  (public re-exports)
     _blend_window,
     sliding_window_logits,
 )
+from ddlpc_tpu.utils.compile_cache import enable_compile_cache
 
 
 def load_run(workdir: str):
@@ -43,6 +44,7 @@ def load_run(workdir: str):
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser(prog="python -m ddlpc_tpu.predict")
     p.add_argument("--workdir", required=True, help="training run directory")
     p.add_argument("--input", required=True, help="directory of images")

@@ -59,6 +59,7 @@ from ddlpc_tpu.serve.engine import (
     window_plan,
 )
 from ddlpc_tpu.serve.metrics import ServeMetrics
+from ddlpc_tpu.utils.compile_cache import enable_compile_cache
 
 
 class ServingFrontend:
@@ -776,6 +777,7 @@ def drain_and_close(
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     p = argparse.ArgumentParser(prog="python -m ddlpc_tpu.serve.server")
     p.add_argument("--config", help="ServeConfig JSON (configs/serve_*.json)")
     p.add_argument("--workdir", help="training run to serve (overrides config)")

@@ -14,6 +14,7 @@ import ast
 import sys
 
 from ddlpc_tpu.config import ExperimentConfig
+from ddlpc_tpu.utils.compile_cache import enable_compile_cache
 
 
 def apply_override(d: dict, dotted: str, value: str) -> None:
@@ -66,15 +67,24 @@ def parse_config(argv=None) -> tuple[ExperimentConfig, bool]:
     return cfg, not args.no_resume
 
 
-def main(argv=None) -> int:
+def run(argv=None):
+    """The CLI's whole job — parse, build the Trainer, fit — returning the
+    finished Trainer: ``main`` maps it to an exit status, and
+    ``chip_smoke.py`` reads its device state after the same call."""
+    enable_compile_cache()
     cfg, resume = parse_config(argv)
-    from ddlpc_tpu.resilience.protocol import EXIT_PREEMPTED
     from ddlpc_tpu.train.trainer import Trainer
 
     trainer = Trainer(cfg, resume=resume)
     record = trainer.fit()
     print({k: round(v, 4) if isinstance(v, float) else v for k, v in record.items()})
-    if trainer.preempted:
+    return trainer
+
+
+def main(argv=None) -> int:
+    from ddlpc_tpu.resilience.protocol import EXIT_PREEMPTED
+
+    if run(argv).preempted:
         # Distinct restartable-clean status (resilience/protocol.py): the
         # supervisor relaunches without backoff and the resume skip-replays
         # to the exact preempted step.

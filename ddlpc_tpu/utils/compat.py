@@ -1,82 +1,17 @@
-"""Version-portability shims for the pinned jax toolchain.
-
-The repo targets current jax idiom (``jax.shard_map``), but the container
-pins jax 0.4.37, where shard_map still lives in ``jax.experimental`` and the
-replication-checking kwarg is ``check_rep`` (renamed ``check_vma`` when the
-API was promoted).  Callers import :func:`shard_map` from here and pass the
-portable ``check`` kwarg; the shim resolves whichever API is installed.
-"""
+"""Process-level JAX configuration for the one installed toolchain (jax 0.9.0)."""
 
 from __future__ import annotations
-
-from typing import Any
 
 import jax
 
 
 def force_cpu_devices(n: int) -> None:
-    """Force an ``n``-device virtual CPU backend, portably.
+    """Pin this process to an ``n``-device virtual CPU backend.
 
-    Newer jax exposes the ``jax_num_cpu_devices`` config option; older ones
-    (the pinned 0.4.37) only honor ``--xla_force_host_platform_device_count``
-    in ``XLA_FLAGS``, which is read when the CPU client initializes — so
-    this must be called BEFORE the first device use (it is fine to call it
-    after ``import jax``).
+    Must run before the first device use (it is fine after ``import jax``).
+    The config option outranks an inherited ``JAX_NUM_CPU_DEVICES`` or
+    ``--xla_force_host_platform_device_count``, so a child of the test
+    suite (conftest's 8) still gets the count IT asked for.
     """
-    import os
-
-    # REPLACE any pre-existing count rather than skip: a child process
-    # inheriting the parent's XLA_FLAGS (e.g. conftest's 8) must still get
-    # the count IT asked for, or its run is silently mislabeled.
-    kept = [
-        f
-        for f in os.environ.get("XLA_FLAGS", "").split()
-        if not f.startswith("--xla_force_host_platform_device_count")
-    ]
-    kept.append(f"--xla_force_host_platform_device_count={n}")
-    os.environ["XLA_FLAGS"] = " ".join(kept)
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:
-        pass  # pre-0.5 jax: the XLA_FLAGS fallback already took effect
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mapped axis, inside shard_map/pmap.
-
-    ``lax.axis_size`` only exists on newer jax; the portable idiom
-    ``lax.psum(1, axis)`` constant-folds to a Python int on the old ones.
-    """
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def shard_map(
-    f: Any, *, mesh: Any, in_specs: Any, out_specs: Any, check: bool = True
-) -> Any:
-    """`jax.shard_map` on new jax, `jax.experimental.shard_map` on old.
-
-    ``check`` maps to ``check_vma`` (new) / ``check_rep`` (old) — both gate
-    the same per-output replication verification.  The kwarg is picked by
-    inspecting the resolved function's signature, not by which module it
-    came from: there are release bands where the top-level API still took
-    ``check_rep``.
-    """
-    import inspect
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-
-    try:
-        params = inspect.signature(fn).parameters
-        kwarg = "check_vma" if "check_vma" in params else "check_rep"
-    except (TypeError, ValueError):  # C-level or wrapped: assume modern
-        kwarg = "check_vma" if hasattr(jax, "shard_map") else "check_rep"
-    return fn(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{kwarg: check}
-    )
+    jax.config.update("jax_num_cpu_devices", int(n))

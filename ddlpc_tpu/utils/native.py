@@ -12,9 +12,9 @@ here the native components are part of the framework:
   :class:`NativeBatch` (or None), and data/loader.py falls back to the
   byte-identical numpy path — same discipline as the wire codec.
 
-Each ``load*()`` finds a prebuilt ``.so`` (or builds it with g++ on first
-use); failures are cached so a missing toolchain costs one probe, not one
-per call.
+Each ``load*()`` runs ``make`` for its library on first use (a no-op when
+the ``.so`` is newer than its source); failures are cached so a missing
+toolchain costs one probe, not one per call.
 """
 
 from __future__ import annotations
@@ -175,8 +175,9 @@ class NativeBatch:
 
 
 def _build(target: str) -> bool:
-    if not os.path.exists(os.path.join(_CSRC, "Makefile")):
-        return False
+    """``make -s <target>`` in csrc/: builds a missing library, rebuilds
+    one older than its source, and is a no-op when current.  False when
+    make or the compiler is missing, or the build fails."""
     try:
         subprocess.run(
             ["make", "-s", target],
@@ -185,27 +186,26 @@ def _build(target: str) -> bool:
             capture_output=True,
             timeout=120,
         )
-        return os.path.exists(os.path.join(_CSRC, target))
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return False
+    return os.path.exists(os.path.join(_CSRC, target))
 
 
-def _load(path: str, wrapper, source: str, build: bool):
-    """Shared load-or-build-once core; failures cached per library."""
+def _load(path: str, wrapper):
+    """Shared make-then-load-once core; failures cached per library.
+
+    make runs on every first load, not only when the file is missing: the
+    ``.so`` files are git-ignored build outputs, and a stale one must not
+    outlive an edit to ``csrc/*.cc``."""
     name = os.path.basename(path)
     with _lock:
         if name in _cached:
             return _cached[name]
         if _failed.get(name):
             return None
-        if not os.path.exists(path):
-            if not (
-                build
-                and os.path.exists(os.path.join(_CSRC, source))
-                and _build(name)
-            ):
-                _failed[name] = True
-                return None
+        if not _build(name):
+            _failed[name] = True
+            return None
         try:
             _cached[name] = wrapper(ctypes.CDLL(path))
         except (OSError, AttributeError):
@@ -214,13 +214,13 @@ def _load(path: str, wrapper, source: str, build: bool):
         return _cached[name]
 
 
-def load(build: bool = True) -> Optional[NativeWire]:
-    """The loaded native wire codec, building it on first use; None on
+def load() -> Optional[NativeWire]:
+    """The loaded native wire codec, (re)built on first use; None on
     failure (wire.py stays on its pure-Python zlib path)."""
-    return _load(_LIB, NativeWire, "wire.cc", build)
+    return _load(_LIB, NativeWire)
 
 
-def load_batch(build: bool = True) -> Optional[NativeBatch]:
-    """The loaded native batch-assembly kernel, building it on first use;
+def load_batch() -> Optional[NativeBatch]:
+    """The loaded native batch-assembly kernel, (re)built on first use;
     None on failure (data/loader.py logs once and stays on numpy)."""
-    return _load(_BATCH_LIB, NativeBatch, "batch.cc", build)
+    return _load(_BATCH_LIB, NativeBatch)

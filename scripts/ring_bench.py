@@ -41,7 +41,6 @@ def child(rank: int, port: int, elements: int, out: str, procs: int) -> None:
     import jax
 
     from ddlpc_tpu.parallel.mesh import initialize_distributed
-    from ddlpc_tpu.utils.compat import shard_map  # noqa: F401 (used below)
 
     initialize_distributed(
         coordinator_address=f"127.0.0.1:{port}", num_processes=procs, process_id=rank
@@ -83,12 +82,12 @@ def child(rank: int, port: int, elements: int, out: str, procs: int) -> None:
         results = {}
         for length in (length_a, length_b):
             f = jax.jit(
-                shard_map(
+                jax.shard_map(
                     functools.partial(loop, length=length),
                     mesh=mesh,
                     in_specs=P("data"),
                     out_specs=P(),
-                    check=False,
+                    check_vma=False,
                 )
             )
             g = jnp.concatenate([local] * n_dev)  # global [n·e] sharded over n

@@ -21,7 +21,6 @@ from ddlpc_tpu.parallel.compressed_allreduce import (
     ring_allreduce_mean_quantized,
     wire_dtype,
 )
-from ddlpc_tpu.utils.compat import shard_map
 
 N_DEV = 8
 
@@ -33,7 +32,7 @@ def _mesh():
 def _run_ring(tree_per_dev, cfg, n=N_DEV):
     """tree_per_dev: pytree whose leaves have a leading device axis of n."""
     mesh = Mesh(np.array(jax.devices()[:n]), ("data",))
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             ring_allreduce_mean_quantized,
             axis_name="data",
@@ -43,7 +42,7 @@ def _run_ring(tree_per_dev, cfg, n=N_DEV):
         mesh=mesh,
         in_specs=P("data"),
         out_specs=P("data"),
-        check=False,
+        check_vma=False,
     )
     return fn(tree_per_dev)
 

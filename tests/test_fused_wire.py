@@ -50,7 +50,6 @@ from ddlpc_tpu.parallel.grad_sync import (
     sync_gradients,
     sync_gradients_scatter,
 )
-from ddlpc_tpu.utils.compat import shard_map
 
 N_DEV = 8
 
@@ -185,8 +184,8 @@ def _run_sync(tree_per_dev, cfg, scatter=False, key=None):
             axis_size=N_DEV,
             key=key,
         )
-    wrapped = shard_map(
-        fn, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check=False
+    wrapped = jax.shard_map(
+        fn, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False
     )
     return wrapped(tree_per_dev)
 

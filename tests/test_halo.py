@@ -20,7 +20,6 @@ from ddlpc_tpu.config import (
 )
 from ddlpc_tpu.parallel.halo import halo_exchange, sharded_same_conv
 from ddlpc_tpu.parallel.mesh import make_mesh
-from ddlpc_tpu.utils.compat import shard_map
 
 
 @pytest.fixture(scope="module")
@@ -36,7 +35,7 @@ def test_halo_exchange_matches_neighbor_rows(space_mesh):
         return halo_exchange(x_local, "space", halo)
 
     out = jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=space_mesh,
             in_specs=P(None, "space"),
@@ -69,7 +68,7 @@ def test_halo_too_large_raises(space_mesh):
 
     def run():
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda v: halo_exchange(v, "space", 3),
                 mesh=space_mesh,
                 in_specs=P(None, "space"),
@@ -91,7 +90,7 @@ def test_sharded_conv_matches_global_conv(space_mesh):
         x, k, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
     )
     sharded = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda v: sharded_same_conv(v, k, "space"),
             mesh=space_mesh,
             in_specs=P(None, "space"),
@@ -224,7 +223,7 @@ def test_halo_conv_on_stage_submesh_odd_rows():
             return sharded_same_conv(xl, kernel, "space")
 
         out = jax.jit(
-            shard_map(
+            jax.shard_map(
                 body, mesh=sub,
                 in_specs=P(None, "space"), out_specs=P(None, "space"),
             )
@@ -254,7 +253,7 @@ def test_halo_at_stage_boundary_carry():
             return halo_exchange(xl, "space", 1)
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 body, mesh=mesh_s,
                 in_specs=P(None, "space"), out_specs=P(None, "space"),
             )

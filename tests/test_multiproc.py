@@ -13,18 +13,6 @@ import os
 import subprocess
 import sys
 
-import jax
-import pytest
-
-# jax 0.4.x CPU cannot run cross-process collectives at all (device_put of a
-# multi-host sharded array raises "Multiprocess computations aren't
-# implemented on the CPU backend") — the capability these tests exist to
-# exercise appeared in later jax.  Skip, don't fail, on the pinned 0.4.37.
-pytestmark = pytest.mark.skipif(
-    tuple(map(int, jax.__version__.split(".")[:2])) < (0, 5),
-    reason="multi-process CPU collectives require jax >= 0.5",
-)
-
 SCRIPT = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "scripts",

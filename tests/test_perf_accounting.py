@@ -151,8 +151,8 @@ def test_resolve_peak_flops():
     peak, assumed = obs_flops.resolve_peak_flops(5e12)
     assert peak == 5e12 and not assumed
     peak, assumed = obs_flops.resolve_peak_flops(0.0)
-    # CPU test mesh: unknown device kind falls back to the v5e peak,
-    # flagged as an assumption.
+    # The CPU test mesh has no peak of its own: it assumes the v5e's,
+    # flagged (an unknown ACCELERATOR raises — tests/test_chip_bringup.py).
     assert peak == obs_flops.V5E_PEAK_FLOPS and assumed
 
 

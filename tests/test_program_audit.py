@@ -35,24 +35,45 @@ from ddlpc_tpu.analysis import program as prog  # noqa: E402
 # HLO text walker units (no jax)
 # --------------------------------------------------------------------------
 
+# The text form jax 0.9.0 prints: operands by name, provenance through the
+# module's stack-frame tables (trimmed from a real ``compiled.as_text()``).
 _SAMPLE_HLO = """\
 HloModule jit_step, is_scheduled=true, input_output_alias={ {0}: (0, {}, may-alias), {1}: (2, {}, may-alias) }, entry_computation_layout={(f32[7]{0}, f32[64,33]{1,0}, s8[16]{0})->(f32[7]{0}, f32[64,33]{1,0})}, num_partitions=8
+
+FileNames
+1 "/repo/ddlpc_tpu/parallel/grad_sync.py"
+2 "/repo/ddlpc_tpu/parallel/compressed_allreduce.py"
+3 "/repo/ddlpc_tpu/parallel/train_step.py"
+
+FunctionNames
+1 "sync_gradients"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=135 end_line=135 column=4 end_column=9}
+2 {file_name_id=2 function_name_id=1 line=208 end_line=208 column=4 end_column=9}
+3 {file_name_id=3 function_name_id=1 line=272 end_line=272 column=4 end_column=9}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=2}
+3 {file_location_id=3 parent_frame_id=1}
 
 %region_4.71 (a: f32[], b: f32[]) -> f32[] {
   %a = f32[] parameter(0)
   %b = f32[] parameter(1)
-  ROOT %add.1 = f32[] add(f32[] %a, f32[] %b)
+  ROOT %add.1 = f32[] add(%a, %b)
 }
 
-ENTRY %main.10 (p0: f32[7], p1: f32[64,33], p2: s8[16]) {
+ENTRY %main.10 (p0: f32[7], p1: f32[64,33], p2: s8[16]) -> (f32[7], f32[64,33]) {
   %p0 = f32[7]{0} parameter(0)
   %p1 = f32[64,33]{1,0} parameter(1)
   %p2 = s8[16]{0} parameter(2)
-  %all-reduce.3 = f32[64,33]{1,0} all-reduce(f32[64,33]{1,0} %p1), channel_id=2, replica_groups={{0,1,2,3,4,5,6,7}}, to_apply=%region_4.71, metadata={op_name="jit(step)/psum" source_file="/repo/ddlpc_tpu/parallel/grad_sync.py" source_line=135}
-  %opt-barrier.6 = (f32[6]{0}, f32[1,1,8,6]{3,2,1,0}, f32[16]{0}, f32[16]{0}, f32[16]{0}, /*index=5*/f32[16]{0}, f32[7]{0}) opt-barrier((f32[6]{0}, f32[1,1,8,6]{3,2,1,0}, f32[16]{0}, f32[16]{0}, f32[16]{0}, /*index=5*/f32[16]{0}, f32[7]{0}) %tuple.2)
-  %collective-permute.1 = s8[16]{0} collective-permute(s8[16]{0} %p2), channel_id=3, source_target_pairs={{0,1},{1,2}}, metadata={op_name="jit(step)/ppermute" source_file="/repo/ddlpc_tpu/parallel/compressed_allreduce.py" source_line=208}
-  %all-gather.2 = f32[64,33]{1,0} all-gather(f32[8,33]{1,0} %p0), channel_id=4, dimensions={0}, metadata={op_name="jit(step)/all_gather" source_file="/repo/ddlpc_tpu/parallel/train_step.py" source_line=272}
-  ROOT %tuple.9 = (f32[7]{0}, f32[64,33]{1,0}) tuple(f32[7]{0} %p0, f32[64,33]{1,0} %all-reduce.3)
+  %a = f32[8,33]{1,0} bitcast(%p1)
+  %all-reduce.3 = f32[64,33]{1,0} all-reduce(%p1), channel_id=2, replica_groups={{0,1,2,3,4,5,6,7}}, to_apply=%region_4.71, metadata={op_name="jit(step)/psum" stack_frame_id=1}
+  %opt-barrier.6 = (f32[6]{0}, f32[1,1,8,6]{3,2,1,0}, f32[16]{0}, f32[16]{0}, f32[16]{0}, /*index=5*/f32[16]{0}, f32[7]{0}) opt-barrier(%tuple.2)
+  %collective-permute.1 = s8[16]{0} collective-permute(%p2), channel_id=3, source_target_pairs={{0,1},{1,2}}, metadata={op_name="jit(step)/ppermute" stack_frame_id=2}
+  %all-gather.2 = f32[64,33]{1,0} all-gather(%a), channel_id=4, dimensions={0}, metadata={op_name="jit(step)/all_gather" stack_frame_id=3}
+  ROOT %tuple.9 = (f32[7]{0}, f32[64,33]{1,0}) tuple(%p0, %all-reduce.3)
 }
 """
 
@@ -72,7 +93,10 @@ def test_parse_hlo_module_header_and_ops():
     assert ar.opcode == "all-reduce"
     assert ar.source_file.endswith("grad_sync.py")
     assert ar.source_line == 135
+    # operands are printed by name: the reference resolves to the shape
+    # its computation defined (the region's own %a is a different scope)
     assert ar.operand_bytes == 64 * 33 * 4
+    assert ops["all-gather.2"].operand_bytes == 8 * 33 * 4
 
 
 def test_hlo_collective_census_groups_and_bytes():

@@ -48,7 +48,6 @@ from ddlpc_tpu.parallel.train_step import (
     make_update_step,
 )
 from ddlpc_tpu.train.optim import build_optimizer
-from ddlpc_tpu.utils.compat import shard_map
 
 # Smallest model that still has the interesting leaf zoo (conv kernels,
 # biases and BN scale/bias SMALLER than the shard count → padding path):
@@ -234,9 +233,9 @@ def test_zero1_fence_inputs_match_scatter_shards(codec):
         return sliced, shards
 
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             body, mesh=mesh, in_specs=(P(),),
-            out_specs=(P("data"), P("data")), check=False,
+            out_specs=(P("data"), P("data")), check_vma=False,
         )
     )
     sliced, shards = fn(tree)
