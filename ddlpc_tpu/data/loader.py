@@ -599,12 +599,13 @@ class DeviceCachedLoader(_EpochSampler):
 
         @jax.jit
         def gather(images, labels, idx):
-            bx = jnp.take(images, idx, axis=0).reshape(A, B, h, w, c)
-            by = jnp.take(labels, idx, axis=0).reshape(A, B, h, w)
-            return (
-                jax.lax.with_sharding_constraint(bx, batch_sh),
-                jax.lax.with_sharding_constraint(by, batch_sh),
-            )
+            with jax.named_scope("ddlpc/gather"):
+                bx = jnp.take(images, idx, axis=0).reshape(A, B, h, w, c)
+                by = jnp.take(labels, idx, axis=0).reshape(A, B, h, w)
+                return (
+                    jax.lax.with_sharding_constraint(bx, batch_sh),
+                    jax.lax.with_sharding_constraint(by, batch_sh),
+                )
 
         self._gather = gather
 

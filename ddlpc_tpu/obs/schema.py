@@ -32,6 +32,7 @@ KNOWN_KINDS = frozenset(
         "serve_quant",  # quantized-deploy audit: mode + resident bytes (serve/server.py)
         "profile",  # on-demand profiler reports (obs/profiling.py)
         "preempt",  # graceful-preemption record (train/trainer.py)
+        "init",  # Trainer.__init__ phase seconds, once per construction (train/trainer.py)
         "supervisor_attempt",  # resilience.jsonl (resilience/supervisor.py)
         "supervisor_give_up",
         "perf",  # goodput/MFU accounting (obs/flops.py, per epoch)

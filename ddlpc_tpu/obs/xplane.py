@@ -157,12 +157,43 @@ def self_times_any(trace_dir: str) -> Iterator[Tuple[str, Counter, Counter]]:
             yield plane.name, agg, cnt
 
 
+def op_scope(tf_op: str) -> str:
+    """The scope of a device op from its ``tf_op`` stat (``<op_name>:<type>``):
+    ``jit(step)/shard_map/ddlpc/update/mul:`` → ``shard_map/ddlpc/update``.
+    The ``ddlpc/*`` components are the step's ``jax.named_scope``s
+    (parallel/train_step.py), the rest Flax module names and JAX transforms
+    (``transpose(jvp(UNet))/DetailHead_0/Conv_0`` is a backward op of the head)
+    — the names the benchmark's per-region device metrics group by."""
+    parts = tf_op.rsplit(":", 1)[0].split("/")
+    if parts[0].startswith(("jit(", "pjit(")):
+        parts = parts[1:]
+    return "/".join(parts[:-1])
+
+
+def op_scopes(trace_dir: str) -> dict:
+    """``{op name: scope}`` for every device-plane op whose metadata carries a
+    ``tf_op`` stat (TPU/GPU traces; the CPU backend's events carry none)."""
+    out: dict = {}
+    for plane in load_xspace(trace_dir).planes:
+        if not plane.name.startswith("/device:"):
+            continue
+        stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+        for meta in plane.event_metadata.values():
+            for stat in meta.stats:
+                if stat_names.get(stat.metadata_id) == "tf_op":
+                    tf_op = stat.str_value or stat_names.get(stat.ref_value, "")
+                    if tf_op:
+                        out[meta.name] = op_scope(tf_op)
+    return out
+
+
 def top_ops_report(
     trace_dir: str, top: int = 30, steps: int = 1, tag: str = ""
 ) -> dict:
     """The committed top-ops JSON format (docs/head_bench/trace_*.json
     introduced it; the on-demand hooks emit the same shape, plus the planes
-    the ops came from).  ``steps`` normalizes to per-step milliseconds."""
+    the ops came from and, where the trace gives one, each op's ``scope``).
+    ``steps`` normalizes to per-step milliseconds."""
     steps = max(int(steps), 1)
     agg: Counter = collections.Counter()
     cnt: Counter = collections.Counter()
@@ -172,6 +203,7 @@ def top_ops_report(
         agg.update(a)
         cnt.update(c)
     total_ps = sum(agg.values())
+    scopes = op_scopes(trace_dir)
     return {
         "tag": tag,
         "trace_dir": os.path.abspath(trace_dir),
@@ -184,6 +216,9 @@ def top_ops_report(
                 "op": name[:160],
                 "self_ms_per_step": round(ps / 1e9 / steps, 4),
                 "count": cnt[name],
+                # where the trace names one (docs/OBSERVABILITY.md "Device
+                # scopes"): the region this op's time belongs to
+                **({"scope": scopes[name]} if name in scopes else {}),
             }
             for name, ps in agg.most_common(top)
         ],

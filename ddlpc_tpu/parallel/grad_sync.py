@@ -228,6 +228,7 @@ def _fused_scatter_mean(
     return _wire_decode(summed, inv, compression)
 
 
+@jax.named_scope("ddlpc/grad_sync")
 def sync_gradients(
     grads: PyTree,
     axis_name: str,
@@ -388,6 +389,7 @@ def validate_scatter_compression(compression: CompressionConfig) -> None:
         )
 
 
+@jax.named_scope("ddlpc/grad_sync")
 def sync_gradients_scatter(
     grads: PyTree,
     axis_name: str,

@@ -111,6 +111,7 @@ def _loss_and_metrics(
     return loss, (new_stats, acc)
 
 
+@jax.named_scope("ddlpc/loss")
 def loss_from_logits(
     model: nn.Module, logits: jax.Array, labels: jax.Array, train: bool
 ) -> Tuple[jax.Array, jax.Array]:
@@ -205,14 +206,20 @@ def _accumulate_grads(
         grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
         return (grads_acc, stats), (loss, acc)
 
-    zeros = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), state.params)
-    (grads, batch_stats), (losses, accs) = lax.scan(
-        micro, (zeros, state.batch_stats), (images, labels)
-    )
-    grads = jax.tree.map(lambda g: g / images.shape[0], grads)
+    # Device scopes (``ddlpc/<region>`` in every instruction's op_name) are
+    # metadata only: the optimized HLO, its fusions and its bits do not move.
+    with jax.named_scope("ddlpc/accumulate"):
+        zeros = jax.tree.map(
+            lambda p: jnp.zeros_like(p, jnp.float32), state.params
+        )
+        (grads, batch_stats), (losses, accs) = lax.scan(
+            micro, (zeros, state.batch_stats), (images, labels)
+        )
+        grads = jax.tree.map(lambda g: g / images.shape[0], grads)
     return grads, batch_stats, losses, accs
 
 
+@jax.named_scope("ddlpc/update")
 def _fenced_update(
     tx: optax.GradientTransformation,
     grads: PyTree,
@@ -242,6 +249,24 @@ def _fenced_update(
     return lax.optimization_barrier((new_params, new_opt))
 
 
+@jax.named_scope("ddlpc/update/gather")
+def _gather_params(shards: PyTree, like: PyTree, data_axis: str) -> PyTree:
+    """All-gather ``[1, K]`` parameter chunks back to the shapes of ``like``
+    (arrays or avals): zero1/zero2's publish after the update, zero3's
+    gather-on-demand before the forward."""
+    return jax.tree.map(
+        lambda sh, p: zero.unchunk_leaf(
+            lax.all_gather(sh, data_axis, axis=0, tiled=True), p.shape
+        ),
+        shards,
+        like,
+    )
+
+
+_global_norm = jax.named_scope("ddlpc/grad_sync/norm")(optax.global_norm)
+
+
+@jax.named_scope("ddlpc/grad_sync/norm")
 def _psum_sq_norm(tree: PyTree, axis_name: str) -> jax.Array:
     """Global gradient norm from per-replica partial sums of squares —
     under the sharded update each replica only holds 1/N of the mean
@@ -276,19 +301,14 @@ def _apply_update_sharded(
     grad_shards = sync_gradients_scatter(
         grads, data_axis, compression, axis_size=axis_size, key=key
     )
-    param_shards = jax.tree.map(
-        lambda p: zero.local_chunk(p, axis_size, data_axis), params
-    )
+    with jax.named_scope("ddlpc/update/chunk"):
+        param_shards = jax.tree.map(
+            lambda p: zero.local_chunk(p, axis_size, data_axis), params
+        )
     new_param_shards, new_opt = _fenced_update(
         tx, grad_shards, opt_state, param_shards
     )
-    new_params = jax.tree.map(
-        lambda sh, p: zero.unchunk_leaf(
-            lax.all_gather(sh, data_axis, axis=0, tiled=True), p.shape
-        ),
-        new_param_shards,
-        params,
-    )
+    new_params = _gather_params(new_param_shards, params, data_axis)
     return new_params, new_opt, _psum_sq_norm(grad_shards, data_axis)
 
 
@@ -333,23 +353,19 @@ def _apply_update_zero1(
     grads = sync_gradients(
         grads, data_axis, compression, axis_size=axis_size, key=key
     )
-    grad_norm = optax.global_norm(grads)
-    grad_shards = jax.tree.map(
-        lambda g: zero.local_chunk(g, axis_size, data_axis), grads
-    )
-    param_shards = jax.tree.map(
-        lambda p: zero.local_chunk(p, axis_size, data_axis), params
-    )
+    grad_norm = _global_norm(grads)
+    with jax.named_scope("ddlpc/update/chunk"):
+        grad_shards = jax.tree.map(
+            lambda g: zero.local_chunk(g, axis_size, data_axis), grads
+        )
+    with jax.named_scope("ddlpc/update/chunk"):
+        param_shards = jax.tree.map(
+            lambda p: zero.local_chunk(p, axis_size, data_axis), params
+        )
     new_param_shards, new_opt = _fenced_update(
         tx, grad_shards, opt_state, param_shards
     )
-    new_params = jax.tree.map(
-        lambda sh, p: zero.unchunk_leaf(
-            lax.all_gather(sh, data_axis, axis=0, tiled=True), p.shape
-        ),
-        new_param_shards,
-        params,
-    )
+    new_params = _gather_params(new_param_shards, params, data_axis)
     return new_params, new_opt, grad_norm
 
 
@@ -461,14 +477,7 @@ def make_train_step(
             # shape for the forward/backward.  The gathered tree is a
             # step temporary — XLA frees it after the backward — so the
             # full model never persists in HBM between steps.
-            full_params = jax.tree.map(
-                lambda ch, av: zero.unchunk_leaf(
-                    lax.all_gather(ch, data_axis, axis=0, tiled=True),
-                    av.shape,
-                ),
-                state.params,
-                param_avals,
-            )
+            full_params = _gather_params(state.params, param_avals, data_axis)
             fwd_state = state.replace(params=full_params)
         else:
             fwd_state = state
@@ -480,9 +489,10 @@ def make_train_step(
         # without it, it averages the per-replica running stats — either way
         # the returned state is genuinely replicated, unlike the reference,
         # which never re-syncs BN stats after init (SURVEY §3.1).
-        batch_stats = jax.tree.map(
-            lambda x: lax.pmean(x, data_axis), batch_stats
-        )
+        with jax.named_scope("ddlpc/grad_sync/stats"):
+            batch_stats = jax.tree.map(
+                lambda x: lax.pmean(x, data_axis), batch_stats
+            )
         # The one (logical) collective of the step — replaces reference
         # L0–L4.  Sharded: reduce-scatter + all-gather, the same wire bytes
         # split around a 1/N-sized update.
@@ -515,12 +525,13 @@ def make_train_step(
             params, opt_state = _fenced_update(
                 tx, grads, state.opt_state, state.params
             )
-            grad_norm = optax.global_norm(grads)
-        metrics = {
-            "loss": lax.pmean(losses.mean(), data_axis),
-            "pixel_acc": lax.pmean(accs.mean(), data_axis),
-            "grad_norm": grad_norm,
-        }
+            grad_norm = _global_norm(grads)
+        with jax.named_scope("ddlpc/grad_sync/stats"):
+            metrics = {
+                "loss": lax.pmean(losses.mean(), data_axis),
+                "pixel_acc": lax.pmean(accs.mean(), data_axis),
+                "grad_norm": grad_norm,
+            }
         new_state = TrainState(
             step=state.step + 1,
             params=params,
@@ -676,9 +687,15 @@ def make_train_step_gspmd(
             # Bucketed spelling so the GSPMD codec loss (per-bucket scales
             # and keys) matches the shard_map layouts bucket-for-bucket;
             # bucket_mb=0 degenerates to the single fenced whole-tree stage.
-            grads = apply_codec_fenced_bucketed(
-                resolve_codec_backend(compression), grads, compression, key=rng
-            )
+            # The partitioner owns the collectives here; the codec is the
+            # part of the gradient sync this program spells out.
+            with jax.named_scope("ddlpc/grad_sync"):
+                grads = apply_codec_fenced_bucketed(
+                    resolve_codec_backend(compression),
+                    grads,
+                    compression,
+                    key=rng,
+                )
         if level in ("zero2", "zero3"):
             # ZeRO-2 the GSPMD way: pin the post-codec mean gradient to the
             # rule-derived shardings, telling the partitioner the
@@ -722,7 +739,7 @@ def make_train_step_gspmd(
         metrics = {
             "loss": losses.mean(),
             "pixel_acc": accs.mean(),
-            "grad_norm": optax.global_norm(grads),
+            "grad_norm": _global_norm(grads),
         }
         new_state = TrainState(
             step=state.step + 1,
@@ -885,7 +902,10 @@ def make_eval_step_gspmd(
             train=False,
         )
         cm = confusion_from_logits(logits, labels, num_classes)
-        nll_sum, count = softmax_cross_entropy_sum(logits, labels, ignore_index=-1)
+        with jax.named_scope("ddlpc/loss"):
+            nll_sum, count = softmax_cross_entropy_sum(
+                logits, labels, ignore_index=-1
+            )
         return {"confusion": cm, "loss_sum": nll_sum, "pixel_count": count}
 
     repl = NamedSharding(mesh, P())
@@ -918,7 +938,10 @@ def make_eval_step(
         # Return summed NLL and valid-pixel count, not a mean: the caller
         # accumulates both across shards AND batches and divides once, so
         # padded shards/tail batches get exactly their valid-pixel weight.
-        nll_sum, count = softmax_cross_entropy_sum(logits, labels, ignore_index=-1)
+        with jax.named_scope("ddlpc/loss"):
+            nll_sum, count = softmax_cross_entropy_sum(
+                logits, labels, ignore_index=-1
+            )
         return {
             "confusion": lax.psum(cm, data_axis),
             "loss_sum": lax.psum(nll_sum, data_axis),

@@ -24,6 +24,7 @@ import numpy as np
 from ddlpc_tpu.analysis import lockcheck
 from ddlpc_tpu.obs.registry import sanitize_name
 from ddlpc_tpu.obs.schema import SCHEMA_VERSION
+from ddlpc_tpu.obs.tracing import NULL_SPAN
 from ddlpc_tpu.utils.fsio import atomic_write_text
 
 # ISPRS-style 6-class palette (imp surface, building, low veg, tree, car,
@@ -147,21 +148,35 @@ class MetricsLogger:
             ).set(float(v))
 
 
+# Every stage also lands on the profiler's clock under this prefix (the
+# benchmark's readers and docs/OBSERVABILITY.md key on it).
+ANNOTATION_PREFIX = "ddlpc:"
+
+
 @lockcheck.guarded
 class StageTimer:
     """Named wall-clock stage timing — the structured form of the
-    reference's scattered ``time.time()`` delta prints (кластер.py:265-440).
-    Accumulates totals; ``summary()`` gives seconds per stage.
+    reference's scattered ``time.time()`` delta prints (кластер.py:265-440)
+    and the ONE way the training process writes a span.  Accumulates
+    totals; ``summary()`` gives seconds per stage.
+
+    Every stage is additionally a ``jax.profiler.TraceAnnotation`` named
+    ``ddlpc:<name>`` — always, whatever ``train.trace`` says: outside a
+    profiler session that is one atomic test; inside one (``maybe_profile``,
+    a SIGUSR2 capture, the benchmark's traced window) the stage lands on
+    the host plane of the same ``.xplane.pb`` as the device ops, on one
+    clock.  ``attrs`` (``epoch=``, ``step=``, ...) ride along as the
+    annotation's arguments.
 
     Thread-safe: the ShardedLoader's producer pool records its
     loader_gather/cast/upload stages from worker threads concurrently with
     the training thread's data/step stages.
 
     ``tracer`` (obs/tracing.py, optional) additionally records every stage
-    as a span — this is how the loader's per-stage hooks reach the unified
-    trace without the loader knowing the tracer exists.  Stages run on
-    producer threads, so spans are recorded with the tracer's explicit
-    cross-thread ``add_span`` (no implicit parent)."""
+    as a span with the same name and ``attrs`` — this is how the loader's
+    per-stage hooks reach ``spans.jsonl`` without the loader knowing the
+    tracer exists.  Spans nest per thread (a stage inside a stage on one
+    thread names its parent); a producer thread's stages are roots."""
 
     def __init__(self, tracer=None):
         self.totals: Dict[str, float] = {}  # guarded-by: _lock
@@ -170,19 +185,22 @@ class StageTimer:
         self._lock = lockcheck.lock("StageTimer._lock")
 
     @contextmanager
-    def stage(self, name: str):
+    def stage(self, name: str, **attrs):
+        tracer = self.tracer
+        span = (
+            tracer.span(name, **attrs)
+            if tracer is not None and tracer.enabled
+            else NULL_SPAN
+        )
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **attrs), span:
+                yield
         finally:
-            t1 = time.perf_counter()
-            dt = t1 - t0
+            dt = time.perf_counter() - t0
             with self._lock:
                 self.totals[name] = self.totals.get(name, 0.0) + dt
                 self.counts[name] = self.counts.get(name, 0) + 1
-            tracer = self.tracer
-            if tracer is not None and tracer.enabled:
-                tracer.add_span(name, t0, t1)
 
     def summary(self) -> Dict[str, float]:
         with self._lock:

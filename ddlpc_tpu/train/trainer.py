@@ -70,6 +70,14 @@ from ddlpc_tpu.train.optim import build_optimizer
 from ddlpc_tpu.train.watchdog import StallWatchdog
 
 
+def _record_keys(stage_seconds: Dict[str, float]) -> Dict[str, float]:
+    """``{stage: seconds}`` as epoch-record keys: ``init/state`` →
+    ``t_init_state_s``."""
+    return {
+        f"t_{name.replace('/', '_')}_s": t for name, t in stage_seconds.items()
+    }
+
+
 class PreemptedRun(Exception):
     """Raised inside the epoch loop when a graceful preemption was
     requested (SIGTERM, :meth:`Trainer.request_preempt`, or a chaos
@@ -123,52 +131,6 @@ class Trainer:
                 "path; device_cache gathers batches on device, so worker "
                 "threads have nothing to do — unset one of them"
             )
-        self.mesh = make_mesh(cfg.parallel)
-        data_size = self.mesh.shape[cfg.parallel.data_axis_name]
-        self.global_micro_batch = cfg.train.micro_batch_size * data_size
-        # Stochastic rounding's benefit is regime-dependent (measured, not
-        # assumed — docs/QUANTIZATION.md round-3 table): at global super-batch
-        # 32 it closes the int8 codec's entire convergence lag, but at the
-        # flagship's 512 it COSTS −0.045 val mIoU vs nearest rounding (the
-        # big batch already averages the rounding error away, so the injected
-        # variance is pure noise).  Warn anyone combining it with a
-        # large-batch operating point.
-        global_super_batch = self.global_micro_batch * cfg.train.sync_period
-        if (
-            cfg.compression.mode != "none"
-            and cfg.compression.rounding == "stochastic"
-            and global_super_batch >= 256
-        ):
-            warnings.warn(
-                f"rounding='stochastic' at global super-batch "
-                f"{global_super_batch} (micro {cfg.train.micro_batch_size} x "
-                f"sync {cfg.train.sync_period} x {data_size} replicas): the "
-                f"committed A/B measured stochastic rounding HELPING at small "
-                f"batch (closes int8's lag at super-batch 32) but COSTING "
-                f"-0.045 val mIoU at super-batch 512 "
-                f"(docs/QUANTIZATION.md round-3 table) — large batches "
-                f"average quantization error away on their own; prefer "
-                f"rounding='nearest' here",
-                stacklevel=2,
-            )
-
-        self.train_ds, self.test_ds = build_dataset(cfg.data)
-        self.model = build_model_from_experiment(cfg)
-        self.spatial = cfg.parallel.space_axis_size > 1
-        space = cfg.parallel.space_axis_name if self.spatial else None
-        # ZeRO sharded-update level (parallel/shard_update.py,
-        # docs/SHARDING.md): resolves to 'off'|'zero1'|'zero2'|'zero3'.
-        # 'auto' picks zero2 for data meshes > 1 unless a codec
-        # combination cannot compose (those fall back to 'off' — explicit
-        # levels raise there instead).
-        self.shard_update = resolve_shard_update(
-            cfg.parallel.shard_update,
-            cfg.compression,
-            data_size,
-            self.spatial,
-            grad_clip_norm=cfg.train.grad_clip_norm,
-        )
-
         # Unified telemetry (ddlpc_tpu/obs, docs/OBSERVABILITY.md): one
         # span tracer + one Prometheus-style registry per training process.
         # The tracer is constructed unconditionally — disabled it is a
@@ -195,280 +157,341 @@ class Trainer:
         # records every stage — including the loop's data/step stages and
         # the loader's per-stage hooks — as spans.
         self.timer = StageTimer(tracer=self.tracer)
-        loader_cls = (
-            DeviceCachedLoader if cfg.data.device_cache else ShardedLoader
-        )
-        # compact composes with BOTH transports: on the ShardedLoader it
-        # shrinks the per-batch wire, on the DeviceCachedLoader it shrinks
-        # the resident cache itself (44% of the fp32 HBM).
-        loader_kw = (
-            {"compact": cfg.data.compact_upload} if cfg.data.device_cache
-            else {"compact": cfg.data.compact_upload,
-                  "workers": cfg.data.loader_workers,
-                  "native_gather": cfg.data.native_gather,
-                  "timer": self.timer}
-        )
-        self.loader = loader_cls(
-            self.train_ds,
-            self.mesh,
-            global_micro_batch=self.global_micro_batch,
-            sync_period=cfg.train.sync_period,
-            shuffle=cfg.data.shuffle,
-            seed=cfg.data.seed,
-            data_axis=cfg.parallel.data_axis_name,
-            space_axis=space,
-            **loader_kw,
-        )
-        # Step horizon for decaying LR schedules comes from the loader (one
-        # source of truth for steps/epoch, including tail semantics).
-        self.tx = build_optimizer(
-            cfg.train, total_steps=cfg.train.epochs * len(self.loader)
-        )
+        # Every phase of construction is a stage (host span ``ddlpc:init/*``,
+        # contiguous from here to the end of __init__): their seconds go
+        # into one kind="init" line and the first epoch record as
+        # t_init_<phase>_s, then the timer starts the loop's accounting clean.
+        with self.timer.stage("init/dataset"):
+            self.mesh = make_mesh(cfg.parallel)
+            data_size = self.mesh.shape[cfg.parallel.data_axis_name]
+            self.global_micro_batch = cfg.train.micro_batch_size * data_size
+            # Stochastic rounding's benefit is regime-dependent (measured, not
+            # assumed — docs/QUANTIZATION.md round-3 table): at global super-batch
+            # 32 it closes the int8 codec's entire convergence lag, but at the
+            # flagship's 512 it COSTS −0.045 val mIoU vs nearest rounding (the
+            # big batch already averages the rounding error away, so the injected
+            # variance is pure noise).  Warn anyone combining it with a
+            # large-batch operating point.
+            global_super_batch = self.global_micro_batch * cfg.train.sync_period
+            if (
+                cfg.compression.mode != "none"
+                and cfg.compression.rounding == "stochastic"
+                and global_super_batch >= 256
+            ):
+                warnings.warn(
+                    f"rounding='stochastic' at global super-batch "
+                    f"{global_super_batch} (micro {cfg.train.micro_batch_size} x "
+                    f"sync {cfg.train.sync_period} x {data_size} replicas): the "
+                    f"committed A/B measured stochastic rounding HELPING at small "
+                    f"batch (closes int8's lag at super-batch 32) but COSTING "
+                    f"-0.045 val mIoU at super-batch 512 "
+                    f"(docs/QUANTIZATION.md round-3 table) — large batches "
+                    f"average quantization error away on their own; prefer "
+                    f"rounding='nearest' here",
+                    stacklevel=2,
+                )
+
+            self.train_ds, self.test_ds = build_dataset(cfg.data)
+            self.model = build_model_from_experiment(cfg)
+            self.spatial = cfg.parallel.space_axis_size > 1
+            space = cfg.parallel.space_axis_name if self.spatial else None
+            # ZeRO sharded-update level (parallel/shard_update.py,
+            # docs/SHARDING.md): resolves to 'off'|'zero1'|'zero2'|'zero3'.
+            # 'auto' picks zero2 for data meshes > 1 unless a codec
+            # combination cannot compose (those fall back to 'off' — explicit
+            # levels raise there instead).
+            self.shard_update = resolve_shard_update(
+                cfg.parallel.shard_update,
+                cfg.compression,
+                data_size,
+                self.spatial,
+                grad_clip_norm=cfg.train.grad_clip_norm,
+            )
+
+        with self.timer.stage("init/loader"):
+            loader_cls = (
+                DeviceCachedLoader if cfg.data.device_cache else ShardedLoader
+            )
+            # compact composes with BOTH transports: on the ShardedLoader it
+            # shrinks the per-batch wire, on the DeviceCachedLoader it shrinks
+            # the resident cache itself (44% of the fp32 HBM).
+            loader_kw = (
+                {"compact": cfg.data.compact_upload} if cfg.data.device_cache
+                else {"compact": cfg.data.compact_upload,
+                      "workers": cfg.data.loader_workers,
+                      "native_gather": cfg.data.native_gather,
+                      "timer": self.timer}
+            )
+            self.loader = loader_cls(
+                self.train_ds,
+                self.mesh,
+                global_micro_batch=self.global_micro_batch,
+                sync_period=cfg.train.sync_period,
+                shuffle=cfg.data.shuffle,
+                seed=cfg.data.seed,
+                data_axis=cfg.parallel.data_axis_name,
+                space_axis=space,
+                **loader_kw,
+            )
+            # Step horizon for decaying LR schedules comes from the loader (one
+            # source of truth for steps/epoch, including tail semantics).
+            self.tx = build_optimizer(
+                cfg.train, total_steps=cfg.train.epochs * len(self.loader)
+            )
 
         h, w = cfg.data.image_size
         channels = self.train_ds.image_shape[-1]
-        self.state = create_train_state(
-            self.model,
-            self.tx,
-            jax.random.key(cfg.train.seed),
-            (1, h, w, channels),
-        )
-        # Run layout: replicated, or — under the sharded update — the
-        # level's persistent shards: Adam moments chunked 1/N (zero1/2/3),
-        # plus the params themselves under zero3; the GSPMD path expresses
-        # the same placements as NamedShardings (gspmd/gspmd_zero2/
-        # gspmd_zero3).  ``layout`` converts both ways; checkpoints and
-        # multi-host broadcasts always move the canonical (gathered)
-        # layout, so on-disk state is layout-independent.
-        layout_mode = (
-            "replicated"
-            if self.shard_update == "off"
-            else (
-                GSPMD_LAYOUT_FOR_LEVEL[self.shard_update]
-                if self.spatial
-                else self.shard_update
-            )
-        )
-        self.layout = StateLayout(
-            layout_mode,
-            self.tx,
-            self.state,
-            self.mesh,
-            cfg.parallel.data_axis_name,
-        )
-        self.state = self.layout.place(self.state)
-
-        # Pure data mesh → hand-written shard_map collectives (reference-
-        # parity codec semantics); data×space mesh → GSPMD, where XLA
-        # partitions convs along H with automatic halo exchange.
-        self.train_step = self._build_train_step()
-        if self.spatial:
-            self.eval_step = make_eval_step_gspmd(
+        with self.timer.stage("init/state"):
+            self.state = create_train_state(
                 self.model,
-                self.mesh,
-                num_classes=cfg.model.num_classes,
-                data_axis=cfg.parallel.data_axis_name,
-                space_axis=space,
+                self.tx,
+                jax.random.key(cfg.train.seed),
+                (1, h, w, channels),
             )
-        else:
-            self.eval_step = make_eval_step(
-                self.model,
-                self.mesh,
-                num_classes=cfg.model.num_classes,
-                data_axis=cfg.parallel.data_axis_name,
-            )
-        self.predict = make_predict_fn(self.model)
-
-        # Performance accounting (docs/PERF.md "Accounting"): a per-step
-        # conv FLOP model traced once (no compute), live MFU/goodput and
-        # per-device HBM gauges, and exact per-collective comm byte
-        # counters for the configured codec/transport.  The comm-time
-        # probe (a compiled sync-only program) is built lazily and sampled
-        # at most once per epoch on the trace_sync cadence.
-        self.perf: Optional[obs_flops.PerfAccountant] = None
-        self.comm: Optional[obs_comm.CommAccountant] = None
-        self._comm_probe = None
-        self._comm_probed_epoch = False
-        if cfg.train.perf_accounting:
-            try:
-                flops_per_step = obs_flops.conv_step_flops(
-                    cfg, cfg.train.micro_batch_size, cfg.train.sync_period,
-                    channels=channels,
+            # Run layout: replicated, or — under the sharded update — the
+            # level's persistent shards: Adam moments chunked 1/N (zero1/2/3),
+            # plus the params themselves under zero3; the GSPMD path expresses
+            # the same placements as NamedShardings (gspmd/gspmd_zero2/
+            # gspmd_zero3).  ``layout`` converts both ways; checkpoints and
+            # multi-host broadcasts always move the canonical (gathered)
+            # layout, so on-disk state is layout-independent.
+            layout_mode = (
+                "replicated"
+                if self.shard_update == "off"
+                else (
+                    GSPMD_LAYOUT_FOR_LEVEL[self.shard_update]
+                    if self.spatial
+                    else self.shard_update
                 )
-                if self.spatial:
-                    # The trace is the UNPARTITIONED per-micro-batch
-                    # program; under H-sharding each device executes
-                    # ~1/space of those convs (halo recompute ignored —
-                    # a few rows per conv).  Without this, spatial MFU
-                    # overstates by space_axis_size.
-                    flops_per_step //= cfg.parallel.space_axis_size
-            except Exception as e:  # accounting must never kill the run
-                warnings.warn(
-                    f"per-step FLOP model unavailable ({type(e).__name__}: "
-                    f"{e}); ddlpc_mfu will read 0",
-                    stacklevel=2,
-                )
-                flops_per_step = 0
-            peak, assumed = obs_flops.resolve_peak_flops(
-                cfg.train.peak_flops_per_device
             )
-            self.perf = obs_flops.PerfAccountant(
-                self.registry,
-                flops_per_step=flops_per_step,
-                peak_flops=peak,
-                peak_assumed=assumed,
-                # Downtime inherited from a previous supervised attempt
-                # (breadcrumb / resilience.jsonl) — read BEFORE this run's
-                # first breadcrumb write, debited as category 'restart'.
-                restart_gap_s=obs_flops.restart_gap_seconds(cfg.workdir),
-            )
-            obs_hbm.publish_hbm_gauges(
-                self.registry,
+            self.layout = StateLayout(
+                layout_mode,
+                self.tx,
                 self.state,
-                level=self.shard_update,
-                n_shards=data_size,
-                replicated_by_rule=self.layout.replicated_by_rule_bytes(),
+                self.mesh,
+                cfg.parallel.data_axis_name,
             )
-            if cfg.compression.transport == "ring" and cfg.compression.mode != "none":
-                variant = "ring"
-            elif self.spatial:
-                variant = "gspmd"
-            elif self.shard_update == "zero2":
-                variant = "scatter"
-            elif self.shard_update in ("zero1", "zero3"):
-                variant = self.shard_update
-            else:
-                variant = "allreduce"
-            # Canonical (unchunked) parameter shapes: under zero3 the
-            # placed params are [N, K] chunks, but the wire accounting
-            # and the probe model the sync over the logical grads.
-            canonical_params = self.layout.param_avals
-            n_params = obs_comm.tree_elements(canonical_params)
-            from ddlpc_tpu.parallel.grad_sync import grad_bucket_groups
+            self.state = self.layout.place(self.state)
 
-            n_buckets = len(
-                grad_bucket_groups(
-                    canonical_params, cfg.compression.bucket_mb
-                )
-            )
-            self.comm = obs_comm.CommAccountant(
-                self.registry,
-                obs_comm.comm_plan(
-                    n_params,
-                    n_params,
-                    cfg.compression,
-                    data_size,
-                    variant,
-                    n_buckets=n_buckets,
-                ),
-                variant,
-            )
-            if not self.spatial and data_size > 1:
-                # Shape-only closure: the probe must not pin the initial
-                # (donated) param buffers alive.
-                param_shapes = jax.tree.map(
-                    lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype),
-                    canonical_params,
-                )
-                self._comm_probe = obs_comm.make_comm_probe(
+        with self.timer.stage("init/steps"):
+            # Pure data mesh → hand-written shard_map collectives (reference-
+            # parity codec semantics); data×space mesh → GSPMD, where XLA
+            # partitions convs along H with automatic halo exchange.
+            self.train_step = self._build_train_step()
+            if self.spatial:
+                self.eval_step = make_eval_step_gspmd(
+                    self.model,
                     self.mesh,
-                    cfg.compression,
-                    param_shapes,
+                    num_classes=cfg.model.num_classes,
                     data_axis=cfg.parallel.data_axis_name,
-                    scatter=self.shard_update in ("zero2", "zero3"),
-                    seed=cfg.train.seed,
+                    space_axis=space,
                 )
+            else:
+                self.eval_step = make_eval_step(
+                    self.model,
+                    self.mesh,
+                    num_classes=cfg.model.num_classes,
+                    data_axis=cfg.parallel.data_axis_name,
+                )
+            self.predict = make_predict_fn(self.model)
 
-        self.workdir = cfg.workdir
-        self.ckpt_dir = os.path.join(self.workdir, "checkpoints")
-        self.start_epoch = 0
-        # Preemption-graceful shutdown state (docs/RESILIENCE.md): SIGTERM
-        # (or request_preempt(), or a chaos preempt fault) sets the event;
-        # the step loop finishes the in-flight step, then fit() writes an
-        # emergency checkpoint recording the mid-epoch position and the
-        # process exits with EXIT_PREEMPTED.  ``preempted`` is the flag
-        # __main__ maps to that exit status.
-        self._preempt = threading.Event()
-        self._preempt_done = threading.Event()
-        self._grace_timer: Optional[threading.Timer] = None
-        self.preempted = False
-        # Mid-epoch resume: the restore below may find an emergency
-        # checkpoint taken ``mid_epoch_steps_done`` steps into an epoch —
-        # train_epoch then draws-and-discards exactly that many batches
-        # (the loader is epoch-seeded and deterministic), so the resumed
-        # trajectory is bit-identical to an uninterrupted run's.
-        self._skip_steps = 0
-        self._skip_epoch = -1
-        # Chaos fault injection (resilience/chaos.py): None unless the
-        # DDLPC_CHAOS env var schedules faults; the step counter is
-        # process-lifetime, matching the schedule's step semantics.
-        self._chaos = _chaos_mod.active()
-        self._chaos_step = 0
-        # Lineage of the checkpoint this run resumed from (None on a cold
-        # start; the explicit unknown marker on pre-lineage checkpoints).
-        self.restored_lineage: Optional[dict] = None
-        if resume:
-            self._restore_synchronized()
-        self.logger = MetricsLogger(
-            self.workdir,
-            run_config_json=cfg.to_json(),
-            registry=self.registry,
-        )
-        # Failure detection (SURVEY §5: the reference has none and hangs
-        # forever on a dead peer).  Armed by fit(); beats come from the
-        # epoch loop's data/step stages.
-        self.watchdog = StallWatchdog(
-            timeout_s=cfg.train.stall_timeout_s,
-            action=cfg.train.stall_action,
-            log_path=os.path.join(self.workdir, "stall.log"),
-            # Last breadcrumb before an abort(42): the supervisor reads it
-            # to classify the exit even if stderr was lost.
-            on_stall=lambda age, tag: (
-                write_breadcrumb(
-                    self.workdir, "stalled", stall_age_s=age, stall_tag=tag
+        with self.timer.stage("init/accounting"):
+            # Performance accounting (docs/PERF.md "Accounting"): a per-step
+            # conv FLOP model traced once (no compute), live MFU/goodput and
+            # per-device HBM gauges, and exact per-collective comm byte
+            # counters for the configured codec/transport.  The comm-time
+            # probe (a compiled sync-only program) is built lazily and sampled
+            # at most once per epoch on the trace_sync cadence.
+            self.perf: Optional[obs_flops.PerfAccountant] = None
+            self.comm: Optional[obs_comm.CommAccountant] = None
+            self._comm_probe = None
+            self._comm_probed_epoch = False
+            if cfg.train.perf_accounting:
+                try:
+                    flops_per_step = obs_flops.conv_step_flops(
+                        cfg, cfg.train.micro_batch_size, cfg.train.sync_period,
+                        channels=channels,
+                    )
+                    if self.spatial:
+                        # The trace is the UNPARTITIONED per-micro-batch
+                        # program; under H-sharding each device executes
+                        # ~1/space of those convs (halo recompute ignored —
+                        # a few rows per conv).  Without this, spatial MFU
+                        # overstates by space_axis_size.
+                        flops_per_step //= cfg.parallel.space_axis_size
+                except Exception as e:  # accounting must never kill the run
+                    warnings.warn(
+                        f"per-step FLOP model unavailable ({type(e).__name__}: "
+                        f"{e}); ddlpc_mfu will read 0",
+                        stacklevel=2,
+                    )
+                    flops_per_step = 0
+                peak, assumed = obs_flops.resolve_peak_flops(
+                    cfg.train.peak_flops_per_device
                 )
-                if jax.process_index() == 0
-                else None
-            ),
-        )
-        # Health detectors (obs/health.py): EWMA step-time regression and
-        # loss NaN/spike alerts, fed per epoch record, fanning out to the
-        # JSONL stream, the registry, and the watchdog's diagnosis ring.
-        self.health = HealthMonitor(
-            logger=self.logger,
-            registry=self.registry,
-            watchdog=self.watchdog,
-            service="train",
-        )
-        # On-demand profiling (obs/profiling.py): armed by SIGUSR2 (fit
-        # installs the handler) or GET /debug/trace on the telemetry
-        # endpoint; the step loop drives the capture over the next N steps
-        # and the top-ops report lands in the workdir.
-        self.profiler = OnDemandProfiler(
-            out_dir=self.workdir,
-            steps=cfg.train.profile_steps,
-            logger=self.logger,
-            enabled=jax.process_index() == 0,
-        )
-        self.telemetry: Optional[TelemetryServer] = None
-        if cfg.train.telemetry_port >= 0 and jax.process_index() == 0:
-            self.telemetry = TelemetryServer(
-                self.registry,
-                port=cfg.train.telemetry_port,
-                health_fn=self._health_snapshot,
-                arm_profile_fn=self._arm_profile,
-            ).start()
-        # Async by default: save() pays only the host snapshot; the chunk/
-        # compress/fsync chain overlaps the next epoch's compute on a
-        # writer thread, with a barrier (and error re-raise) on the next
-        # save and at the end of fit() (train/async_checkpoint.py).
-        self.checkpointer = AsyncCheckpointer(
-            keep=cfg.train.keep_checkpoints,
-            format=cfg.train.checkpoint_format,
-            chunk_bytes=max(1, cfg.train.checkpoint_chunk_mb) << 20,
-            compression=cfg.train.checkpoint_compression,
-            background=cfg.train.checkpoint_async,
-        )
+                self.perf = obs_flops.PerfAccountant(
+                    self.registry,
+                    flops_per_step=flops_per_step,
+                    peak_flops=peak,
+                    peak_assumed=assumed,
+                    # Downtime inherited from a previous supervised attempt
+                    # (breadcrumb / resilience.jsonl) — read BEFORE this run's
+                    # first breadcrumb write, debited as category 'restart'.
+                    restart_gap_s=obs_flops.restart_gap_seconds(cfg.workdir),
+                )
+                obs_hbm.publish_hbm_gauges(
+                    self.registry,
+                    self.state,
+                    level=self.shard_update,
+                    n_shards=data_size,
+                    replicated_by_rule=self.layout.replicated_by_rule_bytes(),
+                )
+                if cfg.compression.transport == "ring" and cfg.compression.mode != "none":
+                    variant = "ring"
+                elif self.spatial:
+                    variant = "gspmd"
+                elif self.shard_update == "zero2":
+                    variant = "scatter"
+                elif self.shard_update in ("zero1", "zero3"):
+                    variant = self.shard_update
+                else:
+                    variant = "allreduce"
+                # Canonical (unchunked) parameter shapes: under zero3 the
+                # placed params are [N, K] chunks, but the wire accounting
+                # and the probe model the sync over the logical grads.
+                canonical_params = self.layout.param_avals
+                n_params = obs_comm.tree_elements(canonical_params)
+                from ddlpc_tpu.parallel.grad_sync import grad_bucket_groups
+
+                n_buckets = len(
+                    grad_bucket_groups(
+                        canonical_params, cfg.compression.bucket_mb
+                    )
+                )
+                self.comm = obs_comm.CommAccountant(
+                    self.registry,
+                    obs_comm.comm_plan(
+                        n_params,
+                        n_params,
+                        cfg.compression,
+                        data_size,
+                        variant,
+                        n_buckets=n_buckets,
+                    ),
+                    variant,
+                )
+                if not self.spatial and data_size > 1:
+                    # Shape-only closure: the probe must not pin the initial
+                    # (donated) param buffers alive.
+                    param_shapes = jax.tree.map(
+                        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype),
+                        canonical_params,
+                    )
+                    self._comm_probe = obs_comm.make_comm_probe(
+                        self.mesh,
+                        cfg.compression,
+                        param_shapes,
+                        data_axis=cfg.parallel.data_axis_name,
+                        scatter=self.shard_update in ("zero2", "zero3"),
+                        seed=cfg.train.seed,
+                    )
+
+        with self.timer.stage("init/restore"):
+            self.workdir = cfg.workdir
+            self.ckpt_dir = os.path.join(self.workdir, "checkpoints")
+            self.start_epoch = 0
+            # Preemption-graceful shutdown state (docs/RESILIENCE.md): SIGTERM
+            # (or request_preempt(), or a chaos preempt fault) sets the event;
+            # the step loop finishes the in-flight step, then fit() writes an
+            # emergency checkpoint recording the mid-epoch position and the
+            # process exits with EXIT_PREEMPTED.  ``preempted`` is the flag
+            # __main__ maps to that exit status.
+            self._preempt = threading.Event()
+            self._preempt_done = threading.Event()
+            self._grace_timer: Optional[threading.Timer] = None
+            self.preempted = False
+            # Mid-epoch resume: the restore below may find an emergency
+            # checkpoint taken ``mid_epoch_steps_done`` steps into an epoch —
+            # train_epoch then draws-and-discards exactly that many batches
+            # (the loader is epoch-seeded and deterministic), so the resumed
+            # trajectory is bit-identical to an uninterrupted run's.
+            self._skip_steps = 0
+            self._skip_epoch = -1
+            # Chaos fault injection (resilience/chaos.py): None unless the
+            # DDLPC_CHAOS env var schedules faults; the step counter is
+            # process-lifetime, matching the schedule's step semantics.
+            self._chaos = _chaos_mod.active()
+            self._chaos_step = 0
+            # Lineage of the checkpoint this run resumed from (None on a cold
+            # start; the explicit unknown marker on pre-lineage checkpoints).
+            self.restored_lineage: Optional[dict] = None
+            if resume:
+                self._restore_synchronized()
+
+        with self.timer.stage("init/services"):
+            self.logger = MetricsLogger(
+                self.workdir,
+                run_config_json=cfg.to_json(),
+                registry=self.registry,
+            )
+            # Failure detection (SURVEY §5: the reference has none and hangs
+            # forever on a dead peer).  Armed by fit(); beats come from the
+            # epoch loop's data/step stages.
+            self.watchdog = StallWatchdog(
+                timeout_s=cfg.train.stall_timeout_s,
+                action=cfg.train.stall_action,
+                log_path=os.path.join(self.workdir, "stall.log"),
+                # Last breadcrumb before an abort(42): the supervisor reads it
+                # to classify the exit even if stderr was lost.
+                on_stall=lambda age, tag: (
+                    write_breadcrumb(
+                        self.workdir, "stalled", stall_age_s=age, stall_tag=tag
+                    )
+                    if jax.process_index() == 0
+                    else None
+                ),
+            )
+            # Health detectors (obs/health.py): EWMA step-time regression and
+            # loss NaN/spike alerts, fed per epoch record, fanning out to the
+            # JSONL stream, the registry, and the watchdog's diagnosis ring.
+            self.health = HealthMonitor(
+                logger=self.logger,
+                registry=self.registry,
+                watchdog=self.watchdog,
+                service="train",
+            )
+            # On-demand profiling (obs/profiling.py): armed by SIGUSR2 (fit
+            # installs the handler) or GET /debug/trace on the telemetry
+            # endpoint; the step loop drives the capture over the next N steps
+            # and the top-ops report lands in the workdir.
+            self.profiler = OnDemandProfiler(
+                out_dir=self.workdir,
+                steps=cfg.train.profile_steps,
+                logger=self.logger,
+                enabled=jax.process_index() == 0,
+            )
+            self.telemetry: Optional[TelemetryServer] = None
+            if cfg.train.telemetry_port >= 0 and jax.process_index() == 0:
+                self.telemetry = TelemetryServer(
+                    self.registry,
+                    port=cfg.train.telemetry_port,
+                    health_fn=self._health_snapshot,
+                    arm_profile_fn=self._arm_profile,
+                ).start()
+            # Async by default: save() pays only the host snapshot; the chunk/
+            # compress/fsync chain overlaps the next epoch's compute on a
+            # writer thread, with a barrier (and error re-raise) on the next
+            # save and at the end of fit() (train/async_checkpoint.py).
+            self.checkpointer = AsyncCheckpointer(
+                keep=cfg.train.keep_checkpoints,
+                format=cfg.train.checkpoint_format,
+                chunk_bytes=max(1, cfg.train.checkpoint_chunk_mb) << 20,
+                compression=cfg.train.checkpoint_compression,
+                background=cfg.train.checkpoint_async,
+            )
+        self._init_times = _record_keys(self.timer.summary())
+        self.timer.reset()
+        self.logger.log({"kind": "init", **self._init_times}, echo=False)
 
     def _health_snapshot(self) -> dict:
         return {
@@ -714,26 +737,33 @@ class Trainer:
     # ------------------------------------------------------------------
 
     def train_epoch(self, epoch: int) -> Dict[str, float]:
-        self.loader.set_epoch(epoch)
-        self._comm_probed_epoch = False
-        losses, accs = [], []
+        # Every host phase from here to the record is a stage
+        # (StageTimer.stage: a span on the profiler's clock, a t_<stage>_s
+        # mean in the record, a Tracer span when train.trace is on), so a
+        # device trace can say which of them the chip waited for.
+        stage = self.timer.stage
         t_epoch = time.perf_counter()
-        it = iter(self.loader)
-        step_idx = 0
-        skipped = 0
-        if self._skip_steps and epoch == self._skip_epoch:
-            # Skip-replay resume from an emergency (mid-epoch) checkpoint:
-            # the restored state already contains these optimizer steps, so
-            # draw-and-discard the same deterministic batches the
-            # interrupted run consumed.  Costs host gather only — no
-            # compute — and keeps the resumed trajectory bit-identical to
-            # an uninterrupted run's (tests/test_preemption.py pins it).
-            for _ in range(self._skip_steps):
-                self.watchdog.beat("resume_skip")
-                if next(it, None) is None:
-                    break
-                skipped += 1
-            self._skip_steps = 0
+        with stage("epoch_head", epoch=epoch):
+            self.loader.set_epoch(epoch)
+            self._comm_probed_epoch = False
+            losses, accs = [], []
+            it = iter(self.loader)
+            step_idx = 0
+            skipped = 0
+            if self._skip_steps and epoch == self._skip_epoch:
+                # Skip-replay resume from an emergency (mid-epoch)
+                # checkpoint: the restored state already contains these
+                # optimizer steps, so draw-and-discard the same
+                # deterministic batches the interrupted run consumed.
+                # Costs host gather only — no compute — and keeps the
+                # resumed trajectory bit-identical to an uninterrupted
+                # run's (tests/test_preemption.py pins it).
+                for _ in range(self._skip_steps):
+                    self.watchdog.beat("resume_skip")
+                    if next(it, None) is None:
+                        break
+                    skipped += 1
+                self._skip_steps = 0
         sync_every = self.cfg.train.trace_sync_every_steps
         while True:
             # Stage-resolved timing: the structured version of the
@@ -744,12 +774,12 @@ class Trainer:
             if self._chaos is not None:
                 self._chaos.on_data_fetch()
             self.watchdog.beat("data")
-            with self.timer.stage("data"):
+            with stage("data", epoch=epoch, step=step_idx):
                 batch = next(it, None)
             if batch is None:
                 break
             self.watchdog.beat("step")
-            with self.timer.stage("step"):
+            with stage("step", epoch=epoch, step=step_idx):
                 self.state, metrics = self.train_step(self.state, *batch)
             losses.append(metrics["loss"])
             accs.append(metrics["pixel_acc"])
@@ -774,7 +804,7 @@ class Trainer:
             # — syncing every step would serialize the async dispatch
             # pipeline and measure a run that doesn't exist.
             if self.tracer.enabled and sync_every and step_idx % sync_every == 0:
-                with self.tracer.span("step_sync", epoch=epoch, step=step_idx):
+                with stage("step_sync", epoch=epoch, step=step_idx):
                     jax.block_until_ready(metrics["loss"])
                 # Sampled fenced comm-time measurement, piggybacking on
                 # the sync cadence (the pipeline is already drained here,
@@ -785,7 +815,7 @@ class Trainer:
                     self._comm_probed_epoch = True
                     t_probe = time.perf_counter()
                     try:
-                        with self.tracer.span("comm_probe", epoch=epoch):
+                        with stage("comm_probe", epoch=epoch):
                             self.comm.record_probe(self._comm_probe())
                     except Exception as e:  # accounting never kills the run
                         warnings.warn(
@@ -817,50 +847,57 @@ class Trainer:
                 f"{self.loader.super_batch} — the loader yielded no batches"
             )
         self.watchdog.beat("epoch_metrics_fetch")
-        losses, accs = jax.device_get((losses, accs))
-        losses = [float(l) for l in losses]
-        accs = [float(a) for a in accs]
-        epoch_time = time.perf_counter() - t_epoch
-        steps = len(losses)
-        record = {
-            "epoch": epoch,
-            "loss": float(np.mean(losses)),
-            "pixel_acc": float(np.mean(accs)),
-            "epoch_time_s": epoch_time,
-            # Mean time per sync step — the reference's "среднее время на
-            # батч" line (кластер.py:767-770).
-            "step_time_s": epoch_time / steps,
-            # Compute throughput: tile-instances processed (wrap-fill
-            # duplicates included — they are real forward/backward work).
-            # ``steps`` not len(loader): a skip-replay resume computes only
-            # the remaining steps of its first epoch.
-            "tiles_per_s": steps * self.loader.super_batch / epoch_time,
-        }
-        if skipped:
-            # Flag the partial epoch: its loss/acc means cover only the
-            # post-resume steps (the state is still exact — the skipped
-            # steps were already applied before the preemption).
-            record["resumed_mid_epoch_at_step"] = skipped
-        # When the super-batch exceeds the dataset, an "epoch" processes each
-        # tile wrap_factor times — record it so tiles_per_s cannot read as
-        # dataset coverage (VERDICT r2: flagship super-batch 2048 vs 97 tiles
-        # counts each tile ~21x per epoch).
-        wrap = len(self.loader) * self.loader.super_batch / max(len(self.train_ds), 1)
-        if wrap > 1.0 + 1e-9:
-            record["wrap_fill_factor"] = round(wrap, 2)
-        record.update(
-            {f"t_{name}_s": t for name, t in self.timer.means().items()}
-        )
-        if self.perf is not None:
-            # Goodput accounting from the epoch's disjoint training-thread
-            # intervals: the compiled step dispatch is productive, the
-            # host wait for the next super-batch is a 'data' debit
-            # (loader_gather/cast/upload run on producer threads and
-            # overlap the step — they are throughput, not wall debits).
-            totals = self.timer.summary()
-            self.perf.productive(totals.get("step", 0.0), steps)
-            self.perf.debit("data", totals.get("data", 0.0))
-        self.timer.reset()
+        with stage("metrics_fetch", epoch=epoch):
+            losses, accs = jax.device_get((losses, accs))
+        with stage("epoch_tail", epoch=epoch):
+            losses = [float(l) for l in losses]
+            accs = [float(a) for a in accs]
+            epoch_time = time.perf_counter() - t_epoch
+            steps = len(losses)
+            record = {
+                "epoch": epoch,
+                "loss": float(np.mean(losses)),
+                "pixel_acc": float(np.mean(accs)),
+                "epoch_time_s": epoch_time,
+                # Mean time per sync step — the reference's "среднее время
+                # на батч" line (кластер.py:767-770).
+                "step_time_s": epoch_time / steps,
+                # Compute throughput: tile-instances processed (wrap-fill
+                # duplicates included — they are real forward/backward
+                # work).  ``steps`` not len(loader): a skip-replay resume
+                # computes only the remaining steps of its first epoch.
+                "tiles_per_s": steps * self.loader.super_batch / epoch_time,
+            }
+            if skipped:
+                # Flag the partial epoch: its loss/acc means cover only the
+                # post-resume steps (the state is still exact — the skipped
+                # steps were already applied before the preemption).
+                record["resumed_mid_epoch_at_step"] = skipped
+            # When the super-batch exceeds the dataset, an "epoch" processes
+            # each tile wrap_factor times — record it so tiles_per_s cannot
+            # read as dataset coverage (VERDICT r2: flagship super-batch
+            # 2048 vs 97 tiles counts each tile ~21x per epoch).
+            wrap = len(self.loader) * self.loader.super_batch / max(len(self.train_ds), 1)
+            if wrap > 1.0 + 1e-9:
+                record["wrap_fill_factor"] = round(wrap, 2)
+            # Stage means since the last record.  Stages that close after
+            # this line — epoch_tail itself and fit()'s epoch, log,
+            # perf_publish, evaluate, checkpoint, dump stages — land in the
+            # NEXT epoch's record (docs/OBSERVABILITY.md).
+            record.update(_record_keys(self.timer.means()))
+            # Construction's phases, once in the Trainer's life.
+            record.update(self._init_times)
+            self._init_times = {}
+            if self.perf is not None:
+                # Goodput accounting from the epoch's disjoint training-thread
+                # intervals: the compiled step dispatch is productive, the
+                # host wait for the next super-batch is a 'data' debit
+                # (loader_gather/cast/upload run on producer threads and
+                # overlap the step — they are throughput, not wall debits).
+                totals = self.timer.summary()
+                self.perf.productive(totals.get("step", 0.0), steps)
+                self.perf.debit("data", totals.get("data", 0.0))
+            self.timer.reset()
         return record
 
     def evaluate(self) -> Dict[str, float]:
@@ -970,7 +1007,7 @@ class Trainer:
         lin = obs_lineage.make_lineage(
             step, run_id=self.run_id, config_hash_hex=self.config_hash
         )
-        with self.tracer.span(
+        with self.timer.stage(
             "checkpoint_snapshot",
             epoch=epoch,
             lineage_id=lin["lineage_id"],
@@ -1015,6 +1052,7 @@ class Trainer:
             )
             self.train_step = self._build_train_step()
         record: Dict[str, float] = {}
+        stage = self.timer.stage
         # SIGUSR2 → arm the on-demand profiler (kill -USR2 <pid> against a
         # live run; the next profile_steps steps are captured and
         # aggregated).  Installable only from the main thread — tests and
@@ -1060,7 +1098,7 @@ class Trainer:
                             # Preemption arrived between epochs (or during
                             # the post-epoch eval/checkpoint/dump phases).
                             raise PreemptedRun(epoch, 0)
-                        with self.tracer.span("epoch", epoch=epoch):
+                        with stage("epoch", epoch=epoch):
                             with maybe_profile(
                                 os.path.join(self.workdir, "profile"),
                                 enabled=epoch == cfg.profile_epoch,
@@ -1070,7 +1108,7 @@ class Trainer:
                             # evaluate() beats per batch; per-batch eval cost is
                             # step-like, so the step-sized timeout applies.
                             t_eval = time.perf_counter()
-                            with self.tracer.span("evaluate", epoch=epoch):
+                            with stage("evaluate", epoch=epoch):
                                 record.update(self.evaluate())
                             if self.perf is not None:
                                 self.perf.debit(
@@ -1080,9 +1118,10 @@ class Trainer:
                             # nan@N fault: poison what the health detectors
                             # see (the stream logs the same poisoned value).
                             record = self._chaos.corrupt_record(record)
-                        self.logger.log(record)
-                        # Health detectors see exactly what the stream saw.
-                        self.health.observe_train(record)
+                        with stage("log", epoch=epoch):
+                            self.logger.log(record)
+                            # Health detectors see exactly what the stream saw.
+                            self.health.observe_train(record)
                         if cfg.checkpoint_every_epochs and (
                             epoch + 1
                         ) % cfg.checkpoint_every_epochs == 0:
@@ -1105,21 +1144,26 @@ class Trainer:
                             # Refresh ddlpc_mfu/ddlpc_goodput and append the
                             # flat kind="perf"/"comm" accounting records
                             # (scripts/perf_report.py renders these).
-                            self.logger.log(
-                                self.perf.publish(
-                                    step_time_s=record.get("step_time_s")
-                                ),
-                                echo=False,
-                            )
-                            if self.comm is not None:
+                            with stage("perf_publish", epoch=epoch):
                                 self.logger.log(
-                                    self.comm.publish(
+                                    self.perf.publish(
                                         step_time_s=record.get("step_time_s")
                                     ),
                                     echo=False,
                                 )
+                                if self.comm is not None:
+                                    self.logger.log(
+                                        self.comm.publish(
+                                            step_time_s=record.get(
+                                                "step_time_s"
+                                            )
+                                        ),
+                                        echo=False,
+                                    )
                         if cfg.dump_images_per_epoch:
-                            with self.watchdog.paused("image_dump"):
+                            with self.watchdog.paused("image_dump"), stage(
+                                "dump_images", epoch=epoch
+                            ):
                                 self.dump_images(epoch)
                     else:
                         if jax.process_index() == 0:
@@ -1136,7 +1180,7 @@ class Trainer:
                     # thread per Trainer otherwise); a later save()/fit() on
                     # this Trainer transparently respawns it.
                     with self.watchdog.paused("checkpoint_flush"):
-                        with self.tracer.span("checkpoint_barrier"):
+                        with stage("checkpoint_barrier"):
                             self.checkpointer.close()
         finally:
             if prev_handler is not None:

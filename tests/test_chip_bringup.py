@@ -43,28 +43,63 @@ def bench():
 # ---- compile cache ---------------------------------------------------------
 
 
-def test_compile_cache_placed_from_outside_is_left_alone(monkeypatch):
+KEY_FLAGS = (
+    "jax_compilation_cache_include_metadata_in_key",
+    "jax_hlo_source_file_canonicalization_regex",
+)
+
+
+@pytest.fixture
+def cache_config():
+    """Put back what enable_compile_cache() sets: later tests of this
+    worker lower programs and read their source paths."""
+    names = ("jax_compilation_cache_dir",) + KEY_FLAGS
+    before = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in before.items():
+        jax.config.update(n, v)
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(monkeypatch, cache_config):
     monkeypatch.setenv(compile_cache.ENV_VAR, "/x")
-
-    def no_update(*a, **kw):
-        raise AssertionError("the code must set no cache dir of its own")
-
-    monkeypatch.setattr(jax.config, "update", no_update)
-    assert compile_cache.enable_compile_cache() == "/x"
-
-
-def test_compile_cache_default_is_the_fixed_checkout_path(monkeypatch):
-    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
     before = jax.config.jax_compilation_cache_dir
-    try:
-        got = compile_cache.enable_compile_cache()
-        assert got == os.path.join(REPO, ".jax_cache")
-        assert jax.config.jax_compilation_cache_dir == got
-        # Fixed: the directory is part of the cache key, so a second
-        # process must name the same one.
-        assert compile_cache.enable_compile_cache() == got
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+    assert compile_cache.enable_compile_cache() == "/x"
+    # the code sets no cache dir of its own
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_the_fixed_checkout_path(monkeypatch, cache_config):
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    got = compile_cache.enable_compile_cache()
+    assert got == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == got
+    # Fixed: the directory is part of the cache key, so a second
+    # process must name the same one.
+    assert compile_cache.enable_compile_cache() == got
+
+
+@pytest.mark.parametrize("placed", ["/x", None])
+def test_compile_cache_key_follows_scope_names_not_the_checkout(
+    monkeypatch, cache_config, placed
+):
+    """A program whose named scopes changed must not load the executable
+    compiled before (its op_names are what profiles are read by), and a
+    checkout at another path must still hit: metadata is in the key, the
+    checkout's prefix is taken out of the source paths in it."""
+    if placed:
+        monkeypatch.setenv(compile_cache.ENV_VAR, placed)
+    else:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    compile_cache.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+
+    def f(x):
+        with jax.named_scope("ddlpc/probe"):
+            return x + 1
+
+    lowered = jax.jit(f).lower(jax.numpy.zeros(2)).as_text(debug_info=True)
+    assert "ddlpc/probe" in lowered
+    assert REPO + os.sep not in lowered and "tests/test_chip_bringup.py" in lowered
 
 
 def test_every_entry_point_enables_the_cache(chip_smoke, bench):
@@ -81,7 +116,7 @@ def test_every_entry_point_enables_the_cache(chip_smoke, bench):
 # ---- no chip, no number ----------------------------------------------------
 
 
-def test_bench_timed_mode_refuses_cpu(bench, monkeypatch, capsys):
+def test_bench_timed_mode_refuses_cpu(bench, monkeypatch, capsys, cache_config):
     monkeypatch.setattr(sys, "argv", ["bench.py"])
     with pytest.raises(SystemExit) as exc:
         bench.main()
@@ -101,7 +136,7 @@ def test_bench_import_stays_off_jax():
     assert proc.returncode == 0, proc.stderr
 
 
-def test_chip_smoke_refuses_cpu(chip_smoke, capsys):
+def test_chip_smoke_refuses_cpu(chip_smoke, capsys, cache_config):
     with pytest.raises(SystemExit) as exc:
         chip_smoke.main()
     assert exc.value.code not in (0, None)
