@@ -40,17 +40,17 @@ class ModelConfig:
     stem: str = "none"  # none | s2d
     stem_factor: int = 2
     # Full-resolution residual refinement after the subpixel head
-    # (models/layers.py:DetailHead): two cheap full-res convs over
-    # concat(logits, raw image) restore sub-stem_factor-px structure the
-    # 1/r pyramid cannot carry.  Measured on the HardTiles stem A/B, where
-    # plain s2d collapses the 2-6 px disc class.
+    # (models/layers.py:DetailHead): two full-res convs over concat(logits,
+    # raw image) restore sub-stem_factor-px structure the 1/r pyramid cannot
+    # carry (HardTiles stem A/B: plain s2d collapses the 2-6 px disc class).
+    # Not cheap: 31 % of the step (102 of 328 ms flagship; ledger PR 25).
     detail_head: bool = False
     # Which refinement architecture detail_head selects:
-    # - 'fullres': two 3×3 convs at FULL resolution over concat(d2s logits,
-    #   raw image) — pixel-translation-equivariant, but its low-channel
-    #   full-res convs run lane-padded at 9-37 TF/s and its weight-gradient
-    #   contractions over [B,H·W] dominated the round-3 step (docs/PERF.md
-    #   roofline: ~43% of the flagship step in the head region);
+    # - 'fullres': two 3×3 convs at FULL resolution over concat(full-res
+    #   logits, raw image) — pixel-translation-equivariant; bound by HBM
+    #   traffic on seven full-res tensors of 0.5-1.1 GB a micro-batch, not
+    #   by arithmetic: its conv fusions run at 80-86 % of the HBM roofline
+    #   (weight gradients 41 % and 60 %), 17 TFLOP/s (PERF.md §5, §6 PR 26);
     # - 's2d': the same residual refinement computed AT THE STEM GRID on the
     #   pre-d2s logits concat s2d(image) — channels (classes·r² + 3·r²) land
     #   in the MXU-efficient regime, weights are per-subpixel-phase (cell-
@@ -62,10 +62,10 @@ class ModelConfig:
     detail_head_hidden: int = 16
     # Layout of the logits the model returns under train=True with an s2d
     # stem:
-    # - 'fullres': depth_to_space to [B,H,W,classes] before the loss
-    #   (round-3 behavior) — costs the d2s layout transpose plus loss/metric
-    #   reductions over a 512² tensor whose last dim (classes) lane-pads
-    #   ~20× on TPU;
+    # - 'fullres': [B,H,W,classes] logits before the loss, written by the
+    #   subpixel head's one transposed conv (layers.py:subpixel_conv; no
+    #   separate d2s since PR 26) — costs loss/metric reductions over a
+    #   512² tensor;
     # - 'grouped': return the pre-d2s phase-major logits [B,H/r,W/r,r²·C];
     #   the train step groups the labels identically and computes the SAME
     #   per-pixel loss/metrics on the [..., r², C] view — bit-equal math

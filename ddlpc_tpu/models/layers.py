@@ -188,6 +188,85 @@ def restore_head(logits: jax.Array, stem: str, factor: int) -> jax.Array:
     return depth_to_space(logits, factor) if stem == "s2d" else logits
 
 
+def _phase_conv(full: jax.Array, k4: jax.Array) -> jax.Array:
+    """[B, H, W, C] → [B, H/r, W/r, F]: each r×r block of pixels against
+    ``k4`` [r, r, F, C].  The transpose of :func:`subpixel_conv`."""
+    r = k4.shape[0]
+    return jax.lax.conv_general_dilated(
+        full, k4, (r, r), "VALID", dimension_numbers=("NHWC", "HWOI", "NHWC")
+    )
+
+
+@jax.custom_vjp
+def subpixel_conv(x: jax.Array, k4: jax.Array) -> jax.Array:
+    """``depth_to_space(conv1x1(x))`` as ONE transposed conv: x [B, h, w, F]
+    against k4 [r, r, F, C] (the 1×1 kernel [F, r²·C] with its phases
+    unpacked) → [B, h·r, w·r, C].  XLA writes the full-resolution tensor
+    straight from the conv in the layout its consumers want; the separate
+    depth_to_space costs the flagship four layout copies a micro-batch."""
+    r = k4.shape[0]
+    return jax.lax.conv_general_dilated(
+        x,
+        k4[::-1, ::-1],  # a correlation over the r-dilated input: phase p meets tap r-1-p
+        (1, 1),
+        ((r - 1, r - 1), (r - 1, r - 1)),
+        lhs_dilation=(r, r),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+    )
+
+
+def _subpixel_conv_fwd(x, k4):
+    return subpixel_conv(x, k4), (x, k4)
+
+
+def _subpixel_conv_bwd(res, g):
+    # Both gradients from the strided conv that this op transposes: autodiff
+    # of the dilated conv itself makes XLA reverse the full-resolution
+    # cotangent before the weight gradient (6.5 ms a flagship step).
+    x, k4 = res
+    dk4 = jax.linear_transpose(lambda k: _phase_conv(g, k), k4)(x)[0]
+    return _phase_conv(g, k4), dk4
+
+
+subpixel_conv.defvjp(_subpixel_conv_fwd, _subpixel_conv_bwd)
+
+
+class SubpixelHead(nn.Module):
+    """The zoo's logit head: a 1×1 conv to ``head_channels`` at the stem
+    grid and, under an s2d stem, the subpixel restore to full resolution.
+    Parameters are ``nn.Conv``'s (``kernel`` [1, 1, F, r²·C], ``bias``
+    [r²·C], float32).  ``restore=False`` stops at the stem grid (the grouped
+    train layout and the stem-grid refinement read it); otherwise conv and
+    restore are one transposed conv (:func:`subpixel_conv`)."""
+
+    num_classes: int
+    stem: str = "none"
+    factor: int = 1
+    dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array, restore: bool = True) -> jax.Array:
+        r = self.factor if self.stem == "s2d" else 1
+        f, c = x.shape[-1], self.num_classes
+        channels = head_channels(c, self.stem, self.factor)
+        kernel = self.param(
+            "kernel", nn.linear.default_kernel_init, (1, 1, f, channels), jnp.float32
+        )
+        bias = self.param("bias", nn.initializers.zeros_init(), (channels,), jnp.float32)
+        x, kernel, bias = (a.astype(self.dtype) for a in (x, kernel, bias))
+        if r == 1 or not restore:
+            return (
+                jax.lax.conv_general_dilated(
+                    x, kernel, (1, 1), "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC")
+                )
+                + bias
+            )
+        b, h, w, _ = x.shape
+        y = subpixel_conv(x, kernel.reshape(f, r, r, c).transpose(1, 2, 0, 3))
+        y = y.reshape(b, h, r, w, r, c) + bias.reshape(r, 1, r, c)
+        return y.reshape(b, h * r, w * r, c)
+
+
 class DetailHead(nn.Module):
     """Full-resolution residual refinement for subpixel (s2d) heads.
 
@@ -196,15 +275,23 @@ class DetailHead(nn.Module):
     HardTiles stem A/B the 2-6 px disc class collapses to IoU 0.03 under
     s2d (docs/QUANTIZATION.md hard-task table) because the pyramid never
     sees the raw pixels at full resolution.  This head concatenates the RAW
-    input image with the d2s logits and applies two cheap full-resolution
-    convs as a residual correction:
+    input image with the full-resolution logits and applies two
+    full-resolution convs as a residual correction:
 
         logits += Conv3x3(classes) . relu . Conv3x3(hidden) (logits ++ image)
 
-    FLOPs are negligible next to the pyramid (C<=hidden at the stem's
-    resolution); the real cost is HBM traffic for two low-channel full-res
-    activations, measured ~2-5% of the flagship step.  No normalization:
-    at C=16 a BatchNorm's scalar DMA chatter would cost more than the conv.
+    It is the largest named region of the flagship step: 102 of 328 ms,
+    25.5 of 82 ms at the pod point (``detail_head_device_ms``, ledger PR 25)
+    for 14 % of the step's conv FLOPs.  The cost is HBM traffic, not
+    arithmetic: at micro-batch 128 XLA puts the batch in the lanes and the
+    channels in sublanes, each of the head's seven full-resolution tensors
+    is 0.5-1.1 GB, four of its six conv fusions run at 80-86 % of the HBM
+    roofline and the two weight gradients at 41 % and 60 %.  Width-folded
+    operands ([B,H,W/r,r·C], banded or halo kernels, r 2-16) make the weight
+    gradients 2.4x faster and everything round them slower, because the 6-
+    and 9-channel tensors are sublane-padded and do not fold by a bitcast
+    (PERF.md §6, PR 26): the plain form stays.  No normalization: at C=16 a
+    BatchNorm's scalar DMA chatter would cost more than the conv.
     """
 
     num_classes: int

@@ -22,9 +22,9 @@ from ddlpc_tpu.models.layers import (
     DoubleConv,
     DownBlock,
     StemGridDetailHead,
+    SubpixelHead,
     UpBlock,
     apply_stem,
-    head_channels,
     restore_head,
 )
 
@@ -214,41 +214,47 @@ class UNet(nn.Module):
         return out
 
     def _head(self, x: jax.Array, image: Optional[jax.Array], train: bool):
-        """The 1×1 logit conv + optional detail refinement — the atomic
+        """The subpixel logit head + optional detail refinement — the atomic
         last pipeline block (submodule creation from a helper is fine: the
         compact context of ``__call__`` is active)."""
-        z = nn.Conv(
-            head_channels(self.num_classes, self.stem, self.stem_factor),
-            (1, 1),
+        head = SubpixelHead(
+            self.num_classes,
+            self.stem,
+            self.stem_factor,
             dtype=self.head_dtype,
-            param_dtype=jnp.float32,
             name="Conv_0",
-        )(x.astype(self.head_dtype))
-        if self.detail_head and self.detail_head_kind == "s2d":
-            if self.stem != "s2d":
-                raise ValueError(
-                    "detail_head_kind='s2d' refines the pre-d2s logit grid — "
-                    "it requires stem='s2d' (with stem='none' there is no "
-                    "stem grid; use detail_head_kind='fullres')"
-                )
-            z = StemGridDetailHead(
-                self.num_classes,
-                self.stem_factor,
-                hidden=self.detail_head_hidden,
-                dtype=self.dtype,
-                head_dtype=self.head_dtype,
-                name="StemGridDetailHead_0",
-            )(z, image)
-        if (
+        )
+        stem_grid_refine = self.detail_head and self.detail_head_kind == "s2d"
+        # Phase-major grouped logits: d2s is a pure layout permutation, so
+        # the grouped loss path skips it entirely (train_head_layout).
+        grouped = (
             train
             and self.train_head_layout == "grouped"
             and self.stem == "s2d"
             and not (self.detail_head and self.detail_head_kind == "fullres")
-        ):
-            # Phase-major grouped logits: d2s is a pure layout permutation,
-            # so the grouped loss path skips it entirely (train_head_layout).
-            return z
-        logits = restore_head(z, self.stem, self.stem_factor)
+        )
+        if stem_grid_refine or grouped:
+            z = head(x, restore=False)
+            if stem_grid_refine:
+                if self.stem != "s2d":
+                    raise ValueError(
+                        "detail_head_kind='s2d' refines the pre-d2s logit grid — "
+                        "it requires stem='s2d' (with stem='none' there is no "
+                        "stem grid; use detail_head_kind='fullres')"
+                    )
+                z = StemGridDetailHead(
+                    self.num_classes,
+                    self.stem_factor,
+                    hidden=self.detail_head_hidden,
+                    dtype=self.dtype,
+                    head_dtype=self.head_dtype,
+                    name="StemGridDetailHead_0",
+                )(z, image)
+            if grouped:
+                return z
+            logits = restore_head(z, self.stem, self.stem_factor)
+        else:
+            logits = head(x)  # conv and restore in one transposed conv
         if self.detail_head and self.detail_head_kind == "fullres":
             logits = DetailHead(
                 self.num_classes,
