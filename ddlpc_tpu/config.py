@@ -94,6 +94,32 @@ class ModelConfig:
     # full-resolution logit upsample) — the loss/metrics cast to fp32 before
     # any softmax/reduction either way, so only logit *storage* rounds.
     head_dtype: str = "float32"  # float32 | bfloat16
+    # The lfm2_moe family (models/lfm2_moe.py), under the published names of
+    # its config.json; every other family ignores them, and a configuration
+    # file that lacks them (the conv zoo's) loads as before.  The defaults are
+    # LFM2-24B-A2B's published widths.  ``num_classes`` is the vocabulary
+    # (slice) held here; ``num_experts`` is the router's width at any size,
+    # ``experts_held``/``expert_offset`` say which of them this
+    # expert-parallel rank computes (the others' part is left out);
+    # ``layer_types`` is the operator of each layer kept, the first
+    # ``num_dense_layers`` of which have a dense SwiGLU feed-forward.
+    hidden_size: int = 2048
+    intermediate_size: int = 11776
+    moe_intermediate_size: int = 1536
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    num_experts: int = 64
+    num_experts_per_tok: int = 4
+    experts_held: int = 8
+    expert_offset: int = 0
+    num_dense_layers: int = 1
+    layer_types: Tuple[str, ...] = ()
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    use_expert_bias: bool = True
 
 
 @dataclass(frozen=True)

@@ -29,11 +29,15 @@ DATASET_SPECS = {
     "cityscapes": dict(image_size=(512, 1024), channels=3, num_classes=19),
     "synthetic": dict(image_size=(512, 512), channels=3, num_classes=6),
     "synthetic_hard": dict(image_size=(512, 512), channels=3, num_classes=6),
+    # Token tiles (PackedTokenTiles): one id per position; the sequence
+    # length and the vocabulary are the configuration's, so no geometry here.
+    "packed_tokens": dict(channels=1),
 }
 
 
 class TileDataset:
-    """In-RAM array-backed dataset of (image [H,W,C] float32, label [H,W] int32).
+    """In-RAM array-backed dataset of (image [H,W,C] float32, label [H,W] int32);
+    an integer image array (token tiles) stays int32.
 
     Mirrors the reference's eager load-everything approach (кластер.py:660-674)
     — appropriate for ISPRS-scale corpora (~hundreds of tiles) — but behind an
@@ -47,7 +51,10 @@ class TileDataset:
             raise ValueError(
                 f"labels {labels.shape} do not match images {images.shape[:3]}"
             )
-        self.images = np.ascontiguousarray(images, np.float32)
+        # Integer tiles (token ids) keep an integer dtype: they index an
+        # embedding and must arrive exact.
+        image_dtype = np.int32 if np.issubdtype(images.dtype, np.integer) else np.float32
+        self.images = np.ascontiguousarray(images, image_dtype)
         self.labels = np.ascontiguousarray(labels, np.int32)
 
     def __len__(self) -> int:
@@ -916,7 +923,46 @@ def HardTiles(
     return TileDataset(np.clip(images, 0.0, 1.0), labels)
 
 
-SYNTHETIC_GENERATORS = {"synthetic": SyntheticTiles, "synthetic_hard": HardTiles}
+def PackedTokenTiles(
+    num_tiles: int = 48,
+    image_size: Tuple[int, int] = (1, 8192),
+    channels: int = 1,
+    num_classes: int = 8192,
+    seed: int = 0,
+    median_doc_len: float = 600.0,
+    doc_len_sigma: float = 1.2,
+) -> TileDataset:
+    """Packed-document token tiles for a per-position classifier: a tile is
+    one sequence ``int32[1, S, 1]`` of ids and its labels ``int32[1, S]`` are
+    the next ids, with no padding and no ignored position.
+
+    Documents have heavy-tailed lengths (log-normal, median
+    ``median_doc_len`` tokens, clipped to 16..S); their ids are Zipf(1.0)
+    over ``1..num_classes-1`` and id 0 ends each document.  Documents are
+    concatenated and the stream is cut into ``num_tiles`` pieces of S + 1, so
+    that ``labels[t] = ids[t + 1]`` and a document may straddle two tiles
+    (as in a packed pre-training corpus).
+    """
+    if channels != 1 or image_size[0] != 1:
+        raise ValueError(f"token tiles are [1, S, 1], got {image_size} x {channels}")
+    rng = np.random.default_rng(seed)
+    s = image_size[1]
+    total = num_tiles * (s + 1)
+    p = 1.0 / np.arange(1, num_classes, dtype=np.float64)
+    stream = rng.choice(num_classes - 1, size=total, p=p / p.sum()).astype(np.int32) + 1
+    # More than enough lengths to cover the stream even if every one were short.
+    lengths = np.exp(rng.normal(np.log(median_doc_len), doc_len_sigma, size=total // 16 + 1))
+    ends = np.cumsum(np.clip(lengths, 16, s).astype(np.int64)) - 1
+    stream[ends[ends < total]] = 0  # the reserved id closes each document
+    stream = stream.reshape(num_tiles, s + 1)
+    return TileDataset(stream[:, None, :-1, None], stream[:, None, 1:])
+
+
+SYNTHETIC_GENERATORS = {
+    "synthetic": SyntheticTiles,
+    "synthetic_hard": HardTiles,
+    "packed_tokens": PackedTokenTiles,
+}
 
 
 def dataset_defaults(name: str, **overrides) -> DataConfig:
@@ -966,7 +1012,7 @@ def build_dataset(cfg: DataConfig):
     :func:`dataset_defaults` to start from the right geometry.
     """
     spec = DATASET_SPECS.get(cfg.dataset)
-    if spec is not None and cfg.dataset != "synthetic":
+    if spec is not None and cfg.dataset != "synthetic" and "image_size" in spec:
         if (
             tuple(cfg.image_size) != spec["image_size"]
             or cfg.num_classes != spec["num_classes"]

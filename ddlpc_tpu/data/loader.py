@@ -47,6 +47,16 @@ def _compact_cast(
     return imgs.astype(ml_dtypes.bfloat16), labs.astype(np.int8)
 
 
+def _refuse_compact_tokens(dataset, compact: bool) -> None:
+    """Integer tiles are token ids: the compact wire's bf16 would round them."""
+    images = getattr(dataset, "images", None)
+    if compact and images is not None and np.issubdtype(images.dtype, np.integer):
+        raise ValueError(
+            "data.compact_upload casts tiles to bfloat16, which cannot hold "
+            "token ids exactly: unset it for an integer-tile (token) dataset"
+        )
+
+
 def make_global_array(
     local: np.ndarray, mesh: Mesh, spec: P
 ) -> jax.Array:
@@ -249,6 +259,7 @@ class ShardedLoader(_EpochSampler):
         # fuse a reduction differently).  Requires labels in [-1, 127];
         # asserted per batch in the producer thread.
         self.compact = compact
+        _refuse_compact_tokens(dataset, compact)
         # Host-side parallelism for gather+cast+upload (SURVEY §7 hard
         # part (c): ≥400 tiles/s/chip needs prefetch + host parallelism).
         # 1 keeps the single-background-thread behavior; batches stay
@@ -347,7 +358,10 @@ class ShardedLoader(_EpochSampler):
         if self._ring is None:
             A, Bl = self.sync_period, self.local_micro_batch
             h, w, c = self.ds.image_shape
-            img_dt = ml_dtypes.bfloat16 if self.compact else np.float32
+            # Integer (token) tiles travel as they are; see _refuse_compact_tokens.
+            resident = getattr(self.ds, "images", None)
+            plain_dt = resident.dtype if isinstance(resident, np.ndarray) else np.float32
+            img_dt = ml_dtypes.bfloat16 if self.compact else plain_dt
             lab_dt = np.int8 if self.compact else np.int32
 
             # Scratch (fp32/int32 staging for a compact cast that cannot
@@ -565,6 +579,7 @@ class DeviceCachedLoader(_EpochSampler):
             )
         if len(dataset) == 0:
             raise ValueError("dataset is empty")
+        _refuse_compact_tokens(dataset, compact)
         data_size = mesh.shape.get(data_axis, 1)
         if global_micro_batch % data_size:
             raise ValueError(

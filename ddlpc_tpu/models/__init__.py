@@ -2,7 +2,8 @@
 
 Build any registered model from a ``ModelConfig``.  The reference has exactly
 one model, U-Net (кластер.py:620-656); BASELINE.json's configs additionally
-require U-Net++ (deep supervision) and DeepLabV3+ (ASPP/atrous).
+require U-Net++ (deep supervision) and DeepLabV3+ (ASPP/atrous).  ``lfm2_moe``
+is the one family that is no conv net: a decoder over 1×S token tiles.
 """
 
 from __future__ import annotations
@@ -13,6 +14,7 @@ from flax import linen as nn
 
 from ddlpc_tpu.config import ModelConfig
 from ddlpc_tpu.models.deeplabv3p import DeepLabV3Plus
+from ddlpc_tpu.models.lfm2_moe import LFM2MoE
 from ddlpc_tpu.models.unet import UNet
 from ddlpc_tpu.models.unetpp import UNetPP
 
@@ -98,6 +100,22 @@ def _build_deeplab(cfg: ModelConfig, norm_axis_name: Optional[str]) -> nn.Module
         dtype=jnp.dtype(cfg.compute_dtype),
         head_dtype=jnp.dtype(cfg.head_dtype),
     )
+
+
+@register("lfm2_moe")
+def _build_lfm2_moe(cfg: ModelConfig, norm_axis_name: Optional[str]) -> nn.Module:
+    del norm_axis_name  # RMSNorm only: no batch statistics to synchronise
+    if not cfg.layer_types or set(cfg.layer_types) - {"conv", "full_attention"}:
+        raise ValueError(
+            f"lfm2_moe needs model.layer_types of 'conv' | 'full_attention', "
+            f"got {cfg.layer_types!r}"
+        )
+    if not 0 <= cfg.expert_offset <= cfg.num_experts - cfg.experts_held:
+        raise ValueError(
+            f"experts [{cfg.expert_offset}, {cfg.expert_offset + cfg.experts_held}) "
+            f"are not among the router's {cfg.num_experts}"
+        )
+    return LFM2MoE(cfg)
 
 
 def build_model(cfg: ModelConfig, norm_axis_name: Optional[str] = None) -> nn.Module:

@@ -1,0 +1,430 @@
+"""LFM2-MoE (``model_type: lfm2_moe``) as a per-position classifier over a
+1×S token tile, one expert-parallel rank's share.
+
+The family (LiquidAI LFM2-24B-A2B, config.json): a decoder whose operator is
+a doubly gated depthwise causal convolution of length ``conv_L_cache`` in
+``conv`` layers and grouped-query attention (per-head RMS norm on q and k,
+rotary positions) in ``full_attention`` layers; the feed-forward is a SwiGLU
+in the ``num_dense_layers`` leading layers and sigmoid-scored
+top-``num_experts_per_tok``-of-``num_experts`` routed experts, selected with a
+bias that does not weigh, in the others.  Equations in ISSUE 27 / PERF.md §4;
+the plain float32 reference is ``benchmark/reference/lfm2_moe.py``.
+
+In this system's terms a tile is one packed sequence: ``images`` is
+``int32[B, 1, S, 1]`` (token ids), the logits are ``[B, 1, S, vocab]`` and
+``labels[B, 1, S]`` is the next token, so the loss, the step and the Trainer
+are the zoo's own.  A floating input is cast to ids (``benchmark/flops.py``
+and ``create_train_state`` callers trace with float32; float32 holds every id
+under 2**24 exactly).
+
+The share: the router scores all ``num_experts`` and picks the top k at any
+size; the layer holds experts ``[expert_offset, expert_offset +
+experts_held)`` and adds their part for the tokens routed to them.  What the
+absent experts would add is left out (model-configs guide §4); nothing stands
+in for the other ranks or their exchange.  No token is dropped: the (token,
+expert) pairs are sorted by held expert into a buffer of all ``tokens × k``
+rows (the worst case, static), and the grouped products compute the rows of
+the held groups only.
+
+Position-wise projections are ``flax.linen.Dense`` (measured faster than the
+zoo's 1×1 ``nn.Conv`` on the chip), so the conv FLOP walks see none of this
+family's work: ``obs/flops.step_flops`` counts its matrix products for the
+program and ``benchmark/seq_flops.py`` for the benchmark.  Compute is
+``compute_dtype`` (bf16) with float32 parameters; router scores, norm
+statistics, softmax and rotary angles are float32.  Under ``train=True`` each layer's body is rematerialised
+(``nn.remat``), so what the backward keeps is the residual stream.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax import lax
+
+from ddlpc_tpu.config import ModelConfig
+
+_INIT = nn.initializers.normal(stddev=0.02)
+# Query rows per block of the XLA causal attention (no [H, S, S] scores).
+QUERY_BLOCK = 512
+
+
+def _proj(features: int, dtype, name: str) -> nn.Dense:
+    """A bias-free position-wise projection over the last axis of [B,1,S,C]:
+    a ``dot_general``, not the 1×1 ``nn.Conv`` of the conv zoo, which the TPU
+    compiler runs slower at these shapes (2048→6144 over 32,768 positions on a
+    v5e: forward 7.93 against 4.65 ms, forward and backward 16.4 against
+    13.5; PERF.md §6, PR 27)."""
+    return nn.Dense(features, use_bias=False, dtype=dtype, param_dtype=jnp.float32, name=name)
+
+
+class RMSNorm(nn.Module):
+    """``x · rsqrt(mean(x²) + eps) · g`` over the last axis, statistics in float32."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        g = self.param("scale", nn.initializers.ones, (x.shape[-1],), jnp.float32)
+        x32 = x.astype(jnp.float32)
+        y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.eps)
+        return (y * g).astype(self.dtype)
+
+
+def rotary_tables(seq_len: int, head_dim: int, theta: float):
+    """cos, sin ``[S, head_dim]`` in float32: the half-split (``rotate_half``)
+    convention of the family's published modelling code, angles
+    ``t · theta^(-2i/head_dim)`` repeated over both halves."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    angles = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rotary(x, cos, sin):
+    """x ``[..., S, heads, head_dim]``; cos, sin ``[S, head_dim]``."""
+    half = x.shape[-1] // 2
+    x32 = x.astype(jnp.float32)
+    rotated = jnp.concatenate([-x32[..., half:], x32[..., :half]], axis=-1)
+    return (x32 * cos[:, None, :] + rotated * sin[:, None, :]).astype(x.dtype)
+
+
+@jax.custom_vjp
+def _softmax_rows(x):
+    """Softmax over the last axis in float32, with each row statistic
+    (max, sum, and the backward's Σ dp·p) materialised on its own.  Left to
+    fuse a row reduction with its broadcast, the TPU compiler turns it into a
+    ``reduce-window`` as wide as the row, which costs O(T) an element: 4.0 of
+    5.8 s a step at S = 8192 (PERF.md §6, PR 27)."""
+    m = lax.optimization_barrier(jnp.max(x, axis=-1))
+    e = jnp.exp(x - m[..., None])
+    z = lax.optimization_barrier(jnp.sum(e, axis=-1))
+    return e / z[..., None]
+
+
+def _softmax_rows_fwd(x):
+    p = _softmax_rows(x)
+    return p, p
+
+
+def _softmax_rows_bwd(p, g):
+    inner = lax.optimization_barrier(jnp.sum(g * p, axis=-1))
+    return (p * (g - inner[..., None]),)
+
+
+_softmax_rows.defvjp(_softmax_rows_fwd, _softmax_rows_bwd)
+
+
+def _attend_block(q, k, v, start: int):
+    """One query block of one sequence against the keys up to its end, one
+    batched product per k/v head.  q ``[KV, G·Bq, D]`` (the G query heads of
+    a k/v head stacked, head-major), k and v ``[KV, T, D]`` with
+    ``T = start + Bq``.  The scores are ``[KV, G·Bq, T]`` with the keys in
+    the minor dimension, where the softmax reduces."""
+    block = k.shape[1] - start
+    scores = jnp.einsum("kmd,ktd->kmt", q, k, preferred_element_type=jnp.float32)
+    scores = scores * (q.shape[-1] ** -0.5)
+    qpos = start + jnp.arange(q.shape[1]) % block
+    visible = jnp.arange(k.shape[1])[None, :] <= qpos[:, None]
+    scores = jnp.where(visible, scores, -1e30)
+    probs = _softmax_rows(scores).astype(v.dtype)
+    return jnp.einsum("kmt,ktd->kmd", probs, v)
+
+
+def blocked_causal_attention(q, k, v, block: int):
+    """Causal softmax(q kᵀ / sqrt(D)) v without the ``[H, S, S]`` scores:
+    sequences one after another, each in query blocks that see only the keys
+    up to their own end (the causal half, to within a block), each block
+    rematerialised in the backward.  q ``[B, S, H, D]``; k, v ``[B, S, KV, D]``
+    with each k/v head serving ``H / KV`` query heads."""
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    block = min(block, s)
+    if s % block:
+        raise ValueError(f"sequence length {s} is not a multiple of the query block {block}")
+    # heads to the front: [B, KV, S/block, G·block, D] and [B, KV, S, D]
+    q = q.reshape(b, s // block, block, kv, g, d).transpose(0, 3, 1, 4, 2, 5)
+    q = q.reshape(b, kv, s // block, g * block, d)
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+
+    def one_sequence(qkv):
+        qs, ks, vs = qkv
+        out = [
+            jax.checkpoint(functools.partial(_attend_block, start=i * block))(
+                qs[:, i], ks[:, : (i + 1) * block], vs[:, : (i + 1) * block]
+            )
+            for i in range(s // block)
+        ]
+        return jnp.stack(out, axis=1)  # [KV, S/block, G·block, D]
+
+    out = lax.map(one_sequence, (q, k, v)).reshape(b, kv, s // block, g, block, d)
+    return out.transpose(0, 2, 4, 1, 3, 5).reshape(b, s, h, d)
+
+
+class ShortConv(nn.Module):
+    """``W_out (C ⊙ conv(B ⊙ X))`` with ``(B, C, X) = split₃(W_in u)`` and a
+    depthwise causal convolution of ``length`` taps (zeros before the start)."""
+
+    hidden: int
+    length: int = 3
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        gate_b, gate_c, x = jnp.split(_proj(3 * self.hidden, self.dtype, "in_proj")(u), 3, axis=-1)
+        taps = self.param("conv_kernel", _INIT, (self.length, self.hidden), jnp.float32)
+        z = gate_b * x
+        s = z.shape[-2]
+        padded = jnp.pad(z, ((0, 0), (0, 0), (self.length - 1, 0), (0, 0)))
+        # c_t = Σ_j taps[j] ⊙ z_{t - (length-1) + j}
+        c = sum(
+            taps[j].astype(self.dtype) * padded[:, :, j : j + s] for j in range(self.length)
+        )
+        return _proj(self.hidden, self.dtype, "out_proj")(gate_c * c)
+
+
+class Attention(nn.Module):
+    hidden: int
+    num_heads: int
+    num_kv_heads: int
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u, cos, sin):
+        b, _, s, _ = u.shape
+        d = self.hidden // self.num_heads
+        q = _proj(self.num_heads * d, self.dtype, "q_proj")(u).reshape(b, s, self.num_heads, d)
+        k = _proj(self.num_kv_heads * d, self.dtype, "k_proj")(u).reshape(b, s, self.num_kv_heads, d)
+        v = _proj(self.num_kv_heads * d, self.dtype, "v_proj")(u).reshape(b, s, self.num_kv_heads, d)
+        q = apply_rotary(RMSNorm(self.eps, self.dtype, name="q_norm")(q), cos, sin)
+        k = apply_rotary(RMSNorm(self.eps, self.dtype, name="k_norm")(k), cos, sin)
+        out = blocked_causal_attention(q, k, v, QUERY_BLOCK)
+        return _proj(self.hidden, self.dtype, "o_proj")(out.reshape(b, 1, s, self.hidden))
+
+
+class SwiGLU(nn.Module):
+    hidden: int
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        h = nn.silu(_proj(self.width, self.dtype, "w1")(u)) * _proj(self.width, self.dtype, "w3")(u)
+        return _proj(self.hidden, self.dtype, "w2")(h)
+
+
+@jax.custom_vjp
+def _dispatch(x, order, inverse):
+    """Rows of ``x [N, d]`` in sorted pair order: ``x[order // k]``, ``[N·k, d]``.
+    The backward is the inverse gather and a sum over each token's k pairs,
+    not a scatter-add."""
+    return x.at[order // (order.shape[0] // x.shape[0])].get(mode="promise_in_bounds")
+
+
+def _dispatch_fwd(x, order, inverse):
+    return _dispatch(x, order, inverse), (inverse, x.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    inverse, n = res
+    g = g.at[inverse].get(mode="promise_in_bounds")
+    return g.reshape(n, -1, g.shape[-1]).sum(axis=1), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(rows, perm, inverse):
+    """``rows[perm]`` for a permutation; the backward gathers by its inverse."""
+    return rows.at[perm].get(mode="promise_in_bounds")
+
+
+def _permute_fwd(rows, perm, inverse):
+    return _permute(rows, perm, inverse), inverse
+
+
+def _permute_bwd(inverse, g):
+    return g.at[inverse].get(mode="promise_in_bounds"), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """Sigmoid-scored top-k routing over all ``num_experts`` and the SwiGLU
+    experts held here.  Returns the held experts' part of the layer's output
+    and the layer's routing counts."""
+
+    hidden: int
+    width: int
+    num_experts: int
+    top_k: int
+    experts_held: int
+    expert_offset: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    use_expert_bias: bool = True
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        shape = u.shape
+        x = u.reshape(-1, self.hidden)
+        n, k, held_n = x.shape[0], self.top_k, self.experts_held
+        gate = self.param("gate", _INIT, (self.hidden, self.num_experts), jnp.float32)
+        expert_init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1, batch_axis=(0,))
+        w1 = self.param("w1", expert_init, (held_n, self.hidden, self.width), jnp.float32)
+        w3 = self.param("w3", expert_init, (held_n, self.hidden, self.width), jnp.float32)
+        w2 = self.param("w2", expert_init, (held_n, self.width, self.hidden), jnp.float32)
+
+        with jax.named_scope("ddlpc/moe/route"):
+            # float32 at full precision: a bf16 pass would flip selections.
+            scores = jax.nn.sigmoid(
+                jnp.dot(x.astype(jnp.float32), gate, precision=lax.Precision.HIGHEST)
+            )
+            choose = scores
+            if self.use_expert_bias:
+                # A constant of the configuration (no update rule is published):
+                # it selects and does not weigh, and takes no gradient.
+                bias = self.param("expert_bias", _INIT, (self.num_experts,), jnp.float32)
+                choose = scores + lax.stop_gradient(bias)
+            _, selected = lax.top_k(choose, k)  # [N, k] expert ids
+            # the selected experts' scores by compare-and-sum: the backward of
+            # take_along_axis is a scatter, which serialises on the TPU
+            chosen = selected[..., None] == jnp.arange(self.num_experts)  # [N, k, E]
+            weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
+            if self.norm_topk_prob:
+                weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+            weights = weights * self.routed_scaling_factor
+
+            local = selected - self.expert_offset
+            held = (local >= 0) & (local < held_n)
+            group = jnp.where(held, local, held_n).reshape(-1)  # absent experts sort last
+            order = jnp.argsort(group, stable=True)  # sorted row -> pair (token·k + j)
+            inverse = jnp.argsort(order)  # pair -> sorted row
+            # (a compare-and-sum, not bincount: a scatter-add of every pair
+            # into a handful of bins serialises on the TPU)
+            sizes = (group[:, None] == jnp.arange(held_n)).sum(axis=0, dtype=jnp.int32)
+            routed = sizes.sum()
+            in_group = (jnp.arange(n * k) < routed)[:, None]
+            # [N·k, d]: the held groups first.  The grouped products leave the
+            # rows past the last group unwritten (NaN on the TPU), forward and
+            # backward, so both ends of the expert block are masked.
+            rows = jnp.where(in_group, _dispatch(x, order, inverse), 0)
+
+        with jax.named_scope("ddlpc/moe/experts"):
+            grouped = functools.partial(
+                lax.ragged_dot, group_sizes=sizes, preferred_element_type=self.dtype
+            )
+            h = nn.silu(grouped(rows, w1.astype(self.dtype))) * grouped(rows, w3.astype(self.dtype))
+            out = grouped(h, w2.astype(self.dtype))
+
+        with jax.named_scope("ddlpc/moe/route"):
+            # Rows of the held groups that the products wrote: finite and not
+            # all zero (an unwritten row reads NaN on the TPU, zero on the CPU).
+            total = jnp.abs(out.astype(jnp.float32)).sum(axis=-1, keepdims=True)
+            written = (in_group & jnp.isfinite(total) & (total > 0)).sum(dtype=jnp.int32)
+            out = jnp.where(in_group, out, 0)
+            out = _permute(out, inverse, order).reshape(n, k, self.hidden)
+            weights = jnp.where(held, weights, 0.0)
+            y = jnp.einsum("nk,nkd->nd", weights, out.astype(jnp.float32)).astype(self.dtype)
+
+        self.sow("intermediates", "group_sizes", sizes)
+        held_pairs = held.sum(dtype=jnp.int32)
+        sums = {
+            "moe_rows_routed": held_pairs,
+            "moe_rows_offered": jnp.int32(n * k),
+            # Pairs whose expert is held and whose row the grouped products
+            # did not write; a run is not sound unless it stays 0.
+            "moe_rows_dropped": held_pairs - written,
+        }
+        maxes = {"moe_max_load": sizes.max() / jnp.maximum(sizes.mean(dtype=jnp.float32), 1.0)}
+        return y.reshape(shape), {"sum": sums, "max": maxes}
+
+
+class DecoderLayer(nn.Module):
+    """``x = h + Op(norm(h)); h' = x + FFN(norm(x))``."""
+
+    cfg: ModelConfig
+    kind: str  # conv | full_attention
+    dense: bool
+
+    @nn.compact
+    def __call__(self, h, cos, sin):
+        c, dtype = self.cfg, jnp.dtype(self.cfg.compute_dtype)
+        u = RMSNorm(c.norm_eps, dtype, name="operator_norm")(h)
+        if self.kind == "full_attention":
+            with jax.named_scope("ddlpc/attention"):
+                op = Attention(
+                    c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
+                    c.norm_eps, dtype, name="self_attn",
+                )(u, cos, sin)
+        else:
+            with jax.named_scope("ddlpc/short_conv"):
+                op = ShortConv(c.hidden_size, c.conv_L_cache, dtype, name="conv")(u)
+        x = h + op
+        u = RMSNorm(c.norm_eps, dtype, name="ffn_norm")(x)
+        if self.dense:
+            with jax.named_scope("ddlpc/dense_ffn"):
+                ffn = SwiGLU(c.hidden_size, c.intermediate_size, dtype, name="feed_forward")(u)
+            counts = None
+        else:
+            ffn, counts = RoutedExperts(
+                c.hidden_size, c.moe_intermediate_size, c.num_experts, c.num_experts_per_tok,
+                c.experts_held, c.expert_offset, c.norm_topk_prob, c.routed_scaling_factor,
+                c.use_expert_bias, dtype, name="feed_forward",
+            )(u)
+        return x + ffn, counts
+
+
+class LFM2MoE(nn.Module):
+    """``cfg`` is the configuration's ``model`` group itself: the family's
+    shapes under their published names (``config.py:ModelConfig``)."""
+
+    cfg: ModelConfig
+
+    @nn.compact
+    def __call__(self, images, train: bool = False):
+        c, dtype = self.cfg, jnp.dtype(self.cfg.compute_dtype)
+        ids = images[..., 0]  # [B, 1, S]
+        if jnp.issubdtype(ids.dtype, jnp.floating):
+            ids = ids.astype(jnp.int32)
+        with jax.named_scope("ddlpc/embed"):
+            embedding = self.param(
+                "embedding", _INIT, (c.num_classes, c.hidden_size), jnp.float32
+            )
+            h = embedding.astype(dtype).at[ids].get(mode="promise_in_bounds")
+        cos, sin = rotary_tables(
+            ids.shape[-1], c.hidden_size // c.num_attention_heads, c.rope_theta
+        )
+        # The backward keeps each layer's input and recomputes its body.
+        layer_cls = nn.remat(DecoderLayer) if train else DecoderLayer
+        routed = []
+        for i, kind in enumerate(c.layer_types):
+            h, counts = layer_cls(c, kind, i < c.num_dense_layers, name=f"layers_{i}")(
+                h, cos, sin
+            )
+            if counts is not None:
+                routed.append(counts)
+        with jax.named_scope("ddlpc/head"):
+            hn = RMSNorm(c.norm_eps, dtype, name="final_norm")(h)
+            logits = jnp.einsum(
+                "bhsc,vc->bhsv", hn, embedding.astype(dtype),
+                preferred_element_type=jnp.dtype(c.head_dtype),
+            )
+        # What the step adds up over layers, micro-batches and replicas, and
+        # what it takes the largest of (parallel/train_step.py:_reduce_counters).
+        sums, maxes = {"tokens_per_step": jnp.int32(ids.size)}, {}
+        if routed:
+            sums |= jax.tree.map(lambda *v: sum(v), *[r["sum"] for r in routed])
+            maxes = jax.tree.map(lambda *v: jnp.stack(v).max(), *[r["max"] for r in routed])
+        for kind, values in (("sum", sums), ("max", maxes)):
+            self.sow("counters", kind, values, reduce_fn=lambda _, v: v, init_fn=lambda: 0)
+        return logits

@@ -209,6 +209,79 @@ def conv_step_flops(
     return sync_period * per_micro
 
 
+_PRODUCT_FLOPS_CACHE: Dict[tuple, Tuple[int, int, bool]] = {}
+
+
+def product_flops(cfg, micro_batch: int, channels: int = 3) -> Tuple[int, int, bool]:
+    """``(dense, grouped, has_conv)`` of ONE forward of ``cfg``'s model over
+    a micro-batch: the FLOPs of every ``dot_general`` (2 · output elements ·
+    contracted length), those of every ``ragged_dot`` if each row of its
+    buffer lay in a group (2·m·k·n), and whether the program holds a
+    ``conv_general_dilated`` at all.  Traced with ``train=False``, so a
+    model that rematerialises under ``train=True`` is not counted twice; a
+    ``scan`` body (attention mapped over sequences) counts ``length`` times.
+    Memoized like :func:`conv_step_flops`."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ddlpc_tpu.models import build_model
+
+    key = (cfg.model, tuple(cfg.data.image_size), int(micro_batch), int(channels))
+    if key in _PRODUCT_FLOPS_CACHE:
+        return _PRODUCT_FLOPS_CACHE[key]
+    model = build_model(cfg.model)
+    h, w = cfg.data.image_size
+    # float32 tiles, as collect_convs: a token model casts them to ids.
+    x_s = jax.ShapeDtypeStruct((micro_batch, h, w, channels), jnp.float32)
+    variables = jax.eval_shape(
+        lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, h, w, channels), jnp.float32), train=False
+        )
+    )
+    jaxpr = jax.make_jaxpr(lambda v, x: model.apply(v, x, train=False))(variables, x_s)
+
+    def walk(jaxpr):
+        dense, grouped, has_conv = 0, 0, False
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name == "dot_general":
+                (contract, _), _ = eqn.params["dimension_numbers"]
+                lhs = eqn.invars[0].aval.shape
+                dense += 2 * int(np.prod(eqn.outvars[0].aval.shape)) * int(
+                    np.prod([lhs[d] for d in contract])
+                )
+            elif name == "ragged_dot_general":
+                m, k = eqn.invars[0].aval.shape
+                grouped += 2 * m * k * eqn.invars[1].aval.shape[-1]
+            has_conv |= name == "conv_general_dilated"
+            times = eqn.params["length"] if name == "scan" else 1
+            for sub in _sub_jaxprs(eqn.params):
+                d, g, c = walk(sub)
+                dense, grouped, has_conv = dense + times * d, grouped + times * g, has_conv | c
+        return dense, grouped, has_conv
+
+    _PRODUCT_FLOPS_CACHE[key] = walk(jaxpr.jaxpr)
+    return _PRODUCT_FLOPS_CACHE[key]
+
+
+def step_flops(
+    cfg, micro_batch: int, sync_period: int, channels: int = 3
+) -> Tuple[int, int]:
+    """``(dense, grouped)`` required FLOPs of one optimizer step per device,
+    by what the model's traced program holds, whatever its name: ``dense`` is
+    the convolutions (:func:`conv_step_flops`, the walk over the
+    ``value_and_grad`` program, where there is a convolution) plus three
+    times the forward's matrix products (forward and backward; recomputation
+    is not required work); ``grouped`` is three times the forward's grouped
+    products (``ragged_dot``) with every row of their buffers in a group.
+    How many rows were, only the run knows: :meth:`PerfAccountant.routed`
+    takes the share from the step's counters."""
+    dense, grouped, has_conv = product_flops(cfg, micro_batch, channels)
+    convs = conv_step_flops(cfg, micro_batch, sync_period, channels) if has_conv else 0
+    return convs + 3 * sync_period * dense, 3 * sync_period * grouped
+
+
 def device_peak_flops(device) -> float:
     """Peak dense bf16 FLOP/s of a ``jax.Device`` from the one table;
     an unknown ``device_kind`` raises."""
@@ -317,9 +390,14 @@ class PerfAccountant:
         peak_assumed: bool = False,
         restart_gap_s: float = 0.0,
         service: str = "train",
+        grouped_flops_per_step: int = 0,
     ):
         self._lock = threading.Lock()
-        self.flops_per_step = int(flops_per_step)
+        # ``flops_per_step`` is what MFU counts: the dense FLOPs, plus the
+        # grouped products' at the share of their rows last reported routed.
+        self._dense_flops = int(flops_per_step)
+        self._grouped_flops = int(grouped_flops_per_step)
+        self.flops_per_step = self._dense_flops
         self.peak_flops = float(peak_flops)
         self.peak_assumed = bool(peak_assumed)
         self.restart_gap_s = float(restart_gap_s)
@@ -332,7 +410,7 @@ class PerfAccountant:
         self._g_mfu = registry.gauge(
             "ddlpc_mfu",
             "Model FLOP utilization of the last epoch's mean step "
-            "(conv FLOPs / (step seconds * peak FLOP/s per device)).",
+            "(required FLOPs / (step seconds * peak FLOP/s per device)).",
         )
         self._g_goodput = registry.gauge(
             "ddlpc_goodput",
@@ -341,7 +419,8 @@ class PerfAccountant:
         )
         self._g_flops = registry.gauge(
             "ddlpc_flops_per_step",
-            "Per-device conv FLOPs of one optimizer step (traced jaxpr).",
+            "Per-device conv and matrix-product FLOPs of one optimizer step "
+            "(traced jaxpr; grouped products by the routed rows).",
         )
         self._g_peak = registry.gauge(
             "ddlpc_peak_flops_per_device",
@@ -383,6 +462,15 @@ class PerfAccountant:
         with self._lock:
             self._debits[category] = self._debits.get(category, 0.0) + seconds
         self._g_debit.set(self._debits[category], category=category)
+
+    def routed(self, rows_routed: float, rows_offered: float) -> None:
+        """The step's routing counters (``moe_rows_routed``,
+        ``moe_rows_offered``): the grouped products count at the share of
+        their buffers' rows that lay in a group, and at nothing before."""
+        share = rows_routed / rows_offered if rows_offered > 0 else 0.0
+        with self._lock:
+            self.flops_per_step = self._dense_flops + int(self._grouped_flops * share)
+        self._g_flops.set(float(self.flops_per_step))
 
     def mfu(self, step_time_s: float) -> float:
         """MFU of a step of ``step_time_s`` seconds under the model."""

@@ -77,9 +77,11 @@ def create_train_state(
     tx: optax.GradientTransformation,
     rng: jax.Array,
     input_shape: Tuple[int, ...],
+    input_dtype: Any = jnp.float32,
 ) -> TrainState:
-    """Initialize parameters/optimizer on host. input_shape: [N, H, W, C]."""
-    variables = model.init(rng, jnp.zeros(input_shape, jnp.float32), train=False)
+    """Initialize parameters/optimizer on host. input_shape: [N, H, W, C];
+    ``input_dtype`` is the dataset's (integer for token tiles)."""
+    variables = model.init(rng, jnp.zeros(input_shape, input_dtype), train=False)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     return TrainState(
@@ -98,17 +100,40 @@ def _loss_and_metrics(
     labels: jax.Array,
     train: bool,
 ):
+    """Returns ``loss, (new batch_stats, accuracy, counters)``.  A model
+    without a ``batch_stats`` collection keeps the (empty) tree it was given;
+    ``counters`` is what the model sowed into its ``counters`` collection under
+    ``train=True``: scalars under ``"sum"`` and under ``"max"``, by how the step
+    reduces them (``models/lfm2_moe.py``'s routing counts; empty for the conv zoo)."""
     variables = {"params": params, "batch_stats": batch_stats}
+    counters = {}
     if train:
         logits, updates = model.apply(
-            variables, images, train=True, mutable=["batch_stats"]
+            variables, images, train=True, mutable=["batch_stats", "counters"]
         )
-        new_stats = updates["batch_stats"]
+        new_stats = updates.get("batch_stats", batch_stats)
+        counters = dict(updates.get("counters", {}))
     else:
         logits = model.apply(variables, images, train=False)
         new_stats = batch_stats
     loss, acc = loss_from_logits(model, logits, labels, train)
-    return loss, (new_stats, acc)
+    return loss, (new_stats, acc, counters)
+
+
+def _reduce_counters(stacked: dict, axis_name: Optional[str] = None) -> dict:
+    """One value per optimizer step from the model's per-micro-batch counters
+    (leading axis ``A``): those under ``"sum"`` added up over micro-batches
+    and replicas, those under ``"max"`` the largest of them.  The model says
+    which is which and nothing downstream asks again: the Trainer records
+    every counter's mean over an epoch's steps (the mean of a ``"max"``
+    counter's per-step values included).  ``axis_name`` is None in a program
+    over global arrays."""
+    out = {}
+    for name, v in stacked.get("sum", {}).items():
+        out[name] = v.sum() if axis_name is None else lax.psum(v.sum(), axis_name)
+    for name, v in stacked.get("max", {}).items():
+        out[name] = v.max() if axis_name is None else lax.pmax(v.max(), axis_name)
+    return out
 
 
 @jax.named_scope("ddlpc/loss")
@@ -183,7 +208,8 @@ def _accumulate_grads(
     """Scan ``A`` micro-batches accumulating fp32 grads (the reference's
     loss.backward() accumulation loop, кластер.py:750-759).  Shared by the
     shard_map and GSPMD step builders so their semantics cannot diverge.
-    Returns (mean grads, new batch_stats, losses [A], accs [A]).
+    Returns (mean grads, new batch_stats, losses [A], accs [A], counters:
+    dict of [A], see :func:`_reduce_counters`).
 
     ``remat=True`` wraps each micro-batch's forward in ``jax.checkpoint``:
     no activations are stored between forward and backward — the backward
@@ -200,11 +226,11 @@ def _accumulate_grads(
     def micro(carry, xy):
         grads_acc, stats = carry
         x, y = xy
-        (loss, (stats, acc)), grads = jax.value_and_grad(
+        (loss, (stats, acc, counters)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(state.params, stats, x, y)
         grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
-        return (grads_acc, stats), (loss, acc)
+        return (grads_acc, stats), (loss, acc, counters)
 
     # Device scopes (``ddlpc/<region>`` in every instruction's op_name) are
     # metadata only: the optimized HLO, its fusions and its bits do not move.
@@ -212,11 +238,11 @@ def _accumulate_grads(
         zeros = jax.tree.map(
             lambda p: jnp.zeros_like(p, jnp.float32), state.params
         )
-        (grads, batch_stats), (losses, accs) = lax.scan(
+        (grads, batch_stats), (losses, accs, counters) = lax.scan(
             micro, (zeros, state.batch_stats), (images, labels)
         )
         grads = jax.tree.map(lambda g: g / images.shape[0], grads)
-    return grads, batch_stats, losses, accs
+    return grads, batch_stats, losses, accs, counters
 
 
 @jax.named_scope("ddlpc/update")
@@ -481,7 +507,7 @@ def make_train_step(
             fwd_state = state.replace(params=full_params)
         else:
             fwd_state = state
-        grads, batch_stats, losses, accs = _accumulate_grads(
+        grads, batch_stats, losses, accs, counters = _accumulate_grads(
             model, fwd_state, images, labels, remat=remat
         )
         # Keep BatchNorm running stats replica-identical at every sync point:
@@ -531,6 +557,7 @@ def make_train_step(
                 "loss": lax.pmean(losses.mean(), data_axis),
                 "pixel_acc": lax.pmean(accs.mean(), data_axis),
                 "grad_norm": grad_norm,
+                **_reduce_counters(counters, data_axis),
             }
         new_state = TrainState(
             step=state.step + 1,
@@ -674,7 +701,7 @@ def make_train_step_gspmd(
         )
 
     def step_fn(state: TrainState, images: jax.Array, labels: jax.Array):
-        grads, batch_stats, losses, accs = _accumulate_grads(
+        grads, batch_stats, losses, accs, counters = _accumulate_grads(
             model, state, images, labels, remat=remat
         )
         if compression.mode != "none":
@@ -740,6 +767,7 @@ def make_train_step_gspmd(
             "loss": losses.mean(),
             "pixel_acc": accs.mean(),
             "grad_norm": _global_norm(grads),
+            **_reduce_counters(counters),
         }
         new_state = TrainState(
             step=state.step + 1,

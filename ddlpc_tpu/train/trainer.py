@@ -70,6 +70,10 @@ from ddlpc_tpu.train.optim import build_optimizer
 from ddlpc_tpu.train.watchdog import StallWatchdog
 
 
+# The step's own metrics; any other key it returns is a model counter.
+_STEP_METRICS = ("loss", "pixel_acc", "grad_norm")
+
+
 def _record_keys(stage_seconds: Dict[str, float]) -> Dict[str, float]:
     """``{stage: seconds}`` as epoch-record keys: ``init/state`` →
     ``t_init_state_s``."""
@@ -247,6 +251,10 @@ class Trainer:
                 self.tx,
                 jax.random.key(cfg.train.seed),
                 (1, h, w, channels),
+                # Token tiles are integer ids; every other dataset is float32.
+                input_dtype=getattr(
+                    getattr(self.train_ds, "images", None), "dtype", np.float32
+                ),
             )
             # Run layout: replicated, or — under the sharded update — the
             # level's persistent shards: Adam moments chunked 1/N (zero1/2/3),
@@ -308,7 +316,7 @@ class Trainer:
             self._comm_probed_epoch = False
             if cfg.train.perf_accounting:
                 try:
-                    flops_per_step = obs_flops.conv_step_flops(
+                    flops_per_step, grouped_flops = obs_flops.step_flops(
                         cfg, cfg.train.micro_batch_size, cfg.train.sync_period,
                         channels=channels,
                     )
@@ -325,13 +333,14 @@ class Trainer:
                         f"{e}); ddlpc_mfu will read 0",
                         stacklevel=2,
                     )
-                    flops_per_step = 0
+                    flops_per_step = grouped_flops = 0
                 peak, assumed = obs_flops.resolve_peak_flops(
                     cfg.train.peak_flops_per_device
                 )
                 self.perf = obs_flops.PerfAccountant(
                     self.registry,
                     flops_per_step=flops_per_step,
+                    grouped_flops_per_step=grouped_flops,
                     peak_flops=peak,
                     peak_assumed=assumed,
                     # Downtime inherited from a previous supervised attempt
@@ -746,7 +755,7 @@ class Trainer:
         with stage("epoch_head", epoch=epoch):
             self.loader.set_epoch(epoch)
             self._comm_probed_epoch = False
-            losses, accs = [], []
+            losses, accs, extras = [], [], []
             it = iter(self.loader)
             step_idx = 0
             skipped = 0
@@ -783,6 +792,11 @@ class Trainer:
                 self.state, metrics = self.train_step(self.state, *batch)
             losses.append(metrics["loss"])
             accs.append(metrics["pixel_acc"])
+            # Whatever the model counts beside loss and accuracy (the routing
+            # counters of models/lfm2_moe.py): fetched with them, below.
+            extras.append(
+                {k: v for k, v in metrics.items() if k not in _STEP_METRICS}
+            )
             step_idx += 1
             if self.comm is not None:
                 # Exact logical collective bytes for this optimizer step
@@ -848,7 +862,7 @@ class Trainer:
             )
         self.watchdog.beat("epoch_metrics_fetch")
         with stage("metrics_fetch", epoch=epoch):
-            losses, accs = jax.device_get((losses, accs))
+            losses, accs, extras = jax.device_get((losses, accs, extras))
         with stage("epoch_tail", epoch=epoch):
             losses = [float(l) for l in losses]
             accs = [float(a) for a in accs]
@@ -868,6 +882,15 @@ class Trainer:
                 # computes only the remaining steps of its first epoch.
                 "tiles_per_s": steps * self.loader.super_batch / epoch_time,
             }
+            # Model counters, one value per step (already summed or maximised
+            # over micro-batches and replicas: train_step.py:_reduce_counters):
+            # the epoch's record holds the mean over its steps, and the
+            # registry the same as gauges.
+            for name in extras[0]:
+                record[name] = float(np.mean([float(e[name]) for e in extras]))
+                self.registry.gauge(
+                    f"ddlpc_{name}", "model counter, per optimizer step"
+                ).set(record[name])
             if skipped:
                 # Flag the partial epoch: its loss/acc means cover only the
                 # post-resume steps (the state is still exact — the skipped
@@ -895,6 +918,8 @@ class Trainer:
                 # (loader_gather/cast/upload run on producer threads and
                 # overlap the step — they are throughput, not wall debits).
                 totals = self.timer.summary()
+                if "moe_rows_offered" in record:
+                    self.perf.routed(record["moe_rows_routed"], record["moe_rows_offered"])
                 self.perf.productive(totals.get("step", 0.0), steps)
                 self.perf.debit("data", totals.get("data", 0.0))
             self.timer.reset()

@@ -69,14 +69,25 @@ def _shrunk(cfg: ExperimentConfig, workdir: str) -> ExperimentConfig:
     # pyramid, so the shrunk tile must keep min dim ≥ 4·2⁵ = 128.
     min_dim = 128 if cfg.model.stem == "s2d" else 64
     scale = max(h // min_dim, 1)
+    model = dataclasses.replace(
+        cfg.model,
+        features=tuple(max(f // 8, 4) for f in cfg.model.features),
+        bottleneck_features=max(cfg.model.bottleneck_features // 8, 4),
+    )
+    if cfg.model.name == "lfm2_moe":
+        # A token tile is [1, S]: shrink the sequence, the vocabulary and
+        # every width; keep the layer pattern and the expert share's shape.
+        h, w, scale = 1, 64, 1
+        model = dataclasses.replace(
+            cfg.model, num_classes=128, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=48, num_attention_heads=4, num_key_value_heads=2,
+            num_experts=8, num_experts_per_tok=2, experts_held=2,
+        )
     return cfg.replace(
-        model=dataclasses.replace(
-            cfg.model,
-            features=tuple(max(f // 8, 4) for f in cfg.model.features),
-            bottleneck_features=max(cfg.model.bottleneck_features // 8, 4),
-        ),
+        model=model,
         data=dataclasses.replace(
             cfg.data,
+            num_classes=model.num_classes,
             image_size=(h // scale, w // scale),
             synthetic_len=40,
             test_split=4,
@@ -125,7 +136,8 @@ def test_config_files_exist():
     # The five BASELINE parity configs plus the TPU-first flagship and the
     # TPU-first U-Net++ (s2d stem — 20× the paper layout's throughput);
     # serve_*.json deploy artifacts are filtered out above.
-    assert len(CONFIG_FILES) == 7, CONFIG_FILES
+    # ... and lfm2_24b_a2b_ep8.json, the one token-tile configuration.
+    assert len(CONFIG_FILES) == 8, CONFIG_FILES
 
 
 @pytest.mark.parametrize(

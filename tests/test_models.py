@@ -444,7 +444,7 @@ def test_grouped_layout_loss_and_grads_identical(detail):
 
     def loss_of(model):
         def f(params):
-            loss, (stats, acc) = _loss_and_metrics(
+            loss, (stats, acc, _) = _loss_and_metrics(
                 model, params, v["batch_stats"], x, y, train=True
             )
             return loss, acc

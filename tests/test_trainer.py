@@ -253,8 +253,9 @@ def test_configs_dir_parses():
     from ddlpc_tpu.config import ExperimentConfig, FleetConfig, ServeConfig
 
     paths = sorted(glob.glob(os.path.join(os.path.dirname(__file__), "..", "configs", "*.json")))
-    # 5 BASELINE parity + TPU flagship + s2d U-Net++ + serve + fleet deploys
-    assert len(paths) == 9
+    # 5 BASELINE parity + TPU flagship + s2d U-Net++ + the token-tile
+    # lfm2_24b_a2b_ep8 + serve + fleet deploys
+    assert len(paths) == 10
     for p in paths:
         if os.path.basename(p).startswith("serve_"):
             # serve_*.json are ServeConfig deploy artifacts, not experiments
