@@ -166,6 +166,37 @@ def blocked_causal_attention(q, k, v, block: int):
     return out.transpose(0, 2, 4, 1, 3, 5).reshape(b, s, h, d)
 
 
+def _kernels(seq_len: int):
+    """``ops/pallas_attention`` where its kernels take the sequence length,
+    else None.  Imported here: Pallas takes a second to import, and only a
+    model that attends pays it."""
+    from ddlpc_tpu.ops import pallas_attention
+
+    return pallas_attention if pallas_attention.supported(seq_len) else None
+
+
+def _kernel_lowers(seq_len: int):
+    """int32 1 where :func:`causal_attention` lowers to the fused kernel, 0
+    where it lowers to the XLA form: read from the platform the program is
+    lowered for and from the sequence length, like the choice itself."""
+    if _kernels(seq_len) is None:
+        return jnp.int32(0)
+    return lax.platform_dependent(tpu=lambda: jnp.int32(1), default=lambda: jnp.int32(0))
+
+
+def causal_attention(q, k, v):
+    """Causal grouped-query attention, one algorithm with two lowerings: the
+    fused kernel (``ops/pallas_attention.py``: no scores in HBM) where the
+    program is lowered for a TPU and the kernels take the sequence length (a
+    multiple of their block, short enough for their VMEM),
+    :func:`blocked_causal_attention` everywhere else.  Shapes as there."""
+    xla = functools.partial(blocked_causal_attention, block=QUERY_BLOCK)
+    kernels = _kernels(q.shape[1])
+    if kernels is None:
+        return xla(q, k, v)
+    return lax.platform_dependent(q, k, v, tpu=kernels.causal_attention, default=xla)
+
+
 class ShortConv(nn.Module):
     """``W_out (C ⊙ conv(B ⊙ X))`` with ``(B, C, X) = split₃(W_in u)`` and a
     depthwise causal convolution of ``length`` taps (zeros before the start)."""
@@ -204,7 +235,7 @@ class Attention(nn.Module):
         v = _proj(self.num_kv_heads * d, self.dtype, "v_proj")(u).reshape(b, s, self.num_kv_heads, d)
         q = apply_rotary(RMSNorm(self.eps, self.dtype, name="q_norm")(q), cos, sin)
         k = apply_rotary(RMSNorm(self.eps, self.dtype, name="k_norm")(k), cos, sin)
-        out = blocked_causal_attention(q, k, v, QUERY_BLOCK)
+        out = causal_attention(q, k, v)
         return _proj(self.hidden, self.dtype, "o_proj")(out.reshape(b, 1, s, self.hidden))
 
 
@@ -421,10 +452,16 @@ class LFM2MoE(nn.Module):
             )
         # What the step adds up over layers, micro-batches and replicas, and
         # what it takes the largest of (parallel/train_step.py:_reduce_counters).
-        sums, maxes = {"tokens_per_step": jnp.int32(ids.size)}, {}
+        sums = {"tokens_per_step": jnp.int32(ids.size)}
+        # The attention operators that lowered to the fused kernel (the same
+        # in every micro-batch and replica, so the step's largest is the count).
+        maxes = {
+            "attention_kernel_layers": c.layer_types.count("full_attention")
+            * _kernel_lowers(ids.shape[-1])
+        }
         if routed:
             sums |= jax.tree.map(lambda *v: sum(v), *[r["sum"] for r in routed])
-            maxes = jax.tree.map(lambda *v: jnp.stack(v).max(), *[r["max"] for r in routed])
+            maxes |= jax.tree.map(lambda *v: jnp.stack(v).max(), *[r["max"] for r in routed])
         for kind, values in (("sum", sums), ("max", maxes)):
             self.sow("counters", kind, values, reduce_fn=lambda _, v: v, init_fn=lambda: 0)
         return logits

@@ -212,7 +212,9 @@ def conv_step_flops(
 _PRODUCT_FLOPS_CACHE: Dict[tuple, Tuple[int, int, bool]] = {}
 
 
-def product_flops(cfg, micro_batch: int, channels: int = 3) -> Tuple[int, int, bool]:
+def product_flops(
+    cfg, micro_batch: int, channels: int = 3, platform: Optional[str] = None
+) -> Tuple[int, int, bool]:
     """``(dense, grouped, has_conv)`` of ONE forward of ``cfg``'s model over
     a micro-batch: the FLOPs of every ``dot_general`` (2 · output elements ·
     contracted length), those of every ``ragged_dot`` if each row of its
@@ -220,14 +222,18 @@ def product_flops(cfg, micro_batch: int, channels: int = 3) -> Tuple[int, int, b
     ``conv_general_dilated`` at all.  Traced with ``train=False``, so a
     model that rematerialises under ``train=True`` is not counted twice; a
     ``scan`` body (attention mapped over sequences) counts ``length`` times.
-    Memoized like :func:`conv_step_flops`."""
+    A ``pallas_call`` counts by its ``cost_estimate`` (its body holds one
+    grid step's products, not the grid's), and of a ``lax.platform_dependent``
+    switch the one branch that is lowered for ``platform`` (the default
+    backend's unless given).  Memoized like :func:`conv_step_flops`."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ddlpc_tpu.models import build_model
 
-    key = (cfg.model, tuple(cfg.data.image_size), int(micro_batch), int(channels))
+    platform = platform or jax.default_backend()
+    key = (cfg.model, tuple(cfg.data.image_size), int(micro_batch), int(channels), platform)
     if key in _PRODUCT_FLOPS_CACHE:
         return _PRODUCT_FLOPS_CACHE[key]
     model = build_model(cfg.model)
@@ -245,6 +251,10 @@ def product_flops(cfg, micro_batch: int, channels: int = 3) -> Tuple[int, int, b
         dense, grouped, has_conv = 0, 0, False
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
+            if name == "pallas_call":
+                cost = eqn.params.get("cost_estimate")
+                dense += int(cost.flops) if cost is not None else 0
+                continue
             if name == "dot_general":
                 (contract, _), _ = eqn.params["dimension_numbers"]
                 lhs = eqn.invars[0].aval.shape
@@ -256,7 +266,11 @@ def product_flops(cfg, micro_batch: int, channels: int = 3) -> Tuple[int, int, b
                 grouped += 2 * m * k * eqn.invars[1].aval.shape[-1]
             has_conv |= name == "conv_general_dilated"
             times = eqn.params["length"] if name == "scan" else 1
-            for sub in _sub_jaxprs(eqn.params):
+            subs = list(_sub_jaxprs(eqn.params))
+            lowered_for = eqn.params.get("branches_platforms") if name == "cond" else None
+            if lowered_for:  # the last branch is the default (None)
+                subs = [next(b for b, ps in zip(subs, lowered_for) if ps is None or platform in ps)]
+            for sub in subs:
                 d, g, c = walk(sub)
                 dense, grouped, has_conv = dense + times * d, grouped + times * g, has_conv | c
         return dense, grouped, has_conv

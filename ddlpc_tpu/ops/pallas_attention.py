@@ -1,0 +1,342 @@
+"""Pallas TPU kernels for causal grouped-query attention, forward and backward.
+
+``softmax(q kᵀ / sqrt(D)) v`` under the causal mask in which no
+``[..., rows, keys]`` array ever reaches HBM: a grid step holds one block of
+queries (the ``G`` query heads of a k/v head stacked into ``G·block`` rows, so
+the four of them read one copy of k and v) against one block of keys; the
+scores, their exponentials and the running row statistics live in VMEM.  The
+arithmetic is that of ``models/lfm2_moe.py:_attend_block``: ``q·kᵀ`` from the
+compute dtype accumulated in float32; mask, running maximum, exponentials and
+running sum in float32; the probabilities cast to the compute dtype for
+``P·V``, accumulated in float32.  The online (blockwise) softmax re-associates
+those sums and approximates nothing.
+
+Only the lower triangle of blocks is in the grid: the ``(query block, key
+block)`` pairs are flattened into one grid axis and read from two prefetched
+tables, so a block above the diagonal costs neither a step nor a DMA, and
+only the diagonal blocks build a mask.
+
+The backward is one kernel over the same pairs that recomputes the
+probabilities from the saved row log-sum-exp (five products a pair): dQ
+accumulates over a query block's keys; dK and dV of the whole sequence stay
+in VMEM until the head's last pair, which bounds the sequence (``MAX_SEQ``).
+
+Every ``pallas_call`` carries a ``pl.CostEstimate`` of the causal half of
+the products it stands for; ``obs/flops.product_flops`` counts the kernel by
+it (the kernel's body holds one block's products, not the grid's).
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Query rows per head, and keys, of one grid step.  Measured on a v5e at
+# q[4, 8192, 32, 64] (PERF.md §6, PR 28).
+BLOCK = 512
+LANES = 128
+_MASKED = -1e30  # as the XLA form; exp(_MASKED - m) is exactly 0
+_NT = (((1,), (1,)), ((), ()))  # a · bᵀ
+_TN = (((0,), (0,)), ((), ()))  # aᵀ · b
+# The backward keeps dK and dV of one k/v head's whole sequence in VMEM.
+MAX_SEQ = 16384
+
+
+def supported(seq_len: int, block: int = BLOCK) -> bool:
+    """Whether the kernels take a sequence of ``seq_len`` at ``block``."""
+    return block % LANES == 0 and seq_len % block == 0 and seq_len <= MAX_SEQ
+
+
+def _vmem_limit(seq_len: int) -> int:
+    """16 MiB for a block pair's scores and operands, and 2 KiB a position
+    for the backward's whole-sequence dK and dV (float32 accumulators and
+    double-buffered outputs, head size padded to 128 lanes)."""
+    return (16 << 20) + seq_len * 2048
+
+
+def _lanes(x, n: int):
+    """``x [rows, 128]`` with equal lanes, as ``[rows, n]``."""
+    return x[:, :n] if n <= LANES else jnp.tile(x, (1, n // LANES))
+
+
+def _visible(rows: int, block: int, keys_minor: bool = True):
+    """The diagonal block's mask.  Row ``n`` of the stacked queries is position
+    ``n mod block`` of its block; it sees the keys up to itself."""
+    shape = (rows, block) if keys_minor else (block, rows)
+    qpos = lax.rem(lax.broadcasted_iota(jnp.int32, shape, 0 if keys_minor else 1), block)
+    kpos = lax.broadcasted_iota(jnp.int32, shape, 1 if keys_minor else 0)
+    return kpos <= qpos
+
+
+def _scores(a, b, scale: float):
+    s = lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+    return s if scale == 1.0 else s * scale
+
+
+def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+                *, scale: float):
+    t = pl.program_id(2)
+    i, j = qi_ref[t], kj_ref[t]
+    g, block, d = q_ref.shape
+    rows = g * block
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _MASKED)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def step(diagonal: bool):
+        k, v = k_ref[...], v_ref[...]
+        s = _scores(q_ref[...].reshape(rows, d), k, scale)  # [rows, keys] f32
+        if diagonal:
+            s = jnp.where(_visible(rows, block), s, _MASKED)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_next, block))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
+        m_ref[...] = m_next
+        pv = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + pv
+
+    @pl.when(j < i)
+    def _():
+        step(False)
+
+    @pl.when(j == i)  # the diagonal block is the row's last
+    def _():
+        step(True)
+        l = l_ref[...]
+        o_ref[...] = (acc_ref[...] / _lanes(l, d)).reshape(g, block, d).astype(o_ref.dtype)
+        # [rows, 128] equal lanes -> one lane-major row for the backward
+        lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
+
+
+def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+                dq_acc, dk_acc, dv_acc, *, scale: float):
+    """One (query block, key block) pair of dQ, dK and dV.  The scores are
+    held transposed, ``[keys, rows]``, so the saved row statistics broadcast
+    along sublanes.  dQ accumulates over a query block's keys and is written
+    on the diagonal; dK and dV of the whole sequence (one k/v head) accumulate
+    in VMEM and are written after the last pair."""
+    t = pl.program_id(2)
+    i, j = qi_ref[t], kj_ref[t]
+    g, block, d = q_ref.shape
+    rows = g * block
+
+    @pl.when(t == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def step(diagonal: bool):
+        k, v = k_ref[...], v_ref[...]
+        q, do = q_ref[...].reshape(rows, d), do_ref[...].reshape(rows, d)
+        st = _scores(k, q, scale)
+        if diagonal:
+            st = jnp.where(_visible(rows, block, keys_minor=False), st, _MASKED)
+        pt = jnp.exp(st - lse_ref[...])
+        keys = pl.ds(pl.multiple_of(j * block, block), block)
+        dv_acc[keys, :] += jnp.dot(pt.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dpt = lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[...])
+        if scale != 1.0:
+            dst = dst * scale
+        dst = dst.astype(q.dtype)
+        dk_acc[keys, :] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+        dq_acc[...] += lax.dot_general(dst, k, _TN, preferred_element_type=jnp.float32)
+
+    @pl.when(j < i)
+    def _():
+        step(False)
+
+    @pl.when(j == i)
+    def _():
+        step(True)
+        dq_ref[...] = dq_acc[...].reshape(g, block, d).astype(dq_ref.dtype)
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _triangle(n: int):
+    """The lower triangle of an n×n block grid, flattened query-major, the
+    diagonal last in its row: (query blocks, key blocks) as int32 tables."""
+    qi, kj = np.array([(i, j) for i in range(n) for j in range(i + 1)], np.int32).T
+    return jnp.asarray(qi), jnp.asarray(kj)
+
+
+def _call(kernel, name, operands, out_shapes, out_specs, scratch, in_specs, *, block, products,
+          interpret):
+    """A kernel over the lower triangle of one k/v head's block pairs, with
+    the cost of the causal half of ``products`` [S, D] x [D, S] products a
+    query head."""
+    b, kv, g, s, d = operands[0].shape
+    tables = _triangle(s // block)
+    return pl.pallas_call(
+        kernel,
+        out_shape=out_shapes,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, kv, tables[0].shape[0]),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(s),
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=products * b * kv * g * s * s * d,  # 2·S²·D / 2 a product and head
+            transcendentals=b * kv * g * s * s // 2,
+            bytes_accessed=sum(
+                math.prod(x.shape) * jnp.dtype(x.dtype).itemsize for x in (*operands, *out_shapes)
+            ),
+        ),
+        interpret=interpret,
+        name=name,
+    )(*tables, *operands)
+
+
+def _specs(g: int, block: int, d: int):
+    """Block specs of a [B, KV, G, S, D] query-side array, a [B, KV, S, D]
+    key-side array and a [B, KV, S/block, 1, G·block] row statistic, indexed
+    through the prefetched (query block, key block) tables."""
+    q = pl.BlockSpec((None, None, g, block, d), lambda b, h, t, qi, kj: (b, h, 0, qi[t], 0))
+    kv = pl.BlockSpec((None, None, block, d), lambda b, h, t, qi, kj: (b, h, kj[t], 0))
+    row = pl.BlockSpec((None, None, None, 1, g * block), lambda b, h, t, qi, kj: (b, h, qi[t], 0, 0))
+    return q, kv, row
+
+
+def _forward(q, k, v, block: int, scale: float, interpret: bool):
+    b, kv, g, s, d = q.shape
+    q_spec, kv_spec, row_spec = _specs(g, block, d)
+    rows = g * block
+    return _call(
+        functools.partial(_fwd_kernel, scale=scale),
+        "causal_attention_fwd",
+        (q, k, v),
+        (jax.ShapeDtypeStruct(q.shape, q.dtype),
+         jax.ShapeDtypeStruct((b, kv, s // block, 1, rows), jnp.float32)),
+        (q_spec, row_spec),
+        [pltpu.VMEM((rows, LANES), jnp.float32), pltpu.VMEM((rows, LANES), jnp.float32),
+         pltpu.VMEM((rows, d), jnp.float32)],
+        [q_spec, kv_spec, kv_spec],
+        block=block,
+        products=2,
+        interpret=interpret,
+    )
+
+
+def _backward(q, k, v, do, lse, delta, block: int, scale: float, interpret: bool):
+    b, kv, g, s, d = q.shape
+    q_spec, kv_spec, row_spec = _specs(g, block, d)
+    whole = pl.BlockSpec((None, None, s, d), lambda b_, h, t, qi, kj: (b_, h, 0, 0))
+    rows = g * block
+    return _call(
+        functools.partial(_bwd_kernel, scale=scale),
+        "causal_attention_bwd",
+        (q, k, v, do, lse, delta),
+        (jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        (q_spec, whole, whole),
+        [pltpu.VMEM((rows, d), jnp.float32), pltpu.VMEM((s, d), jnp.float32),
+         pltpu.VMEM((s, d), jnp.float32)],
+        [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        block=block,
+        products=5,
+        interpret=interpret,
+    )
+
+
+def _query_major(x, kv: int):
+    """``[B, S, H, D]`` as ``[B, KV, G, S, D]``, the layout the kernels read."""
+    b, s, h, d = x.shape
+    return x.reshape(b, s, kv, h // kv, d).transpose(0, 2, 3, 1, 4)
+
+
+def _key_major(x):
+    """``[B, S, KV, D]`` as ``[B, KV, S, D]``, and back."""
+    return x.transpose(0, 2, 1, 3)
+
+
+def _caller_layout(x):
+    """``[B, KV, G, S, D]`` back to ``[B, S, H, D]``."""
+    b, kv, g, s, d = x.shape
+    return x.transpose(0, 3, 1, 2, 4).reshape(b, s, kv * g, d)
+
+
+def _scales(d: int):
+    """``(into q, into the scores)`` of ``1 / sqrt(d)``: a power of two is
+    exact in any floating dtype and goes into q (and out of dQ); any other is
+    applied to the float32 scores inside the kernels."""
+    scale = d ** -0.5
+    return (scale, 1.0) if math.frexp(scale)[0] == 0.5 else (1.0, scale)
+
+
+def _forward_from(q, k, v, block: int, interpret: bool):
+    into_q, scale = _scales(q.shape[-1])
+    q = q * jnp.asarray(into_q, q.dtype)
+    out, lse = _forward(
+        _query_major(q, k.shape[2]), _key_major(k), _key_major(v), block, scale, interpret
+    )
+    return _caller_layout(out), lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _attention(q, k, v, block, interpret):
+    return _forward_from(q, k, v, block, interpret)[0]
+
+
+def _attention_fwd(q, k, v, block, interpret):
+    # The residuals stay in the caller's layout: head-major arrays of head
+    # size 64 are lane-padded to twice their size in HBM.
+    out, lse = _forward_from(q, k, v, block, interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _attention_bwd(block, interpret, residuals, do):
+    q, k, v, out, lse = residuals
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    into_q, scale = _scales(d)
+    into_q = jnp.asarray(into_q, q.dtype)
+    # Σ_d dO·O a row, laid out as the log-sum-exp: [B, KV, S/block, 1, G·block]
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, S, H]
+    delta = delta.reshape(b, s // block, block, kv, h // kv).transpose(0, 3, 1, 4, 2)
+    dq, dk, dv = _backward(
+        _query_major(q * into_q, kv), _key_major(k), _key_major(v), _query_major(do, kv),
+        lse, delta.reshape(lse.shape), block, scale, interpret,
+    )
+    return _caller_layout(dq) * into_q, _key_major(dk), _key_major(dv)
+
+
+_attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+def causal_attention(q, k, v, *, block: int = BLOCK, interpret: bool = False):
+    """Causal ``softmax(q kᵀ / sqrt(D)) v``.  q ``[B, S, H, D]``; k, v
+    ``[B, S, KV, D]`` with each k/v head serving ``H / KV`` query heads
+    (query head ``h`` reads k/v head ``h // (H / KV)``); ``S`` a multiple of
+    ``block``.  Returns ``[B, S, H, D]`` in q's dtype."""
+    if not supported(q.shape[1], block):
+        raise ValueError(
+            f"sequence length {q.shape[1]} is not a multiple of the kernel's block {block} "
+            f"up to {MAX_SEQ}"
+        )
+    return _attention(q, k, v, block, interpret)

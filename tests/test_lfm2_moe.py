@@ -338,7 +338,7 @@ def test_float_ids_are_cast_and_train_mode_is_the_same_function():
     assert set(aux["counters"]["sum"]) == {
         "tokens_per_step", "moe_rows_routed", "moe_rows_offered", "moe_rows_dropped",
     }
-    assert set(aux["counters"]["max"]) == {"moe_max_load"}
+    assert set(aux["counters"]["max"]) == {"moe_max_load", "attention_kernel_layers"}
     assert plain.shape == (2, 1, SEQ, VOCAB) and plain.dtype == jnp.float32
 
 
@@ -476,6 +476,9 @@ def test_trainer_fits_counts_saves_and_restores(tmp_path):
     assert 0 < last["moe_rows_routed"] < last["moe_rows_offered"]
     assert last["moe_max_load"] >= 1.0
     assert trainer.registry.get("ddlpc_moe_rows_routed") is not None
+    # in the record and a gauge; 0: no attention operator lowers to the kernel on the CPU
+    assert last["attention_kernel_layers"] == 0.0
+    assert trainer.registry.get("ddlpc_attention_kernel_layers") is not None
     params = jax.device_get(trainer.state.params)
 
     resumed = Trainer(cfg, resume=True)
