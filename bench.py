@@ -23,17 +23,10 @@ Extra modes (committed artifacts, VERDICT r1 weak #4):
               reports per-device step-time overhead.  CPU wall-clock is not
               TPU wall-clock; this validates semantics + overhead shape, not
               ICI bandwidth.
-  --pipeline-ab  staged (pipe=2) vs unstaged A/B on a virtual CPU mesh:
-              ms/step at M ∈ {2,4,8,16} microbatches with the GPipe model
-              bubble (S-1)/(M+S-1) next to the MEASURED bubble (idle slot
-              fraction of the schedule the driver executed), plus the
-              flagship per-stage HBM evidence.  Prints the
-              pipeline_ms_per_step contract line and writes
-              docs/sharding/pipeline_ab.json.
 
 One process per chip: a parent that has touched JAX holds the chip, so this
-module imports no jax at module level — --scaling and --pipeline-ab spawn
-children and keep the parent off JAX entirely; everything else runs
+module imports no jax at module level — --scaling spawns children and
+keeps the parent off JAX entirely; everything else runs
 in-process and imports the accelerator stack inside the function.
 """
 
@@ -320,8 +313,8 @@ def chip() -> dict:
         raise SystemExit(
             f"bench.py times the chip and found platform "
             f"{devices[0].platform!r}, not 'tpu' — there is no fallback. "
-            f"The CPU-only count harnesses are --scaling, --pipeline-ab "
-            f"and --update-ab --devices N."
+            f"The CPU-only count harnesses are --scaling and --update-ab "
+            f"--devices N."
         )
     return {
         "platform": devices[0].platform,
@@ -642,204 +635,6 @@ def run_update_ab(rounds: int, out_path: str) -> dict:
     }
 
 
-_PIPELINE_AB_CHILD = r"""
-import json, time
-import jax
-from ddlpc_tpu.utils.compat import force_cpu_devices
-force_cpu_devices(8)
-import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
-from ddlpc_tpu.config import (CompressionConfig, DataConfig, ExperimentConfig,
-                              ModelConfig, ParallelConfig, TrainConfig)
-from ddlpc_tpu.models import build_model_from_experiment
-from ddlpc_tpu.parallel.mesh import make_mesh
-from ddlpc_tpu.parallel.pipeline import (bubble_fraction,
-                                         make_pipeline_train_step)
-from ddlpc_tpu.parallel.train_step import create_train_state, make_train_step
-from ddlpc_tpu.train.optim import build_optimizer
-
-S = %(stages)d
-ROWS = 8  # global rows per microbatch, identical in both arms
-H = W = 32
-REPS = %(reps)d
-
-def cfg_for(stages, micro, M):
-    return ExperimentConfig(
-        model=ModelConfig(features=(8, 16), bottleneck_features=16,
-                          num_classes=6),
-        data=DataConfig(image_size=(H, W)),
-        train=TrainConfig(micro_batch_size=micro, sync_period=M),
-        parallel=ParallelConfig(pipeline_stages=stages),
-        compression=CompressionConfig(mode='none'))
-
-def timed(fn):
-    fn(); fn()  # compile + settle
-    ts = []
-    for _ in range(REPS):
-        t0 = time.perf_counter(); fn(); ts.append(time.perf_counter() - t0)
-    return float(np.median(ts)) * 1e3
-
-rng = np.random.default_rng(0)
-rows = []
-for M in (2, 4, 8, 16):
-    images = rng.uniform(0, 1, (M, ROWS, H, W, 3)).astype(np.float32)
-    labels = rng.integers(0, 6, (M, ROWS, H, W)).astype(np.int32)
-
-    # Unstaged arm: all 8 devices on the data axis, the same M microbatches
-    # folded into the train step's accumulation scan (sync_period=M).
-    cfg = cfg_for(1, ROWS // 8, M)
-    mesh = make_mesh(cfg.parallel)
-    model = build_model_from_experiment(cfg)
-    tx = build_optimizer(cfg.train)
-    state = create_train_state(model, tx, jax.random.key(0), (1, H, W, 3))
-    step = make_train_step(model, tx, mesh, cfg.compression,
-                           donate_state=False)
-    im = jax.device_put(images, NamedSharding(mesh, P(None, 'data')))
-    lb = jax.device_put(labels, NamedSharding(mesh, P(None, 'data')))
-    def mono(step=step, state=state, im=im, lb=lb):
-        _, m = step(state, im, lb)
-        float(m['loss'])
-    t_mono = timed(mono)
-
-    # Staged arm: pipe=S x data=8/S, M round-robin microbatches.  The
-    # driver's per-stage updates donate their buffers, so the state must
-    # thread through (holder) rather than replay a donated pstate.
-    cfgp = cfg_for(S, ROWS // (8 // S), M)
-    meshp = make_mesh(cfgp.parallel)
-    modelp = build_model_from_experiment(cfgp)
-    txp = build_optimizer(cfgp.train)
-    statep = create_train_state(modelp, txp, jax.random.key(0), (1, H, W, 3))
-    drv = make_pipeline_train_step(modelp, txp, meshp, cfgp.compression,
-                                   n_microbatches=M)
-    holder = [drv.init_state(statep)]
-    def staged(drv=drv, holder=holder, images=images, labels=labels):
-        holder[0], _ = drv.step(holder[0], images, labels)
-    t_pipe = timed(staged)
-    rows.append({'n_microbatches': M, 'staged_ms_per_step': round(t_pipe, 3),
-                 'unstaged_ms_per_step': round(t_mono, 3),
-                 'measured_bubble': drv.last_schedule['measured_bubble'],
-                 'model_bubble': round(bubble_fraction(S, M), 4),
-                 'executed_slots': drv.last_schedule['executed_slots'],
-                 'idle_slots': drv.last_schedule['idle_slots']})
-print(json.dumps({'rows': rows, 'stages': S, 'rows_per_microbatch': ROWS,
-                  'devices': len(jax.devices())}))
-"""
-
-
-def run_pipeline_ab(rounds: int, out_path: str, stages: int = 2) -> dict:
-    """Staged-vs-unstaged A/B on an 8-way virtual CPU mesh (child process,
-    run_scaling's re-exec idiom): same model, same global rows per
-    microbatch, ms/step at M ∈ {2,4,8,16} microbatches.  Each row carries
-    the GPipe MODEL bubble (S-1)/(M+S-1) next to the MEASURED bubble: the
-    idle fraction of the (stage × cycle) slot grid counted off the
-    round-robin schedule the driver actually executed
-    (PipelineTrainStep.last_schedule) — a fill/drain bug dispatches fewer
-    slots per cycle and the measured column jumps while the closed form
-    stays put.  The measured column must shrink as M grows.  CPU
-    wall-clock carries no idle signal (every virtual device shares the
-    host cores), so it prices dispatch + compute overhead
-    (``overhead_vs_unstaged``), not the bubble, and not TPU step time.
-    Embeds the flagship per-stage HBM evidence from the committed
-    hbm_report (the ≤0.55× params+grads+opt bar), writes ``out_path``
-    (schema-stamped kind="pipeline" rows), and returns the
-    ``pipeline_ms_per_step`` driver-contract record (largest-M arm)."""
-    import os
-    import subprocess
-    import sys
-
-    from ddlpc_tpu.obs import schema as obs_schema
-
-    code = _PIPELINE_AB_CHILD % {"stages": stages, "reps": max(rounds, 3)}
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        cwd=os.path.dirname(os.path.abspath(__file__)),
-        capture_output=True,
-        text=True,
-        timeout=1800,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"),
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(f"pipeline A/B child failed:\n{proc.stderr[-2000:]}")
-    data = json.loads(proc.stdout.strip().splitlines()[-1])
-    rows, S = data["rows"], data["stages"]
-    for r in rows:
-        r["overhead_vs_unstaged"] = round(
-            r["staged_ms_per_step"] / r["unstaged_ms_per_step"], 3
-        )
-        r["stages"] = S
-        r["devices"] = data["devices"]
-        obs_schema.stamp(r, kind="pipeline")
-    bubbles = [r["measured_bubble"] for r in rows]
-    if bubbles != sorted(bubbles, reverse=True):
-        raise RuntimeError(
-            f"measured bubble fraction must shrink with microbatch count, "
-            f"got {bubbles} — the round-robin schedule is not amortizing "
-            f"its fill/drain"
-        )
-
-    # The memory side of the trade: the committed flagship hbm_report's
-    # staged arms (scripts/hbm_report.py --layout pipe2 ...) — max-stage
-    # params+grads+opt_state vs the replicated unstaged baseline, the
-    # "does the model fit" number pipelining exists to cut.
-    hbm = None
-    hbm_path = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)),
-        "docs", "sharding", "hbm_report.json",
-    )
-    try:
-        with open(hbm_path) as f:
-            rep = json.load(f)
-        off = rep["arms"]["off"]["state_bytes_per_device"]
-        base = off["params"] + off["grads"] + off["opt_state"]
-        ratios = {}
-        for name, arm in rep["arms"].items():
-            if not name.startswith("pipe"):
-                continue
-            b = arm["state_bytes_per_device"]
-            ratios[name] = round(
-                (b["params"] + b["grads"] + b["opt_state"]) / base, 4
-            )
-        if ratios:
-            hbm = {
-                "source": "docs/sharding/hbm_report.json",
-                "config": rep.get("config"),
-                "max_stage_params_grads_opt_vs_unstaged_x": ratios,
-            }
-    except (OSError, KeyError, ValueError):
-        pass  # artifact absent/stale: the timing table stands alone
-
-    report = {
-        "bench": "pipeline_ab",
-        "stages": S,
-        "devices": data["devices"],
-        "rows_per_microbatch": data["rows_per_microbatch"],
-        "backend": "cpu",
-        "note": (
-            "CPU mesh: measured_bubble is the executed schedule's idle "
-            "(stage x cycle) slot fraction; wall-clock columns price "
-            "host dispatch + compute, not ICI bandwidth"
-        ),
-        "rows": rows,
-        "hbm": hbm,
-    }
-    if out_path:
-        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
-        with open(out_path, "w") as f:
-            json.dump(report, f, indent=2)
-    best = rows[-1]
-    return {
-        "metric": "pipeline_ms_per_step",
-        "value": best["staged_ms_per_step"],
-        "unit": "ms",
-        "n_microbatches": best["n_microbatches"],
-        "unstaged_ms_per_step": best["unstaged_ms_per_step"],
-        "measured_bubble": best["measured_bubble"],
-        "model_bubble": best["model_bubble"],
-        "stages": S,
-        "devices": data["devices"],
-    }
-
-
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--all", action="store_true", help="run the whole zoo")
@@ -865,18 +660,6 @@ def main() -> None:
         help="committed artifact path for --update-ab",
     )
     p.add_argument(
-        "--pipeline-ab",
-        action="store_true",
-        help="A/B staged (pipe=2) vs unstaged execution on a virtual CPU "
-        "mesh (bubble-fraction table) and print the pipeline_ms_per_step "
-        "contract line",
-    )
-    p.add_argument(
-        "--pipeline-ab-out",
-        default="docs/sharding/pipeline_ab.json",
-        help="committed artifact path for --pipeline-ab ('' skips writing)",
-    )
-    p.add_argument(
         "--devices",
         type=int,
         default=0,
@@ -890,11 +673,6 @@ def main() -> None:
         # Runs entirely in CPU-pinned children; the parent stays off JAX.
         for rec in run_scaling():
             print(json.dumps(rec))
-        return
-
-    if args.pipeline_ab:
-        # Same: one CPU-pinned child, no JAX in the parent.
-        print(json.dumps(run_pipeline_ab(args.rounds, args.pipeline_ab_out)))
         return
 
     from ddlpc_tpu.utils.compile_cache import enable_compile_cache

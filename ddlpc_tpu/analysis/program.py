@@ -41,17 +41,7 @@ Contracts checked absolutely (no baseline needed):
   bytes wasted per device;
 - ``donation`` — every ``donate_argnums`` leaf is input/output-aliased
   in the compiled module (the HBM the donation was supposed to save is
-  reported when it is not);
-- ``stage-boundary`` (pipeline arms, ``parallel/pipeline.py``) — a
-  staged forward/backward SEGMENT owns no gradient wire: its only
-  collectives are the sync-BN stat pmeans (small f32 all-reduces,
-  budgeted against the stage's batch-stat element count), it carries
-  zero fences, and no inter-stage carry leaf leaves the stage wider
-  than the model compute dtype (cross-stage dtype widening is exactly
-  the regression a quantized-wire pipeline must not hide).  The
-  per-stage UPDATE program is audited under all five contracts above,
-  with the closed form evaluated on the stage's param subtree over the
-  stage group's data-axis size.
+  reported when it is not).
 
 Everything else (collective counts, argument/output bytes, entry dtype
 census) is pinned by the committed per-config baseline
@@ -121,7 +111,6 @@ class Arm:
     spatial: bool = False           # data×space mesh, GSPMD step
     serve_quantize: str = "off"     # serve arms only
     bucket_mb: float = 0.0          # comm/compute overlap bucket target
-    pipeline_stages: int = 1        # pipe mesh axis (parallel/pipeline.py)
 
     @property
     def comm_variant(self) -> Optional[str]:
@@ -135,18 +124,14 @@ class Arm:
             return self.shard_update
         return "allreduce"
 
-    def declared_wire_dtype(self, axis_size: Optional[int] = None) -> str:
+    def declared_wire_dtype(self) -> str:
         """The dtype the arm CLAIMS is on the wire.  The ring transport
         puts real quantized integers on every hop; the fused simulate
         path puts the lattice itself on the collective operand wherever
         the sums fit the narrow dtype exactly — the declaration mirrors
         ``grad_sync.simulate_wire_dtype`` (the single source of truth for
         when the fusion engages) and the HLO dtype-flow + closed-form
-        contracts are what prove it.  ``axis_size`` overrides the default
-        audit topology — pipeline stage groups sync over AXIS_SIZE/S
-        replicas, and whether the sums fit the narrow dtype depends on
-        how many replicas are summed."""
-        axis_size = AXIS_SIZE if axis_size is None else axis_size
+        contracts are what prove it."""
         if self.transport == "ring" and self.mode != "none":
             import jax.numpy as jnp
 
@@ -155,12 +140,12 @@ class Arm:
 
             comp = self.compression()
             return hlo_mod.hlo_dtype_name(
-                jnp.dtype(wire_dtype(axis_size, levels_for(comp)))
+                jnp.dtype(wire_dtype(AXIS_SIZE, levels_for(comp)))
             )
         if self.comm_variant in ("allreduce", "scatter", "zero1", "zero3"):
             from ddlpc_tpu.obs.comm import simulate_wire_row
 
-            name, _ = simulate_wire_row(self.compression(), axis_size)
+            name, _ = simulate_wire_row(self.compression(), AXIS_SIZE)
             return name
         return "f32"
 
@@ -219,15 +204,6 @@ ARMS: Dict[str, Arm] = {
             bucket_mb=0.02),
         Arm("fp16_bucketed_gspmd", mode="float16", spatial=True,
             quantize_local=False, bucket_mb=0.02),
-        # MPMD pipeline arms (parallel/pipeline.py): the 8-device mesh
-        # splits pipe=2 × data=4; each arm audits its per-stage
-        # forward/backward segments (stage-boundary contract: no wire,
-        # no widening, no fences) and per-stage update programs (the
-        # full five contracts, closed form on the stage param subtree
-        # over the 4-replica stage group).
-        Arm("pipe2_none", pipeline_stages=2),
-        Arm("pipe2_int8_zero2", mode="int8", shard_update="zero2",
-            pipeline_stages=2),
         Arm("serve_fp32"),
         Arm("serve_int8", serve_quantize="int8"),
         Arm("serve_bf16", serve_quantize="bf16"),
@@ -255,17 +231,6 @@ def _program_table() -> Dict[str, Tuple[str, str]]:
             out[f"{name}/forward"] = (name, "serve_forward")
         elif name.startswith("eval"):
             out[f"{name}/eval_step"] = (name, "eval_step")
-        elif arm.pipeline_stages > 1:
-            # Staged MPMD programs: the driver's own per-stage segments
-            # (the last stage folds forward+loss+backward into one
-            # program) plus every stage's update.
-            S = arm.pipeline_stages
-            for s in range(S - 1):
-                out[f"{name}/stage{s}_fwd"] = (name, "stage_fwd")
-                out[f"{name}/stage{s}_bwd"] = (name, "stage_bwd")
-            out[f"{name}/stage{S - 1}_loss_bwd"] = (name, "stage_bwd")
-            for s in range(S):
-                out[f"{name}/stage{s}_update"] = (name, "stage_update")
         else:
             if not arm.spatial:
                 out[f"{name}/update_step"] = (name, "update_step")
@@ -301,7 +266,6 @@ def _tiny_experiment(arm: Arm):
     parallel = ParallelConfig(
         data_axis_size=SPATIAL_DATA if arm.spatial else -1,
         space_axis_size=SPATIAL_SPACE if arm.spatial else 1,
-        pipeline_stages=arm.pipeline_stages,
     )
     return ExperimentConfig(
         model=ModelConfig(
@@ -425,19 +389,6 @@ class Declared:
     # tree of per-leaf expected shard element counts (None = skip audit)
     sharding_in: Any = None
     sharding_out: Any = None
-    # -- pipeline stage programs (parallel/pipeline.py) ------------------
-    # Donated args that are CONSUMED, not aliased: the stage update
-    # donates the stacked grad accumulator whose buffer has no
-    # same-shaped output — the donation frees it for scratch reuse, so
-    # requiring an input_output_alias would mislabel a real HBM win.
-    donated_freed_args: Tuple[int, ...] = ()
-    # stage segments: the model compute dtype every inter-stage carry
-    # leaf must stay within, the output indices that cross the boundary,
-    # and the stage's batch-stat element count (the sync-BN pmean budget
-    # check_stage_segment holds segment collectives to).
-    carry_dtype: Optional[str] = None
-    carry_out_idx: Tuple[int, ...] = ()
-    stats_elements: int = 0
 
 
 @dataclass
@@ -465,11 +416,8 @@ def expected_fences(arm: Arm, kind: str, n_buckets: int = 1) -> int:
     grad_bucket_groups of the audited tree), each inside its own fence
     pair: the fused wire encode keeps apply_codec_fenced's cut points
     and count, the dequantize is deliberately unfenced (one scalar
-    multiply cannot FMA-contract — grad_sync._fenced_wire_encode).
-    Pipeline stage SEGMENTS (stage_fwd/stage_bwd) carry zero fences —
-    all codec and update fencing lives in the per-stage update, whose
-    count follows the update_step rules on the stage's bucket count."""
-    if kind in ("eval_step", "serve_forward", "stage_fwd", "stage_bwd"):
+    multiply cannot FMA-contract — grad_sync._fenced_wire_encode)."""
+    if kind in ("eval_step", "serve_forward"):
         return 0
     fences = 2  # _fenced_update pins the optimizer chain
     quantizing = arm.mode != "none"
@@ -580,8 +528,6 @@ def build_program(name: str) -> ProgramBundle:
 
     if kind == "serve_forward":
         return _build_serve(name, arm, cfg)
-    if kind in ("stage_fwd", "stage_bwd", "stage_update"):
-        return _build_stage_program(name, arm, kind)
 
     mesh = _mesh_for(arm)
     model, tx, state = _abstract_state(cfg, mesh)
@@ -760,201 +706,6 @@ def build_program(name: str) -> ProgramBundle:
     return ProgramBundle(
         name, arm, kind, fn, (state_avals, images, labels), declared
     )
-
-
-# --------------------------------------------------------------------------
-# pipeline stage programs (parallel/pipeline.py)
-# --------------------------------------------------------------------------
-
-# One driver per pipeline arm, built lazily and kept for the process —
-# every stage program of the arm lowers out of the SAME driver instance
-# (the real programs the schedule dispatches, not lookalikes), and the
-# tiny-model state it splits is materialized once, not per program.
-_PIPE_CACHE: Dict[str, Tuple] = {}
-
-
-def _pipe_driver(arm: Arm):
-    """(cfg, driver, placed PipelineState) for a pipeline arm on the
-    tiny model.  Unlike the flat arms this MATERIALIZES the tiny state:
-    the driver's ``init_state`` is the only code path that builds the
-    stage plan, splits params/stats/opt and constructs the per-stage
-    jitted programs — auditing anything else would audit a fork."""
-    if arm.name in _PIPE_CACHE:
-        return _PIPE_CACHE[arm.name]
-    import jax
-
-    from ddlpc_tpu.models import build_model_from_experiment
-    from ddlpc_tpu.parallel.pipeline import make_pipeline_train_step
-    from ddlpc_tpu.parallel.train_step import create_train_state
-    from ddlpc_tpu.train.optim import build_optimizer
-
-    cfg = _tiny_experiment(arm)
-    mesh = _mesh_for(arm)
-    model = build_model_from_experiment(cfg)
-    tx = build_optimizer(cfg.train)
-    h, w = cfg.data.image_size
-    state = create_train_state(model, tx, jax.random.key(0), (1, h, w, 3))
-    drv = make_pipeline_train_step(
-        model, tx, mesh, cfg.compression,
-        n_microbatches=cfg.train.sync_period,
-        shard_update=arm.shard_update, seed=cfg.train.seed,
-    )
-    pstate = drv.init_state(state)
-    _PIPE_CACHE[arm.name] = (cfg, drv, pstate)
-    return _PIPE_CACHE[arm.name]
-
-
-def _avals_of(tree):
-    import jax
-
-    return jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree
-    )
-
-
-def _build_stage_program(name: str, arm: Arm, kind: str) -> ProgramBundle:
-    """Bundle one of the pipeline driver's per-stage programs.  Segments
-    (stage_fwd / stage_bwd / the last stage's fused loss_bwd) get the
-    stage-boundary contract — zero fences, stat-sync-only collectives,
-    carry leaves no wider than the model compute dtype; the per-stage
-    update gets the full update_step treatment with the closed form on
-    the stage param subtree at the stage group's axis size."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from ddlpc_tpu.parallel import shard_update as zero
-    from ddlpc_tpu.parallel.grad_sync import grad_bucket_groups
-
-    cfg, drv, pstate = _pipe_driver(arm)
-    seg = name.rsplit("/", 1)[1]            # e.g. "stage0_fwd"
-    s = int(seg[len("stage"):].split("_", 1)[0])
-    S, nd = drv.n_stages, drv._n_data
-    mesh_s = drv._meshes[s]
-    h, w = cfg.data.image_size
-    B = cfg.train.micro_batch_size * nd     # one global microbatch
-    params_av = _avals_of(drv._p_split[s])
-    stats_av = _avals_of(drv._s_split[s])
-    carries = drv.carry_avals((B, h, w, 3))
-    gacc_av = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct((nd,) + tuple(a.shape), jnp.float32),
-        params_av,
-    )
-    carry_name = hlo_mod.hlo_dtype_name(jnp.dtype(drv.model.dtype))
-
-    def data_tree(tree):
-        return _named_tree(
-            mesh_s, jax.tree.map(lambda _: P("data"), tree), tree
-        )
-
-    cin_av = (
-        jax.ShapeDtypeStruct((B, h, w, 3), jnp.float32)
-        if s == 0
-        else carries[s - 1]
-    )
-    declared = Declared(
-        fences=expected_fences(arm, kind),
-        axis_size=nd,
-        stats_elements=_tree_elements(stats_av),
-        carry_dtype=carry_name,
-    )
-
-    if kind == "stage_fwd":
-        declared.carry_out_idx = (0,)
-        declared.sharding_in = (
-            _repl_tree(params_av), _repl_tree(stats_av), data_tree(cin_av)
-        )
-        declared.sharding_out = (data_tree(carries[s]), _repl_tree(stats_av))
-        return ProgramBundle(
-            name, arm, kind, drv._fwd[s],
-            (params_av, stats_av, cin_av), declared,
-        )
-
-    if kind == "stage_bwd":
-        if seg.endswith("loss_bwd"):
-            # Last stage: forward + loss + backward in one program; loss
-            # and pixel-acc leave stacked per replica (host averages) so
-            # the segment stays inside the stage-boundary contract.
-            labels_av = jax.ShapeDtypeStruct((B, h, w), jnp.int32)
-            declared.carry_out_idx = (2,)   # dcin back to stage s-1
-            declared.donated_args = (4,)    # gacc
-            declared.sharding_in = (
-                _repl_tree(params_av), _repl_tree(stats_av),
-                data_tree(cin_av), data_tree(labels_av), data_tree(gacc_av),
-            )
-            declared.sharding_out = (
-                1, 1, data_tree(carries[s - 1]), _repl_tree(stats_av),
-                data_tree(gacc_av),
-            )
-            return ProgramBundle(
-                name, arm, kind, drv._bwd[s],
-                (params_av, stats_av, cin_av, labels_av, gacc_av), declared,
-            )
-        dout_av = carries[s]
-        declared.donated_args = (4,)
-        # Stage 0's carry cotangent is a scalar stub (nothing upstream
-        # consumes it) — only interior stages ship a real dcin.
-        declared.carry_out_idx = () if s == 0 else (0,)
-        dcin_elems = 1 if s == 0 else data_tree(carries[s - 1])
-        declared.sharding_in = (
-            _repl_tree(params_av), _repl_tree(stats_av),
-            data_tree(cin_av), data_tree(dout_av), data_tree(gacc_av),
-        )
-        declared.sharding_out = (dcin_elems, data_tree(gacc_av))
-        return ProgramBundle(
-            name, arm, kind, drv._bwd[s],
-            (params_av, stats_av, cin_av, dout_av, gacc_av), declared,
-        )
-
-    # stage_update: the exact make_update_step wire + fenced update on
-    # the stage's param subtree, within the nd-replica stage group.
-    st_av = _avals_of(pstate.stages[s])
-    comp = arm.compression()
-    n_grad = _tree_elements(params_av)
-    n_buckets = len(grad_bucket_groups(drv._p_split[s], comp.bucket_mb))
-    level = drv._level
-    declared.comm_variant = arm.comm_variant
-    declared.wire_dtype = arm.declared_wire_dtype(axis_size=nd)
-    declared.fences = expected_fences(arm, kind, n_buckets)
-    declared.n_grad = n_grad
-    declared.n_param = n_grad
-    declared.n_buckets = n_buckets
-    declared.donated_args = (0, 1, 2)
-    declared.donated_freed_args = (2,)  # gacc: consumed, no alias target
-    declared.carry_dtype = None         # no carry leaves this program
-    quantizing = comp.mode != "none"
-    fused = declared.wire_dtype != "f32" and arm.comm_variant in (
-        "allreduce", "scatter", "zero1"
-    )
-    if arm.comm_variant == "allreduce":
-        declared.scale_collectives = n_buckets if fused else 0
-    param_elems = _repl_tree(params_av)
-    opt_elems = _repl_tree(st_av.opt_state)
-    if level != "off":
-        declared.ag_pad_bytes = _chunk_padding_bytes(params_av, nd, 4)
-        if level == "zero2":
-            wire_item = hlo_mod.max_operand_itemsize(declared.wire_dtype)
-            declared.rs_pad_bytes = _chunk_padding_bytes(
-                params_av, nd, wire_item
-            )
-            declared.scale_collectives = n_buckets * (
-                int(fused) + int(quantizing and comp.quantize_mean)
-            )
-        opt_spec = _respec_chunked(
-            zero.opt_partition_specs(drv.tx, drv._p_split[s], level, "data"),
-            st_av.opt_state,
-        )
-        opt_elems = _named_tree(mesh_s, opt_spec, st_av.opt_state)
-    step_av = jax.ShapeDtypeStruct((), jnp.int32)
-    declared.sharding_in = (
-        param_elems, opt_elems, data_tree(gacc_av), _repl_tree(stats_av), 1
-    )
-    declared.sharding_out = (
-        param_elems, opt_elems, _repl_tree(stats_av), 1, 1
-    )
-    avals = (st_av.params, st_av.opt_state, gacc_av, st_av.batch_stats,
-             step_av)
-    return ProgramBundle(name, arm, kind, drv._upd[s], avals, declared)
 
 
 def _respec_chunked(spec_tree, chunked_avals):
@@ -1342,90 +1093,13 @@ def check_dtype_flow(
     return out
 
 
-def check_stage_segment(
-    bundle: ProgramBundle, rows: List[Dict[str, object]], level: str
-) -> List[ProgramViolation]:
-    """The stage-boundary collective contract: a pipeline segment owns
-    no gradient wire.  Its only admissible collectives are the sync-BN
-    stat pmeans — small f32 all-reduces whose total element count is
-    budgeted at 4× the stage's batch-stat elements (forward pmeans the
-    fresh mean/var per norm layer; the backward recompute re-runs them
-    and their transposes — still stat-shaped).  Anything else (a
-    reduce-scatter, an all-gather, a permute, or an all-reduce moving a
-    gradient-sized payload) means gradient traffic leaked out of the
-    stage update and into a segment."""
-    d = bundle.declared
-    out: List[ProgramViolation] = []
-    budget = 4 * d.stats_elements
-    total = 0
-    for r in rows:
-        if r["kind"] != "all-reduce" or str(r["dtype"]) != "f32":
-            out.append(
-                ProgramViolation(
-                    bundle.name, "stage-boundary",
-                    f"{level} census has {r['kind']}[{r['dtype']}] inside "
-                    f"a pipeline stage segment — segments own no gradient "
-                    f"wire; every collective belongs to the stage update "
-                    f"and the only cross-stage traffic is the host-driven "
-                    f"carry send",
-                )
-            )
-            continue
-        total += int(r["elements"])
-    if total > budget:
-        out.append(
-            ProgramViolation(
-                bundle.name, "stage-boundary",
-                f"{level} segment all-reduces move {total} f32 elements, "
-                f"over the sync-BN stat budget {budget} (4 × "
-                f"{d.stats_elements} stage batch-stat elements) — a "
-                f"gradient-sized payload leaked into a stage segment",
-            )
-        )
-    return out
-
-
-def check_carry_dtypes(
-    bundle: ProgramBundle, out_shape
-) -> List[ProgramViolation]:
-    """No inter-stage carry leaf may leave a segment wider than the
-    model compute dtype (``declared.carry_dtype``): the carry is the
-    stage boundary's whole payload, and silently promoting it to f32
-    doubles the activation-send and GPipe-stash bytes the HBM pricing
-    (obs/hbm.py) and the A/B's claims rest on."""
-    import jax
-
-    d = bundle.declared
-    if d.carry_dtype is None or not d.carry_out_idx:
-        return []
-    limit = hlo_mod.max_operand_itemsize(d.carry_dtype)
-    outs = out_shape if isinstance(out_shape, (tuple, list)) else (out_shape,)
-    out: List[ProgramViolation] = []
-    for idx in d.carry_out_idx:
-        for leaf in jax.tree_util.tree_leaves(outs[idx]):
-            dt = hlo_mod.hlo_dtype_name(leaf.dtype)
-            if hlo_mod.max_operand_itemsize(dt) > limit:
-                out.append(
-                    ProgramViolation(
-                        bundle.name, "stage-boundary",
-                        f"inter-stage carry leaf {dt}{list(leaf.shape)} is "
-                        f"wider than the declared boundary dtype "
-                        f"{d.carry_dtype} — cross-stage dtype widening "
-                        f"(output {idx})",
-                    )
-                )
-    return out
-
-
 def _jaxpr_wire_rows(
     bundle: ProgramBundle, census: List[Dict[str, object]]
 ) -> Optional[List[Dict[str, object]]]:
     """jaxpr census rows usable for the comm/dtype checks.  Only the
     update program's census is pure wire (train/eval programs interleave
     batch-stat and metric collectives, which only HLO metadata can
-    separate; the pipeline stage_update pmeans stats and keeps the norm
-    psum live, so its wire checks run on the HLO census' classified
-    rows)."""
+    separate)."""
     if bundle.kind != "update_step":
         return None
     return census
@@ -1481,11 +1155,6 @@ def _audit_traced(bundle, audit: ProgramAudit, fast: bool) -> ProgramAudit:
         audit.violations.extend(
             check_dtype_flow(bundle, wire_rows, "jaxpr")
         )
-    if bundle.kind in ("stage_fwd", "stage_bwd"):
-        audit.violations.extend(
-            check_stage_segment(bundle, audit.jaxpr_census, "jaxpr")
-        )
-        audit.violations.extend(check_carry_dtypes(bundle, out_shape))
     if fast:
         return audit
 
@@ -1518,10 +1187,6 @@ def _audit_traced(bundle, audit: ProgramAudit, fast: bool) -> ProgramAudit:
     hlo_wire = [r for r in audit.hlo_census if r.get("group") == "wire"]
     audit.violations.extend(check_comm_closed_form(bundle, hlo_wire, "hlo"))
     audit.violations.extend(check_dtype_flow(bundle, hlo_wire, "hlo"))
-    if bundle.kind in ("stage_fwd", "stage_bwd"):
-        audit.violations.extend(
-            check_stage_segment(bundle, audit.hlo_census, "hlo")
-        )
     _audit_donation(bundle, compiled, module, audit)
     _audit_sharding(bundle, compiled, audit, out_shape)
     return audit
@@ -1594,12 +1259,6 @@ def _audit_donation(bundle, compiled, module, audit: ProgramAudit) -> None:
             if p in aliased_params:
                 aliased_leaves += 1
                 aliased_bytes += module.entry_params[p].bytes
-            elif arg_idx in d.donated_freed_args:
-                # Consumed-not-aliased by declaration (e.g. the stage
-                # update's stacked grad accumulator: no same-shaped
-                # output exists; the donation frees the buffer for
-                # scratch reuse, which is the intended HBM win).
-                continue
             else:
                 audit.violations.append(
                     ProgramViolation(

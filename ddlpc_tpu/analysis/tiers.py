@@ -142,7 +142,6 @@ MODULE_TIERS: Dict[str, str] = {
     "ddlpc_tpu.parallel.grad_sync": JAX,
     "ddlpc_tpu.parallel.compressed_allreduce": JAX,
     "ddlpc_tpu.parallel.partition": JAX,
-    "ddlpc_tpu.parallel.pipeline": JAX,
     "ddlpc_tpu.parallel.shard_update": JAX,
     "ddlpc_tpu.parallel.train_step": JAX,
     "ddlpc_tpu.train": JAX,

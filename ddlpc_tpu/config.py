@@ -360,27 +360,6 @@ class ParallelConfig:
     #   (always stored gathered); every layout restores from every
     #   other's blobs bit-identically.
     shard_update: str = "auto"  # auto | on | off | zero1 | zero2 | zero3
-    # MPMD pipeline parallelism (docs/SHARDING.md "Pipeline stages",
-    # arxiv 2412.14374): cut the encoder–decoder into `pipeline_stages`
-    # contiguous block groups (parallel/partition.py stage rules) and map
-    # each group onto its own (data, space) sub-mesh along a third `pipe`
-    # mesh axis (parallel/mesh.py).  1 (default) = unstaged — the mesh
-    # and every compiled program are bit-identical to pre-pipeline
-    # revisions (test-pinned).  Values > 1 must divide the device count
-    # after the space axis takes its share; the stage cut is chosen by
-    # balanced per-block parameter bytes, so per-device resident
-    # params+grads+moments shrink toward 1/stages (obs/hbm.py prices it,
-    # bench.py --pipeline-ab measures it).
-    pipeline_stages: int = 1
-    # Microbatches per optimizer step under the GPipe round-robin
-    # schedule (parallel/pipeline.py): the bubble fraction is
-    # (S-1)/(M+S-1), so more microbatches amortize the fill/drain bubble
-    # (кластер.py's 50-step gradient accumulation is exactly this stream).
-    # 0 (default) resolves to `pipeline_stages` when staged; ignored at
-    # pipeline_stages=1, where TrainConfig.sync_period already plays the
-    # accumulation role.
-    pipeline_microbatches: int = 0
-    pipe_axis_name: str = "pipe"
 
 
 @dataclass(frozen=True)
@@ -735,6 +714,40 @@ class FleetConfig:
         )
 
 
+# Keys that a past revision's Trainer wrote into ``<workdir>/config.json``
+# (it writes every field) and a later revision removed, per config class:
+# key -> (the values under which the removed code did nothing, or None where
+# it did nothing at any value; what was removed).  A run directory written
+# with an inert value still loads; any other value asked for behaviour that
+# no longer exists and is refused by name.
+_PIPELINE = "the host-driven pipeline, removed in PR 29"
+_RETIRED_KEYS: dict[str, dict[str, tuple[Any, str]]] = {
+    "ParallelConfig": {
+        "pipeline_stages": ((1,), _PIPELINE),
+        # Both were read only at pipeline_stages > 1, which is refused above.
+        "pipeline_microbatches": (None, _PIPELINE),
+        "pipe_axis_name": (None, _PIPELINE),
+    },
+}
+
+
+def _drop_retired_key(class_name: str, key: str, value: Any) -> bool:
+    """True where ``key`` is a retired key of ``class_name`` holding an inert
+    value (the caller drops it); raises where it holds any other value;
+    False where the key was never retired."""
+    retired = _RETIRED_KEYS.get(class_name, {}).get(key)
+    if retired is None:
+        return False
+    inert, what = retired
+    if inert is not None and value not in inert:
+        raise ValueError(
+            f"config key {class_name}.{key}={value!r} belongs to {what}: "
+            f"only {' / '.join(repr(v) for v in inert)} (under which it did "
+            f"nothing) still loads"
+        )
+    return True
+
+
 @dataclass(frozen=True)
 class ExperimentConfig:
     model: ModelConfig = field(default_factory=ModelConfig)
@@ -759,6 +772,8 @@ class ExperimentConfig:
             kwargs = {}
             for k, v in sub.items():
                 if k not in fields:
+                    if _drop_retired_key(klass.__name__, k, v):
+                        continue
                     raise ValueError(f"unknown config key {klass.__name__}.{k}")
                 if isinstance(v, list):
                     v = tuple(v)

@@ -387,16 +387,7 @@ def upsample_2x(x: jax.Array, method: str = "bilinear") -> jax.Array:
 
 class UpBlock(nn.Module):
     """2× upsample (transposed conv or bilinear), concat skip(s), DoubleConv
-    (reference UpBlock, кластер.py:603-617).
-
-    ``phase`` exists for pipeline staging (parallel/pipeline.py): the
-    decoder's DoubleConvs are the heaviest modules in the tree, so block
-    granularity alone cannot balance a 2-stage cut — ``'up'`` runs just
-    upsample+concat (returns the concatenated tensor), ``'conv'`` runs
-    just the DoubleConv on it.  ``'all'`` (default, and the only path the
-    unstaged builders take) is both in one call — explicit submodule
-    names pin the param tree identical across phases, so checkpoints and
-    stage rule tables agree regardless of where the cut lands."""
+    (reference UpBlock, кластер.py:603-617)."""
 
     features: int
     up_sample_mode: str = "conv_transpose"
@@ -406,32 +397,23 @@ class UpBlock(nn.Module):
     dtype: Dtype = jnp.bfloat16
 
     @nn.compact
-    def __call__(
-        self, x: jax.Array, skips, train: bool = True, phase: str = "all"
-    ) -> jax.Array:
-        if phase not in ("all", "up", "conv"):
-            raise ValueError(f"unknown UpBlock phase {phase!r}")
-        if phase in ("all", "up"):
-            if self.up_sample_mode == "conv_transpose":
-                x = nn.ConvTranspose(
-                    self.features,
-                    kernel_size=(2, 2),
-                    strides=(2, 2),
-                    dtype=self.dtype,
-                    param_dtype=jnp.float32,
-                    name="ConvTranspose_0",
-                )(x)
-            elif self.up_sample_mode == "bilinear":
-                x = upsample_2x(x, "bilinear")
-            else:
-                raise ValueError(
-                    f"unknown up_sample_mode {self.up_sample_mode!r}"
-                )
-            if not isinstance(skips, (list, tuple)):
-                skips = (skips,)
-            x = jnp.concatenate([*skips, x], axis=-1)
-            if phase == "up":
-                return x
+    def __call__(self, x: jax.Array, skips, train: bool = True) -> jax.Array:
+        if self.up_sample_mode == "conv_transpose":
+            x = nn.ConvTranspose(
+                self.features,
+                kernel_size=(2, 2),
+                strides=(2, 2),
+                dtype=self.dtype,
+                param_dtype=jnp.float32,
+                name="ConvTranspose_0",
+            )(x)
+        elif self.up_sample_mode == "bilinear":
+            x = upsample_2x(x, "bilinear")
+        else:
+            raise ValueError(f"unknown up_sample_mode {self.up_sample_mode!r}")
+        if not isinstance(skips, (list, tuple)):
+            skips = (skips,)
+        x = jnp.concatenate([*skips, x], axis=-1)
         return DoubleConv(
             self.features,
             norm=self.norm,

@@ -107,39 +107,6 @@ def state_hbm_bytes(
     }
 
 
-def pipeline_stage_hbm_bytes(stage_states, level: str = "off", n_shards: int = 1):
-    """Per-stage, per-device byte breakdowns for a staged run
-    (parallel/pipeline.py): each stage's placed TrainState priced by the
-    same shape × committed-sharding math, with ``level``/``n_shards`` the
-    ZeRO rung WITHIN the stage group.  The pipe-axis HBM claim reads off
-    this: under ``pipe=S`` every kind that scales with parameters
-    (params + grads + grads_accum + opt_state) drops to the stage's
-    share — max-stage ≈ 1/S of the unstaged number when the cut is
-    balanced (docs/SHARDING.md "Pipeline stages", bench.py
-    --pipeline-ab)."""
-    return [state_hbm_bytes(st, level, n_shards) for st in stage_states]
-
-
-def pipeline_carry_stash_bytes(
-    carry_avals, n_microbatches: int, n_data: int
-) -> int:
-    """Per-device bytes of the GPipe input-carry stash: a stage keeps the
-    inter-stage activation carry of every in-flight microbatch until its
-    backward recomputes from it (stage-granular remat — interior
-    activations are NOT stashed), so the stash is ``M × carry_bytes``
-    with the batch dimension sharded over the stage's data axis.  The
-    memory the schedule — not the parameters — costs; grows linearly in
-    M while the bubble (S-1)/(M+S-1) shrinks: the A/B's explicit
-    trade-off."""
-    import jax
-    import numpy as np
-
-    per_mb = 0
-    for leaf in jax.tree.leaves(carry_avals):
-        per_mb += int(np.prod(leaf.shape)) * jax.numpy.dtype(leaf.dtype).itemsize
-    return (per_mb // max(1, n_data)) * int(n_microbatches)
-
-
 def publish_hbm_gauges(
     registry,
     state,

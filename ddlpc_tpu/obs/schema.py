@@ -47,7 +47,6 @@ KNOWN_KINDS = frozenset(
         "cache",  # response-cache stats snapshots — router.jsonl (serve/cache.py)
         "lineage",  # checkpoint provenance events — metrics.jsonl/router.jsonl (obs/lineage.py consumers)
         "prod_soak",  # train-to-serve soak audit records (scripts/prod_soak.py)
-        "pipeline",  # pipeline A/B rows — docs/sharding/pipeline_ab.json (bench.py --pipeline-ab)
     }
 )
 

@@ -13,12 +13,6 @@ Axes:
 - ``space`` — spatial sharding of the image H dimension with halo exchange,
   the conv-segmentation analog of sequence/context parallelism (for tiles too
   large for one chip's HBM).
-- ``pipe``  — MPMD pipeline stages (arxiv 2412.14374): each index along the
-  axis owns one contiguous group of model blocks; stages run as separate
-  per-stage programs on disjoint (data, space) sub-meshes
-  (:func:`stage_meshes`) driven by the host round-robin schedule in
-  ``parallel/pipeline.py``.  Absent (the mesh stays 2-axis, bit-identical to
-  pre-pipeline revisions) unless ``pipeline_stages > 1``.
 """
 
 from __future__ import annotations
@@ -80,63 +74,29 @@ def make_mesh(
     """
     devices = list(devices if devices is not None else jax.devices())
     space = max(1, cfg.space_axis_size)
-    pipe = max(1, getattr(cfg, "pipeline_stages", 1))
-    if len(devices) % (space * pipe):
+    if len(devices) % space:
         raise ValueError(
-            f"space_axis_size={space} × pipeline_stages={pipe} does not "
-            f"divide device count {len(devices)}"
+            f"space_axis_size={space} does not divide device count {len(devices)}"
         )
     data = cfg.data_axis_size
     if data == -1:
-        data = len(devices) // (space * pipe)
-    if pipe * data * space > len(devices):
+        data = len(devices) // space
+    if data * space > len(devices):
         raise ValueError(
-            f"mesh {pipe}×{data}×{space} (pipe×data×space) needs "
-            f"{pipe * data * space} devices, only {len(devices)} available"
+            f"mesh {data}×{space} (data×space) needs {data * space} devices, only "
+            f"{len(devices)} available"
         )
-    if pipe * data * space < len(devices):
+    if data * space < len(devices):
         import warnings
 
         warnings.warn(
-            f"mesh {pipe}×{data}×{space} uses {pipe * data * space} of "
-            f"{len(devices)} devices; the rest stay idle",
+            f"mesh {data}×{space} uses {data * space} of {len(devices)} devices; "
+            f"the rest stay idle",
             stacklevel=2,
         )
-        devices = devices[: pipe * data * space]
-    if pipe > 1:
-        # pipe is OUTERMOST: a stage is a contiguous run of jax.devices(),
-        # so the data/space collectives inside a stage stay on the fast
-        # links and only the thin activation carry crosses stages — the
-        # MPMD layout of arxiv 2412.14374.
-        grid = np.array(devices).reshape(pipe, data, space)
-        return Mesh(
-            grid,
-            (cfg.pipe_axis_name, cfg.data_axis_name, cfg.space_axis_name),
-        )
+        devices = devices[: data * space]
     grid = np.array(devices).reshape(data, space)
     return Mesh(grid, (cfg.data_axis_name, cfg.space_axis_name))
-
-
-def stage_meshes(mesh: Mesh, pipe_axis: str = "pipe") -> list:
-    """Slice a (pipe, data, space) mesh into its per-stage (data, space)
-    sub-meshes — one ``Mesh`` per index along the pipe axis, over disjoint
-    device groups, axis names preserved.  The per-stage programs
-    (``parallel/pipeline.py``) compile against these, so every in-stage
-    collective (gradient wire, ZeRO chunk traffic, halo exchange) is scoped
-    to the stage group.  A mesh without a pipe axis is its own single
-    stage."""
-    if pipe_axis not in mesh.axis_names:
-        return [mesh]
-    idx = mesh.axis_names.index(pipe_axis)
-    if idx != 0:
-        raise ValueError(
-            f"pipe axis {pipe_axis!r} must be outermost, got mesh axes "
-            f"{mesh.axis_names}"
-        )
-    rest = tuple(n for n in mesh.axis_names if n != pipe_axis)
-    return [
-        Mesh(mesh.devices[s], rest) for s in range(mesh.shape[pipe_axis])
-    ]
 
 
 def batch_sharding(mesh: Mesh, cfg: ParallelConfig) -> NamedSharding:

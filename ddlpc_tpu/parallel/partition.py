@@ -291,152 +291,6 @@ def replicated_by_rule_bytes(decisions: PyTree, tree: PyTree) -> int:
 
 
 # ---------------------------------------------------------------------------
-# pipeline stage rules (docs/SHARDING.md "Pipeline stages")
-#
-# The same declarative pattern as the ZeRO tables, one level up: an ordered
-# (regex, stage_index) table over named flattened leaves is the single owner
-# of which pipeline stage holds each parameter.  First match wins; a leaf no
-# rule covers raises — an unassigned leaf is a missing-rule bug, not a
-# silently replicated straggler (the exact failure mode the ZeRO tables
-# exist to prevent).
-
-
-@dataclass(frozen=True)
-class StageRule:
-    """One ordered stage-assignment rule: ``re.search(pattern, leaf_name)``
-    → the leaf lives on pipeline stage ``stage``."""
-
-    pattern: str
-    stage: int
-
-
-def match_stage_rules(rules: Sequence[StageRule], name: str) -> int:
-    for rule in rules:
-        if re.search(rule.pattern, name):
-            return rule.stage
-    raise ValueError(
-        f"no stage rule matches leaf {name!r} — the stage table must cover "
-        f"every parameter (parallel/pipeline.py builds it from the model's "
-        f"block list; an uncovered leaf means the cut and the model "
-        f"disagree)"
-    )
-
-
-def stage_rules_for_blocks(
-    block_names: Sequence[str], assignment: Sequence[int]
-) -> Tuple[StageRule, ...]:
-    """One rule per top-level block, anchored to the START of the leaf
-    path (``^{block}/``): block names recur nested (every DownBlock/
-    UpBlock holds an inner ``DoubleConv_0``), so a float-anchored
-    ``(^|/)`` would let the bottleneck's rule steal decoder leaves —
-    only the top-level module name decides the stage."""
-    if len(block_names) != len(assignment):
-        raise ValueError("block_names and assignment length mismatch")
-    return tuple(
-        StageRule(rf"^{re.escape(b)}/", int(s))
-        for b, s in zip(block_names, assignment)
-    )
-
-
-def balanced_stage_assignment(
-    block_bytes: Sequence[int], n_stages: int
-) -> List[int]:
-    """Contiguous partition of the ordered block list into ``n_stages``
-    groups minimizing the max per-stage byte share (classic linear
-    partition DP — block counts are tiny).  Contiguity is load-bearing:
-    a pipeline stage must be a contiguous slice of the execution order so
-    one activation carry crosses each boundary.  Returns the per-block
-    stage index, non-decreasing."""
-    n = len(block_bytes)
-    if n_stages < 1:
-        raise ValueError(f"n_stages must be >= 1, got {n_stages}")
-    if n_stages > n:
-        raise ValueError(
-            f"cannot cut {n} blocks into {n_stages} stages — at most one "
-            f"stage per block"
-        )
-    prefix = [0]
-    for b in block_bytes:
-        prefix.append(prefix[-1] + int(b))
-
-    def span(i: int, j: int) -> int:  # bytes of blocks [i, j)
-        return prefix[j] - prefix[i]
-
-    INF = float("inf")
-    # cost[k][j]: minimal max-share cutting the first j blocks into k stages.
-    cost = [[INF] * (n + 1) for _ in range(n_stages + 1)]
-    cut = [[0] * (n + 1) for _ in range(n_stages + 1)]
-    cost[0][0] = 0
-    for k in range(1, n_stages + 1):
-        for j in range(k, n + 1):
-            for i in range(k - 1, j):
-                c = max(cost[k - 1][i], span(i, j))
-                if c < cost[k][j]:
-                    cost[k][j], cut[k][j] = c, i
-    bounds = [n]
-    for k in range(n_stages, 0, -1):
-        bounds.append(cut[k][bounds[-1]])
-    bounds.reverse()  # [0, c1, ..., n]
-    out: List[int] = []
-    for s in range(n_stages):
-        out.extend([s] * (bounds[s + 1] - bounds[s]))
-    return out
-
-
-def split_tree_by_stage(
-    rules: Sequence[StageRule], tree: PyTree, n_stages: int, prefix: str
-) -> List[PyTree]:
-    """Split a nested-dict pytree into ``n_stages`` same-shape subtrees by
-    leaf-name stage assignment — stage s's tree keeps exactly its leaves
-    (empty dicts pruned).  The inverse of :func:`merge_stage_trees`; both
-    are pure host-side dict surgery, so the canonical checkpoint layout
-    round-trips through them byte-identically (tests pin it)."""
-
-    def place(out, path_keys, leaf):
-        node = out
-        for k in path_keys[:-1]:
-            node = node.setdefault(k, {})
-        node[path_keys[-1]] = leaf
-
-    outs: List[dict] = [{} for _ in range(n_stages)]
-    for path, leaf in jax.tree_util.tree_leaves_with_path(tree):
-        name = leaf_name(prefix, path)
-        stage = match_stage_rules(rules, name)
-        if not 0 <= stage < n_stages:
-            raise ValueError(
-                f"stage rule for {name!r} assigns stage {stage}, outside "
-                f"[0, {n_stages})"
-            )
-        place(outs[stage], [_key_str(k) for k in path], leaf)
-    return outs
-
-
-def merge_stage_trees(stage_trees: Sequence[PyTree]) -> PyTree:
-    """Deep-merge per-stage nested-dict subtrees back into one tree —
-    the canonical gathered layout checkpoints store.  Key collisions
-    raise: stages own disjoint blocks by construction, so a collision
-    means two stage tables disagree about ownership."""
-
-    def merge_into(dst: dict, src: dict, path: str):
-        for k, v in src.items():
-            here = f"{path}/{k}" if path else str(k)
-            if isinstance(v, dict) and isinstance(dst.get(k), dict):
-                merge_into(dst[k], v, here)
-            elif k in dst:
-                raise ValueError(
-                    f"stage trees collide at {here!r} — stages must own "
-                    f"disjoint blocks"
-                )
-            else:
-                dst[k] = v
-
-    out: dict = {}
-    for t in stage_trees:
-        merge_into(out, t, "")
-    return out
-
-
-# ---------------------------------------------------------------------------
 # checkpoint shard / gather fns
 
 

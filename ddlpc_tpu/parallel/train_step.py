@@ -140,10 +140,9 @@ def _reduce_counters(stacked: dict, axis_name: Optional[str] = None) -> dict:
 def loss_from_logits(
     model: nn.Module, logits: jax.Array, labels: jax.Array, train: bool
 ) -> Tuple[jax.Array, jax.Array]:
-    """The loss/accuracy tail of :func:`_loss_and_metrics`, factored out
-    so the pipeline's last-stage segment (parallel/pipeline.py) applies
-    byte-identical loss math to logits produced by staged execution —
-    one owner for the grouped-head regrouping and the void-pixel mean."""
+    """The loss/accuracy tail of :func:`_loss_and_metrics` — one owner for
+    the grouped-head regrouping and the void-pixel mean, under the
+    ``ddlpc/loss`` scope the benchmark's layer metrics read."""
     # train_head_layout='grouped': the model returned pre-d2s phase-major
     # logits [..., H/r, W/r, r²·C] (models/layers.py:group_labels).  Group
     # the labels the same way and run the SAME loss/metric functions on the
@@ -476,9 +475,7 @@ def make_train_step(
             raise ValueError(
                 f"mesh axis {name!r} (size {size}) is not consumed by the "
                 f"shard_map train step — use make_train_step_gspmd for "
-                f"data×space meshes (the Trainer selects it automatically) "
-                f"or parallel/pipeline.make_pipeline_train_step for meshes "
-                f"with a pipe axis"
+                f"data×space meshes (the Trainer selects it automatically)"
             )
     axis_size = mesh.shape[data_axis]
     level = zero.normalize_shard_update(shard_update)

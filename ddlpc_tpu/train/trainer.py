@@ -535,16 +535,6 @@ class Trainer:
 
     def _build_train_step(self):
         cfg = self.cfg
-        if getattr(cfg.parallel, "pipeline_stages", 1) > 1:
-            raise ValueError(
-                "pipeline_stages > 1 is not wired into the epoch Trainer: "
-                "staged execution is host-scheduled (one program per stage, "
-                "microbatch round-robin), which the Trainer's single-step "
-                "loop cannot drive — build the step via "
-                "parallel/pipeline.make_pipeline_train_step (bench.py "
-                "--pipeline-ab shows the full driver loop); Trainer "
-                "integration is a ROADMAP follow-on"
-            )
         if self.spatial:
             return make_train_step_gspmd(
                 self.model,

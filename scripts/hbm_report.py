@@ -31,7 +31,6 @@ import argparse
 import dataclasses
 import json
 import os
-import re
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -146,93 +145,6 @@ def run_arm(cfg, shard_update: str, micro_batch: int, sync_period: int) -> dict:
     }
 
 
-def run_pipeline_arm(
-    cfg, n_stages: int, level: str, micro_batch: int, sync_period: int
-) -> dict:
-    """The staged arm: price every pipeline stage's resident state under
-    ``pipe=n_stages`` (ZeRO ``level`` within each stage group) and report
-    the MAX stage as the headline — the device that decides whether the
-    model fits.  No compile: staged programs are host-driven; pricing is
-    the same shape × committed-sharding math as the flat arms, off the
-    driver's placed per-stage states."""
-    import jax
-
-    from ddlpc_tpu.models import build_model_from_experiment
-    from ddlpc_tpu.obs import hbm as obs_hbm
-    from ddlpc_tpu.parallel.mesh import make_mesh
-    from ddlpc_tpu.parallel.pipeline import make_pipeline_train_step
-    from ddlpc_tpu.parallel.train_step import create_train_state
-    from ddlpc_tpu.train.optim import build_optimizer
-
-    cfg = cfg.replace(
-        parallel=dataclasses.replace(
-            cfg.parallel, data_axis_size=-1, space_axis_size=1,
-            pipeline_stages=n_stages, shard_update=level,
-        ),
-        train=dataclasses.replace(
-            cfg.train, micro_batch_size=micro_batch, sync_period=sync_period
-        ),
-    )
-    mesh = make_mesh(cfg.parallel)
-    n_data = mesh.shape[cfg.parallel.data_axis_name]
-    model = build_model_from_experiment(cfg)
-    tx = build_optimizer(cfg.train)
-    h, w = cfg.data.image_size
-    state = create_train_state(model, tx, jax.random.key(0), (1, h, w, 3))
-    n_micro = max(sync_period, n_stages)
-    drv = make_pipeline_train_step(
-        model, tx, mesh, cfg.compression, n_microbatches=n_micro,
-        data_axis=cfg.parallel.data_axis_name,
-        space_axis=cfg.parallel.space_axis_name,
-        pipe_axis=cfg.parallel.pipe_axis_name,
-        shard_update=level,
-    )
-    pstate = drv.init_state(state)
-    stage_level = level if n_data > 1 else "off"
-    per_stage = obs_hbm.pipeline_stage_hbm_bytes(
-        pstate.stages, stage_level, n_data
-    )
-    B_local = micro_batch  # per-replica microbatch rows on a stage device
-    carries = drv.carry_avals((n_data * micro_batch, h, w, 3))
-    for s, row in enumerate(per_stage):
-        # The GPipe stash: stage 0 keeps M input microbatches, interior
-        # stages keep M input carries, the last stage also holds labels.
-        if s == 0:
-            row["batch_images"] = 4 * n_micro * B_local * h * w * 3
-        else:
-            row["carry_stash"] = obs_hbm.pipeline_carry_stash_bytes(
-                carries[s - 1], n_micro, n_data
-            )
-        if s == n_stages - 1:
-            row["batch_labels"] = 4 * n_micro * B_local * h * w
-    headline = max(
-        per_stage,
-        key=lambda r: r["params"] + r["grads"] + r["opt_state"],
-    )
-    per_buffer = {
-        k: headline.get(k, 0)
-        for k in ("params", "grads", "grads_accum", "opt_state",
-                  "batch_stats", "batch_images", "batch_labels")
-    }
-    return {
-        "shard_update": stage_level,
-        "pipeline_stages": n_stages,
-        "n_microbatches": n_micro,
-        "devices": n_data * n_stages,
-        "per_stage_bytes_per_device": per_stage,
-        # Headline = the max stage: the device that must fit.
-        "state_bytes_per_device": per_buffer,
-        "state_bytes_per_device_total": sum(headline.values()),
-        "memory_analysis": {
-            "available": False,
-            "reason": "staged host-driven programs (no single compiled step)",
-        },
-    }
-
-
-_PIPE_ARM = re.compile(r"^pipe(\d+)(?:_(zero[12]))?$")
-
-
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument(
@@ -247,12 +159,9 @@ def main() -> None:
     p.add_argument("--sync-period", type=int, default=2)
     p.add_argument(
         "--layout", nargs="+", default=["zero1", "zero2", "zero3"],
-        choices=["zero1", "zero2", "zero3", "pipe2", "pipe4",
-                 "pipe2_zero2", "pipe4_zero2"],
-        help="layout arms to report next to the replicated baseline "
-        "(the 'off' arm always runs): ZeRO levels, and pipeN[_zero2] "
-        "staged arms (N pipeline stages, optional ZeRO-2 within each "
-        "stage group) whose headline is the max stage's bytes",
+        choices=["zero1", "zero2", "zero3"],
+        help="ZeRO levels to report next to the replicated baseline "
+        "(the 'off' arm always runs)",
     )
     p.add_argument("--out", default="docs/sharding/hbm_report.json")
     args = p.parse_args()
@@ -266,16 +175,10 @@ def main() -> None:
     with open(args.config) as f:
         cfg = ExperimentConfig.from_dict(json.load(f))
 
-    arms = {}
-    for arm in ["off"] + list(args.layout):
-        m = _PIPE_ARM.match(arm)
-        if m:
-            arms[arm] = run_pipeline_arm(
-                cfg, int(m.group(1)), m.group(2) or "off",
-                args.micro_batch, args.sync_period,
-            )
-        else:
-            arms[arm] = run_arm(cfg, arm, args.micro_batch, args.sync_period)
+    arms = {
+        arm: run_arm(cfg, arm, args.micro_batch, args.sync_period)
+        for arm in ["off"] + list(args.layout)
+    }
     off = arms["off"]["state_bytes_per_device"]
     reductions = {}
     for name, arm in arms.items():

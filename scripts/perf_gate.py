@@ -123,7 +123,6 @@ MEASURED_PATH_MODULES = (
     "ddlpc_tpu/parallel/compressed_allreduce.py",
     "ddlpc_tpu/parallel/grad_sync.py",
     "ddlpc_tpu/parallel/partition.py",
-    "ddlpc_tpu/parallel/pipeline.py",
     "ddlpc_tpu/parallel/shard_update.py",
     "ddlpc_tpu/parallel/train_step.py",
     "bench.py",

@@ -126,7 +126,7 @@ def test_bench_timed_mode_refuses_cpu(bench, monkeypatch, capsys, cache_config):
 
 
 def test_bench_import_stays_off_jax():
-    """--scaling/--pipeline-ab spawn chip-free children; the parent must
+    """--scaling spawns chip-free children; the parent must
     not even import jax (a parent that touched the backend holds the chip)."""
     proc = subprocess.run(
         [sys.executable, "-c",

@@ -157,3 +157,66 @@ def test_shipped_configs_record_executable_semantics(path):
         assert cfg.compression.quantize_mean, path
     assert cfg.train.stall_timeout_s >= 60.0, path
     assert cfg.train.stall_action == "abort", path
+
+
+# ---- retired keys (config._RETIRED_KEYS) ----------------------------------
+# A Trainer writes every field into <workdir>/config.json, so a removed
+# option must still load from the run directories that hold its inert value.
+
+_PARENT_CONFIG_JSON = os.path.join(
+    os.path.dirname(__file__), "data", "config_json_d079505_flagship.json"
+)
+
+
+def _loads_as_default(parallel):
+    cfg = ExperimentConfig.from_dict({"parallel": parallel})
+    assert cfg == ExperimentConfig()
+
+
+def _pipeline_stages_2_refused():
+    with pytest.raises(ValueError) as exc:
+        ExperimentConfig.from_dict({"parallel": {"pipeline_stages": 2}})
+    msg = str(exc.value)
+    assert "ParallelConfig.pipeline_stages=2" in msg and "removed" in msg
+
+
+def _to_dict_emits_none():
+    emitted = ExperimentConfig().to_dict()["parallel"]
+    assert not {"pipeline_stages", "pipeline_microbatches", "pipe_axis_name"} & set(
+        emitted
+    )
+
+
+def _parent_config_json_loads():
+    """The flagship configuration's ``to_json()`` taken at d079505 — what
+    that commit's Trainer wrote into ``<workdir>/config.json``."""
+    with open(_PARENT_CONFIG_JSON) as f:
+        text = f.read()
+    assert '"pipeline_stages": 1' in text
+    with open(os.path.join(CONFIG_DIR, "vaihingen_unet_tpu_flagship.json")) as f:
+        today = ExperimentConfig.from_json(f.read())
+    assert ExperimentConfig.from_json(text) == today
+
+
+@pytest.mark.parametrize(
+    "check",
+    [
+        pytest.param(lambda: _loads_as_default({"pipeline_stages": 1}),
+                     id="pipeline_stages=1"),
+        pytest.param(lambda: _loads_as_default({"pipeline_microbatches": 0}),
+                     id="pipeline_microbatches=0"),
+        pytest.param(lambda: _loads_as_default({"pipe_axis_name": "pipe"}),
+                     id="pipe_axis_name=pipe"),
+        pytest.param(
+            lambda: _loads_as_default(
+                {"pipeline_stages": 1, "pipeline_microbatches": 4}
+            ),
+            id="microbatches=4-at-one-stage",
+        ),
+        pytest.param(_pipeline_stages_2_refused, id="pipeline_stages=2-refused"),
+        pytest.param(_to_dict_emits_none, id="to_dict-emits-none"),
+        pytest.param(_parent_config_json_loads, id="parent-config-json"),
+    ],
+)
+def test_retired_config_keys(check):
+    check()

@@ -195,76 +195,3 @@ def test_trainer_selects_gspmd_and_trains(tmp_path):
     rec = trainer.fit(epochs=2)
     assert np.isfinite(rec["loss"])
     assert 0.0 <= rec["val_miou"] <= 1.0
-
-
-def test_halo_conv_on_stage_submesh_odd_rows():
-    """Halo exchange composes with staged execution: a pipeline stage's
-    disjoint (data, space) sub-mesh (parallel/mesh.py:stage_meshes) is a
-    first-class mesh for sharded_same_conv, including an ODD per-shard row
-    count (H=10 over space=2 → 5 rows each) — the split the paper-layout
-    even tiles never exercise."""
-    from ddlpc_tpu.parallel.mesh import stage_meshes
-
-    full = make_mesh(
-        ParallelConfig(pipeline_stages=2, data_axis_size=2, space_axis_size=2)
-    )
-    rng = np.random.default_rng(0)
-    H, W, C, CO = 10, 8, 3, 5
-    x = jnp.asarray(rng.standard_normal((2, H, W, C)), jnp.float32)
-    kernel = jnp.asarray(rng.standard_normal((3, 3, C, CO)) * 0.1, jnp.float32)
-    ref = lax.conv_general_dilated(
-        x, kernel, (1, 1), [(1, 1), (1, 1)],
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    for sub in stage_meshes(full):
-        assert set(sub.shape.items()) == {("data", 2), ("space", 2)}
-
-        def body(xl):
-            return sharded_same_conv(xl, kernel, "space")
-
-        out = jax.jit(
-            jax.shard_map(
-                body, mesh=sub,
-                in_specs=P(None, "space"), out_specs=P(None, "space"),
-            )
-        )(x)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
-
-
-def test_halo_at_stage_boundary_carry():
-    """A spatially-sharded activation carry crossing a stage boundary:
-    halo-exchange on stage 0's sub-mesh, device_put the carry to stage 1's
-    DISJOINT sub-mesh (the pipeline's explicit inter-stage send), then
-    halo-exchange again there — values survive the hop bit-exactly and the
-    second exchange sees the right neighbors."""
-    from jax.sharding import NamedSharding
-
-    from ddlpc_tpu.parallel.mesh import stage_meshes
-
-    full = make_mesh(
-        ParallelConfig(pipeline_stages=2, data_axis_size=2, space_axis_size=2)
-    )
-    sub0, sub1 = stage_meshes(full)
-    H = 12
-    x = jnp.arange(2 * H * 3 * 2, dtype=jnp.float32).reshape(2, H, 3, 2)
-
-    def exchanged(mesh_s, arr):
-        def body(xl):
-            return halo_exchange(xl, "space", 1)
-
-        return jax.jit(
-            jax.shard_map(
-                body, mesh=mesh_s,
-                in_specs=P(None, "space"), out_specs=P(None, "space"),
-            )
-        )(arr)
-
-    x0 = jax.device_put(x, NamedSharding(sub0, P(None, "space")))
-    y0 = exchanged(sub0, x0)
-    # The inter-stage send: disjoint device group, same layout.
-    x1 = jax.device_put(x0, NamedSharding(sub1, P(None, "space")))
-    assert {d.id for d in x1.sharding.device_set}.isdisjoint(
-        {d.id for d in x0.sharding.device_set}
-    )
-    y1 = exchanged(sub1, x1)
-    np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))

@@ -1,9 +1,12 @@
+import json
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ddlpc_tpu.config import ModelConfig
+from ddlpc_tpu.config import ExperimentConfig, ModelConfig
 from ddlpc_tpu.models import build_model
 
 
@@ -593,3 +596,85 @@ def test_undeclared_grouped_logits_refused():
     BadModel.train_head_layout = "grouped"
     with pytest.raises(ValueError, match="refusing to reinterpret"):
         _loss_and_metrics(BadModel(), {}, {}, x, y, train=False)
+
+
+# ---- the parent's parameter tree and logits (tests/data/, PR 29) ----------
+# Written at commit d079505 (the last with the block interpreter in
+# UNet.__call__) by calling the two helpers below against that tree: old
+# checkpoints restore only while every path, shape and dtype stays.
+
+_DATA_DIR = os.path.join(os.path.dirname(__file__), "data")
+_CONFIG_DIR = os.path.join(os.path.dirname(__file__), "..", "configs")
+
+
+def _unet_tree_rows(config_name):
+    """``[collection/path, shape, dtype]`` of every leaf the shipped
+    configuration's model group initialises (shapes only: eval_shape)."""
+    with open(os.path.join(_CONFIG_DIR, config_name + ".json")) as f:
+        cfg = ExperimentConfig.from_json(f.read())
+    model = build_model(cfg.model, norm_axis_name="data")
+    h, w = cfg.data.image_size
+    variables = jax.eval_shape(
+        lambda: model.init(
+            jax.random.key(0), jnp.zeros((1, h, w, 3), jnp.float32), train=False
+        )
+    )
+    return [
+        [jax.tree_util.keystr(path), list(leaf.shape), str(leaf.dtype)]
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+            {k: variables[k] for k in ("params", "batch_stats")}
+        )
+    ]
+
+
+_PROBE_MODELS = {
+    "plain_stem": ModelConfig(
+        features=(8, 16, 32), bottleneck_features=32, num_classes=6,
+        compute_dtype="float32",
+    ),
+    "s2d_stem_fullres_detail_head": ModelConfig(
+        features=(8, 16, 32), bottleneck_features=32, num_classes=6,
+        compute_dtype="float32", stem="s2d", stem_factor=4,
+        detail_head=True, detail_head_kind="fullres",
+    ),
+}
+
+
+def _unet_probe_logits(case):
+    """Logits of a small float32 U-Net at 32 fixed positions, from the
+    training forward (batch statistics) and from the eval forward."""
+    model = build_model(_PROBE_MODELS[case])
+    x = jax.random.normal(jax.random.key(1), (2, 64, 64, 3), jnp.float32)
+    variables = model.init(jax.random.key(0), x, train=False)
+    train_logits, _ = model.apply(variables, x, train=True, mutable=["batch_stats"])
+    eval_logits = model.apply(variables, x, train=False)
+    rng = np.random.default_rng(0)
+    idx = tuple(rng.integers(0, n, size=32) for n in eval_logits.shape)
+    return {
+        "train": np.asarray(train_logits)[idx].tolist(),
+        "eval": np.asarray(eval_logits)[idx].tolist(),
+    }
+
+
+@pytest.mark.parametrize(
+    "config_name",
+    [
+        "vaihingen_unet_cpu",
+        "vaihingen_unet_tpu_flagship",
+        "vaihingen_unet_v5e8",
+        "cityscapes_unet_v5e64",
+    ],
+)
+def test_unet_param_tree_is_the_parents(config_name):
+    with open(os.path.join(_DATA_DIR, "unet_param_trees_d079505.json")) as f:
+        golden = json.load(f)[config_name]
+    assert _unet_tree_rows(config_name) == golden
+
+
+@pytest.mark.parametrize("case", sorted(_PROBE_MODELS))
+def test_unet_logits_are_the_parents(case):
+    with open(os.path.join(_DATA_DIR, "unet_probe_logits_d079505.json")) as f:
+        golden = json.load(f)[case]
+    got = _unet_probe_logits(case)
+    for mode in ("train", "eval"):
+        np.testing.assert_allclose(got[mode], golden[mode], rtol=0, atol=1e-6)

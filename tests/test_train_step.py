@@ -172,3 +172,14 @@ def test_make_mesh_validation():
     with _pytest.warns(UserWarning, match="stay idle"):
         m = _mm(ParallelConfig(data_axis_size=3), jax.devices())
     assert m.shape["data"] == 3
+
+
+@pytest.mark.parametrize(
+    "data,space,shape", [(8, 1, (8, 1)), (4, 2, (4, 2)), (2, 4, (2, 4)), (-1, 2, (4, 2))]
+)
+def test_make_mesh_axes(data, space, shape):
+    """Exactly the (data, space) axes, over jax.devices() in order."""
+    mesh = make_mesh(ParallelConfig(data_axis_size=data, space_axis_size=space))
+    assert mesh.axis_names == ("data", "space")
+    assert mesh.devices.shape == shape
+    assert [d.id for d in mesh.devices.flat] == [d.id for d in jax.devices()]
