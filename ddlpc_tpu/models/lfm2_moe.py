@@ -22,9 +22,15 @@ size; the layer holds experts ``[expert_offset, expert_offset +
 experts_held)`` and adds their part for the tokens routed to them.  What the
 absent experts would add is left out (model-configs guide §4); nothing stands
 in for the other ranks or their exchange.  No token is dropped: the (token,
-expert) pairs are sorted by held expert into a buffer of all ``tokens × k``
-rows (the worst case, static), and the grouped products compute the rows of
-the held groups only.
+expert) pairs are sorted with the held experts' groups first, and the expert
+block runs on a compact buffer of the first ``C`` sorted rows (``buffer_rows``,
+static: twice this rank's share of the ``tokens × k`` pairs under uniform
+routing, and no more bytes than the TPU compiler keeps in VMEM as a gather's
+table) and, where a device-side count finds more routed rows than that, again
+on the next ``C`` until every routed row is held: the worst case costs
+buffers, not rows.  The block is differentiated as a whole
+(``_expert_block``), because reverse mode through a choice made on the device
+would store every buffer's residuals, run or not.
 
 Position-wise projections are ``flax.linen.Dense`` (measured faster than the
 zoo's 1×1 ``nn.Conv`` on the chip), so the conv FLOP walks see none of this
@@ -250,48 +256,209 @@ class SwiGLU(nn.Module):
         return _proj(self.hidden, self.dtype, "w2")(h)
 
 
-@jax.custom_vjp
-def _dispatch(x, order, inverse):
-    """Rows of ``x [N, d]`` in sorted pair order: ``x[order // k]``, ``[N·k, d]``.
-    The backward is the inverse gather and a sum over each token's k pairs,
-    not a scatter-add."""
-    return x.at[order // (order.shape[0] // x.shape[0])].get(mode="promise_in_bounds")
+# The expert block's buffer, two rules and the smaller of them.
+# BUFFER_SHARES times this rank's share of the (token, expert) pairs under
+# uniform routing: in the benchmark's window the held share of the pairs is
+# 11-14 % where uniform routing gives 12.5 % (PERF.md section 5), so a whole
+# share of room.  (``moe_max_load`` is one expert's load against the mean of
+# the held ones; it says nothing of their total, which is what the buffer
+# holds.)  And no more than BUFFER_TABLE_BYTES: the token side gathers every
+# pair's row out of the buffer, and the TPU compiler stages a gather's table
+# in VMEM up to a size, 112 MiB on the v5e with its 128 MiB (131,072 rows of
+# 4 KiB gathered in 0.83 ms out of 96 MiB, 4.35 out of 128; the routed layer
+# forward and backward 26.1 against 44.5 ms; PERF.md section 6, PR 30).  A
+# rank whose routed rows pass the buffer runs the block again on the next
+# rows (``_plus_later_buffers``), so a smaller buffer costs passes, not rows.
+BUFFER_SHARES = 2
+BUFFER_TABLE_BYTES = 96 << 20
+BUFFER_ROW_MULTIPLE = 512  # a buffer is whole row tiles of the grouped products
 
 
-def _dispatch_fwd(x, order, inverse):
-    return _dispatch(x, order, inverse), (inverse, x.shape[0])
+def buffer_rows(pairs: int, experts_held: int, num_experts: int, row_bytes: int) -> int:
+    """Rows of the expert block's buffer for ``pairs`` (token, expert) pairs of
+    ``row_bytes`` each: from shapes alone, and all the pairs (no later buffer,
+    no loop) where BUFFER_SHARES shares of them are all there are."""
+    tile = BUFFER_ROW_MULTIPLE
+    share = -(-BUFFER_SHARES * pairs * experts_held // num_experts)
+    rows = -(-share // tile) * tile
+    if rows >= pairs:
+        return pairs
+    return min(rows, max(BUFFER_TABLE_BYTES // row_bytes // tile, 1) * tile)
 
 
-def _dispatch_bwd(res, g):
-    inverse, n = res
-    g = g.at[inverse].get(mode="promise_in_bounds")
-    return g.reshape(n, -1, g.shape[-1]).sum(axis=1), None, None
+def _rows_at(table, at):
+    """``table[at]`` along the first axis, for in-bounds ``at``."""
+    return table.at[at].get(mode="promise_in_bounds")
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _buffer_view(rows, start, weights, inverse, sizes):
+    """Of the buffer holding sorted rows ``[start, start + rows)``: its group
+    sizes, the mask of its rows that lie in a group, and per pair ``[N, k]``
+    its row in the buffer (clipped) and whether the buffer holds it."""
+    ends = jnp.cumsum(sizes)
+    local = jnp.clip(ends - start, 0, rows) - jnp.clip(ends - sizes - start, 0, rows)
+    in_group = (jnp.arange(rows) < local.sum())[:, None]
+    at = inverse.reshape(weights.shape) - start
+    return local, in_group, jnp.clip(at, 0, rows - 1), (at >= 0) & (at < rows)
 
 
-@jax.custom_vjp
-def _permute(rows, perm, inverse):
-    """``rows[perm]`` for a permutation; the backward gathers by its inverse."""
-    return rows.at[perm].get(mode="promise_in_bounds")
+def _buffer_forward(rows, start, x, w1, w3, w2, weights, order, inverse, sizes):
+    """The held experts' part ``y [N, d]`` of the tokens ``x [N, d]`` from the
+    pairs in sorted rows ``[start, start + rows)`` (held groups first, so the
+    buffer at 0 holds every held pair when ``sizes.sum() <= rows``), the count
+    of held rows the grouped products wrote, and what the backward reads
+    again.  ``weights [N, k]`` is zero where a pair's expert is not held;
+    ``order`` maps a sorted row to its pair, ``inverse`` a pair to its row."""
+    k = weights.shape[1]
+    with jax.named_scope("ddlpc/moe/route"):
+        sizes, in_group, at, inside = _buffer_view(rows, start, weights, inverse, sizes)
+        token = lax.dynamic_slice(order, (start,), (rows,)) // k
+        # The grouped products leave the rows past the last group unwritten
+        # (NaN on the TPU), forward and backward, so both ends of the expert
+        # block are masked.
+        xs = jnp.where(in_group, _rows_at(x, token), 0)
+    with jax.named_scope("ddlpc/moe/experts"):
+        grouped = functools.partial(
+            lax.ragged_dot, group_sizes=sizes, preferred_element_type=x.dtype
+        )
+        a1, a3 = grouped(xs, w1), grouped(xs, w3)
+        h = nn.silu(a1) * a3
+        out = grouped(h, w2)
+    with jax.named_scope("ddlpc/moe/route"):
+        # Rows of the held groups that the products wrote: finite and not
+        # all zero (an unwritten row reads NaN on the TPU, zero on the CPU).
+        total = jnp.abs(out.astype(jnp.float32)).sum(axis=-1, keepdims=True)
+        written = (in_group & jnp.isfinite(total) & (total > 0)).sum(dtype=jnp.int32)
+        out = jnp.where(in_group, out, 0)
+        # Each token's weighted sum over its k pairs in float32, a gather of
+        # [N] rows a pair: pair-major, the [N, k, d] view of the gathered rows
+        # would be a copy into another tiling.  A pair outside the buffer
+        # weighs 0 and any finite row serves.
+        weights = jnp.where(inside, weights, 0.0)
+        y = sum(
+            weights[:, j : j + 1] * _rows_at(out, at[:, j]).astype(jnp.float32)
+            for j in range(k)
+        ).astype(x.dtype)
+    return y, written, (xs, a1, a3, h, out)
 
 
-def _permute_fwd(rows, perm, inverse):
-    return _permute(rows, perm, inverse), inverse
+def _grouped_pullback(lhs, rhs, sizes, g):
+    """``(d lhs, d rhs)`` of ``ragged_dot(lhs, rhs)`` for the cotangent ``g``:
+    the product is linear in each operand, so neither needs the forward."""
+    grouped = functools.partial(lax.ragged_dot, group_sizes=sizes, preferred_element_type=g.dtype)
+    (d_lhs,) = jax.linear_transpose(lambda a: grouped(a, rhs), lhs)(g)
+    (d_rhs,) = jax.linear_transpose(lambda b: grouped(lhs, b), rhs)(g)
+    return d_lhs, d_rhs
 
 
-def _permute_bwd(inverse, g):
-    return g.at[inverse].get(mode="promise_in_bounds"), None, None
+def _buffer_backward(rows, start, saved, g, x, w1, w3, w2, weights, order, inverse, sizes):
+    """Cotangents of ``x, w1, w3, w2, weights`` for ``g = dy [N, d]`` through
+    the buffer :func:`_buffer_forward` ran on.  What crosses between tokens and
+    buffer rows is a gather either way (by ``order`` into the buffer, by
+    ``inverse`` out of it) and a sum over each token's k pairs: a scatter-add
+    serialises on the TPU."""
+    k = weights.shape[1]
+    xs, a1, a3, h, out = saved
+    with jax.named_scope("ddlpc/moe/route"):
+        sizes, in_group, at, inside = _buffer_view(rows, start, weights, inverse, sizes)
+        pair = lax.dynamic_slice(order, (start,), (rows,))
+        g_rows = _rows_at(g, pair // k).astype(jnp.float32)
+        w_rows = _rows_at(weights.reshape(-1), pair)[:, None]
+        d_out = jnp.where(in_group, (w_rows * g_rows).astype(out.dtype), 0)
+        d_w_rows = (g_rows * out.astype(jnp.float32)).sum(axis=-1)
+    with jax.named_scope("ddlpc/moe/experts"):
+        d_h, d_w2 = _grouped_pullback(h, w2, sizes, d_out)
+        d_a1, d_a3 = jax.vjp(lambda a, b: nn.silu(a) * b, a1, a3)[1](d_h)
+        d_xs1, d_w1 = _grouped_pullback(xs, w1, sizes, d_a1)
+        d_xs3, d_w3 = _grouped_pullback(xs, w3, sizes, d_a3)
+    with jax.named_scope("ddlpc/moe/route"):
+        d_xs = jnp.where(in_group, d_xs1 + d_xs3, 0)
+        d_x = sum(
+            jnp.where(inside[:, j : j + 1], _rows_at(d_xs, at[:, j]).astype(jnp.float32), 0)
+            for j in range(k)
+        ).astype(x.dtype)
+        d_weights = jnp.where(inside, _rows_at(d_w_rows, at), 0.0)
+    return d_x, d_w1, d_w3, d_w2, d_weights
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+def _plus_later_buffers(rows, order, sizes, total, buffer):
+    """``total`` (the buffer at sorted row 0) plus ``buffer(start)`` for every
+    later buffer of ``rows`` rows that holds routed rows, as many as a count
+    on the device says: none where the routed rows fit the first, enough to
+    hold all the pairs at the worst skew, so nothing is dropped and nothing
+    recompiles."""
+    if order.shape[0] == rows:
+        return total
+    filled = -(-sizes.sum() // rows)
+    add = lambda i, total: jax.tree.map(jnp.add, total, buffer(i * rows))  # noqa: E731
+    return lax.fori_loop(1, filled, add, total)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _expert_block(rows, x, w1, w3, w2, weights, order, inverse, sizes):
+    """``(y, written)`` of :func:`_buffer_forward` over as many buffers of
+    ``rows`` rows as the routed rows fill (``order`` is padded to whole
+    buffers).  Differentiated as a whole, because the number of buffers is
+    read on the device: reverse mode through that choice would keep every
+    buffer's residuals whether it ran or not and fill the idle ones with
+    zeros, which costs what the compact buffer saves.  The forward keeps the
+    first buffer's residuals alone; the backward recomputes a later buffer's
+    before it pulls back through it, so the overflow pays for itself."""
+    return _expert_block_fwd(rows, x, w1, w3, w2, weights, order, inverse, sizes)[0]
+
+
+def _expert_block_fwd(rows, *operands):
+    *_, order, _, sizes = operands
+    y, written, saved = _buffer_forward(rows, 0, *operands)
+    later = lambda start: _buffer_forward(rows, start, *operands)[:2]  # noqa: E731
+    return _plus_later_buffers(rows, order, sizes, (y, written), later), (saved, operands)
+
+
+def _expert_block_bwd(rows, residuals, cotangents):
+    saved, operands = residuals
+    *_, order, _, sizes = operands
+    g = cotangents[0]
+
+    def later(start):
+        again = _buffer_forward(rows, start, *operands)[2]
+        return _buffer_backward(rows, start, again, g, *operands)
+
+    first = _buffer_backward(rows, 0, saved, g, *operands)
+    return (*_plus_later_buffers(rows, order, sizes, first, later), None, None, None)
+
+
+_expert_block.defvjp(_expert_block_fwd, _expert_block_bwd)
 
 
 class RoutedExperts(nn.Module):
     """Sigmoid-scored top-k routing over all ``num_experts`` and the SwiGLU
     experts held here.  Returns the held experts' part of the layer's output
-    and the layer's routing counts."""
+    and the layer's routing counts.
+
+    The buffer: the (token, expert) pairs sort with the held groups first, and
+    the expert side of the block (the dispatch gather, the masks, the three
+    grouped products and the gate between them, the written-row count) runs
+    on the first ``C`` sorted rows, ``C = buffer_rows(...)``, fixed at trace
+    time from shapes: twice this rank's share of the ``tokens x k`` pairs
+    under uniform routing, no more bytes than a gather's table that stays in
+    VMEM, and all the pairs where every expert is held.  The token side (each
+    token's weighted sum over its k pairs, and the backward of the dispatch)
+    still spans all the pairs, as gathers out of the ``C`` rows.  The
+    fallback: ``routed`` is a device scalar, and where ``routed > C`` the same
+    block runs again on the next ``C`` sorted rows, and again, until the
+    buffers hold every routed row: at most all the pairs' worth, so nothing is
+    dropped at any skew, nothing recompiles between a balanced and a skewed
+    batch, and no full-size temporary exists (one full-size pass beside the
+    compact residuals does not fit the chip: 16.998 of 16.9 GB).  What it
+    costs (TPU v5 lite, 32,768 tokens x top-4, 8 of 64 held, ``C`` 24,576,
+    forward and backward under ``nn.remat``; PERF.md section 6, PR 30): 25.6 ms
+    with one buffer and 30.5 more for each further one, against 73.3 ms on a
+    buffer of all the pairs at the same 12.6 % of them routed and 129.1 at
+    100 %.  Up to three buffers (55 % of the pairs routed, 4.4 times the share)
+    the compact form is ahead, 86.7 against 100.7 ms; from five (77 %) it is
+    behind, 133.9 against 115.0, and at the worst, six buffers for every pair,
+    164.6 against 129.1.  ``moe_rows_buffered`` counts the buffers' rows.
+    :func:`_expert_block` says why the block is differentiated as a whole."""
 
     hidden: int
     width: int
@@ -337,41 +504,32 @@ class RoutedExperts(nn.Module):
 
             local = selected - self.expert_offset
             held = (local >= 0) & (local < held_n)
+            weights = jnp.where(held, weights, 0.0)
             group = jnp.where(held, local, held_n).reshape(-1)  # absent experts sort last
             order = jnp.argsort(group, stable=True)  # sorted row -> pair (token·k + j)
             inverse = jnp.argsort(order)  # pair -> sorted row
             # (a compare-and-sum, not bincount: a scatter-add of every pair
             # into a handful of bins serialises on the TPU)
             sizes = (group[:, None] == jnp.arange(held_n)).sum(axis=0, dtype=jnp.int32)
-            routed = sizes.sum()
-            in_group = (jnp.arange(n * k) < routed)[:, None]
-            # [N·k, d]: the held groups first.  The grouped products leave the
-            # rows past the last group unwritten (NaN on the TPU), forward and
-            # backward, so both ends of the expert block are masked.
-            rows = jnp.where(in_group, _dispatch(x, order, inverse), 0)
 
-        with jax.named_scope("ddlpc/moe/experts"):
-            grouped = functools.partial(
-                lax.ragged_dot, group_sizes=sizes, preferred_element_type=self.dtype
-            )
-            h = nn.silu(grouped(rows, w1.astype(self.dtype))) * grouped(rows, w3.astype(self.dtype))
-            out = grouped(h, w2.astype(self.dtype))
+            # whole buffers of the sorted rows: the padding sorts past every pair
+            rows = buffer_rows(n * k, held_n, self.num_experts, x.dtype.itemsize * self.hidden)
+            order = jnp.pad(order, (0, -(n * k) % rows))
 
-        with jax.named_scope("ddlpc/moe/route"):
-            # Rows of the held groups that the products wrote: finite and not
-            # all zero (an unwritten row reads NaN on the TPU, zero on the CPU).
-            total = jnp.abs(out.astype(jnp.float32)).sum(axis=-1, keepdims=True)
-            written = (in_group & jnp.isfinite(total) & (total > 0)).sum(dtype=jnp.int32)
-            out = jnp.where(in_group, out, 0)
-            out = _permute(out, inverse, order).reshape(n, k, self.hidden)
-            weights = jnp.where(held, weights, 0.0)
-            y = jnp.einsum("nk,nkd->nd", weights, out.astype(jnp.float32)).astype(self.dtype)
+        # Outside both scopes: the block names its own ops, route and experts.
+        y, written = _expert_block(
+            rows, x, w1.astype(self.dtype), w3.astype(self.dtype), w2.astype(self.dtype),
+            weights, order, inverse, sizes,
+        )
 
         self.sow("intermediates", "group_sizes", sizes)
         held_pairs = held.sum(dtype=jnp.int32)
         sums = {
             "moe_rows_routed": held_pairs,
             "moe_rows_offered": jnp.int32(n * k),
+            # Rows of the buffers the block ran on: one, or as many as the
+            # routed rows filled.
+            "moe_rows_buffered": rows * jnp.maximum(-(-held_pairs // rows), 1),
             # Pairs whose expert is held and whose row the grouped products
             # did not write; a run is not sound unless it stays 0.
             "moe_rows_dropped": held_pairs - written,

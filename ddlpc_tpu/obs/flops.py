@@ -217,13 +217,20 @@ def product_flops(
 ) -> Tuple[int, int, bool]:
     """``(dense, grouped, has_conv)`` of ONE forward of ``cfg``'s model over
     a micro-batch: the FLOPs of every ``dot_general`` (2 · output elements ·
-    contracted length), those of every ``ragged_dot`` if each row of its
-    buffer lay in a group (2·m·k·n), and whether the program holds a
-    ``conv_general_dilated`` at all.  Traced with ``train=False``, so a
-    model that rematerialises under ``train=True`` is not counted twice; a
-    ``scan`` body (attention mapped over sequences) counts ``length`` times.
-    A ``pallas_call`` counts by its ``cost_estimate`` (its body holds one
-    grid step's products, not the grid's), and of a ``lax.platform_dependent``
+    contracted length), those of the ``ragged_dot``s if every (token, expert)
+    pair the program picks lay in a group, and whether the program holds a
+    ``conv_general_dilated`` at all.  The grouped products count by the row,
+    not by the buffer, all from shapes: a product ``[m, k] x [g, k, n]``
+    spends 2·k·n on each of its buffer's ``m`` rows, the products that share
+    a ``group_sizes`` vector share a buffer, and the pairs are the ``top_k``s'
+    outputs.  ``grouped`` is what a buffer row costs times the pairs, so it is
+    the same whether a routed layer's buffer holds every pair or a compact
+    share of them, and whether the program runs it once or again in a loop
+    on further rows.  Traced with ``train=False``, so a model that
+    rematerialises under ``train=True`` is not counted twice; a ``scan`` body
+    (attention mapped over sequences) counts ``length`` times.  A
+    ``pallas_call`` counts by its ``cost_estimate`` (its body holds one grid
+    step's products, not the grid's), and of a ``lax.platform_dependent``
     switch the one branch that is lowered for ``platform`` (the default
     backend's unless given).  Memoized like :func:`conv_step_flops`."""
     import jax
@@ -248,7 +255,10 @@ def product_flops(
     jaxpr = jax.make_jaxpr(lambda v, x: model.apply(v, x, train=False))(variables, x_s)
 
     def walk(jaxpr):
-        dense, grouped, has_conv = 0, 0, False
+        """FLOPs of the dense products; FLOPs and rows of the grouped products'
+        buffers; the (token, expert) pairs picked; any convolution."""
+        dense, grouped, rows, pairs, has_conv = 0, 0, 0, 0, False
+        buffers = set()  # the group_sizes vectors seen in this jaxpr
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
             if name == "pallas_call":
@@ -264,6 +274,11 @@ def product_flops(
             elif name == "ragged_dot_general":
                 m, k = eqn.invars[0].aval.shape
                 grouped += 2 * m * k * eqn.invars[1].aval.shape[-1]
+                if eqn.invars[2] not in buffers:
+                    buffers.add(eqn.invars[2])
+                    rows += m
+            elif name == "top_k":
+                pairs += int(np.prod(eqn.outvars[0].aval.shape))
             has_conv |= name == "conv_general_dilated"
             times = eqn.params["length"] if name == "scan" else 1
             subs = list(_sub_jaxprs(eqn.params))
@@ -271,11 +286,15 @@ def product_flops(
             if lowered_for:  # the last branch is the default (None)
                 subs = [next(b for b, ps in zip(subs, lowered_for) if ps is None or platform in ps)]
             for sub in subs:
-                d, g, c = walk(sub)
-                dense, grouped, has_conv = dense + times * d, grouped + times * g, has_conv | c
-        return dense, grouped, has_conv
+                d, g, r, p, c = walk(sub)
+                dense, grouped = dense + times * d, grouped + times * g
+                rows, pairs, has_conv = rows + times * r, pairs + times * p, has_conv | c
+        return dense, grouped, rows, pairs, has_conv
 
-    _PRODUCT_FLOPS_CACHE[key] = walk(jaxpr.jaxpr)
+    dense, grouped, rows, pairs, has_conv = walk(jaxpr.jaxpr)
+    if rows:
+        grouped = grouped * pairs // rows  # what a buffer row costs x the pairs
+    _PRODUCT_FLOPS_CACHE[key] = dense, grouped, has_conv
     return _PRODUCT_FLOPS_CACHE[key]
 
 
@@ -288,8 +307,8 @@ def step_flops(
     ``value_and_grad`` program, where there is a convolution) plus three
     times the forward's matrix products (forward and backward; recomputation
     is not required work); ``grouped`` is three times the forward's grouped
-    products (``ragged_dot``) with every row of their buffers in a group.
-    How many rows were, only the run knows: :meth:`PerfAccountant.routed`
+    products (``ragged_dot``) with every (token, expert) pair in a group.
+    How many pairs were, only the run knows: :meth:`PerfAccountant.routed`
     takes the share from the step's counters."""
     dense, grouped, has_conv = product_flops(cfg, micro_batch, channels)
     convs = conv_step_flops(cfg, micro_batch, sync_period, channels) if has_conv else 0
@@ -480,7 +499,8 @@ class PerfAccountant:
     def routed(self, rows_routed: float, rows_offered: float) -> None:
         """The step's routing counters (``moe_rows_routed``,
         ``moe_rows_offered``): the grouped products count at the share of
-        their buffers' rows that lay in a group, and at nothing before."""
+        the (token, expert) pairs that lay in a group, whatever buffer held
+        their rows, and at nothing before."""
         share = rows_routed / rows_offered if rows_offered > 0 else 0.0
         with self._lock:
             self.flops_per_step = self._dense_flops + int(self._grouped_flops * share)

@@ -146,13 +146,18 @@ def test_shares_add_up_to_the_uncut_layer():
 # ---- routing ---------------------------------------------------------------------
 
 
-def routed_layer(cfg):
-    """The routed layer with every expert held."""
+def held_layer(cfg):
+    """The routed layer holding the experts ``cfg`` says."""
     return program.RoutedExperts(
         hidden=cfg.hidden_size, width=cfg.moe_intermediate_size, num_experts=cfg.num_experts,
-        top_k=cfg.num_experts_per_tok, experts_held=cfg.num_experts, expert_offset=0,
-        dtype=jnp.float32,
+        top_k=cfg.num_experts_per_tok, experts_held=cfg.experts_held,
+        expert_offset=cfg.expert_offset, dtype=jnp.float32,
     )
+
+
+def routed_layer(cfg):
+    """The routed layer with every expert held."""
+    return held_layer(dataclasses.replace(cfg, experts_held=cfg.num_experts, expert_offset=0))
 
 
 def test_bias_selects_and_does_not_weigh():
@@ -238,15 +243,21 @@ def test_no_drop_under_the_worst_skew_and_no_recompilation():
     assert len(compiles) == 1
 
 
-def test_a_row_the_products_leave_unwritten_is_counted_as_dropped(monkeypatch):
+@pytest.mark.parametrize("held", [8, 2])
+def test_a_row_the_products_leave_unwritten_is_counted_as_dropped(
+    monkeypatch, small_row_tiles, held
+):
     """moe_rows_dropped reads the grouped products' output, not the routing
-    that fed them: a held row that comes back unwritten shows in it."""
-    cfg = tiny_config(experts_held=8, expert_offset=0)
-    layer = routed_layer(cfg)
+    that fed them: a held row that comes back unwritten shows in it, on all
+    the pairs (every expert held) and on the compact buffer (2 of 8)."""
+    cfg = tiny_config(experts_held=held, expert_offset=0)
+    layer = held_layer(cfg)
     u = jax.random.normal(jax.random.key(0), (1, 1, 32, cfg.hidden_size))
     params = layer.init(jax.random.key(1), u)["params"]
     _, counts = layer.apply({"params": params}, u)
     assert int(counts["sum"]["moe_rows_dropped"]) == 0
+    compact = int(counts["sum"]["moe_rows_buffered"]) < int(counts["sum"]["moe_rows_offered"])
+    assert compact == (held == 2) and int(counts["sum"]["moe_rows_routed"]) >= 3
     whole = jax.lax.ragged_dot
 
     def leaky(lhs, rhs, group_sizes, **kw):
@@ -262,6 +273,178 @@ def test_skewed_batch_matches_the_reference():
     x = np.full((2, 1, SEQ, 1), 5, np.int32)
     y = np.full((2, 1, SEQ), 9, np.int32)
     out = check.compare(cfg, "lfm2_moe", init(cfg), {}, x, y)
+    assert out["ok"], out
+
+
+# ---- the compact row buffer ---------------------------------------------------------
+# 2 of 8 experts held, top-2: twice the share is half the pairs (128 of the
+# 256 of two 64-token tiles), and the fixture lets a gather's table hold 96
+# float32 rows of the tiny width, in whole tiles of 8 rows (512 would cover
+# every pair): a buffer of 96 rows, three of them at the worst skew.
+
+ROW_BYTES = 4 * TINY["hidden_size"]
+
+
+@pytest.fixture
+def small_row_tiles(monkeypatch):
+    monkeypatch.setattr(program, "BUFFER_ROW_MULTIPLE", 8)
+    monkeypatch.setattr(program, "BUFFER_TABLE_BYTES", 96 * ROW_BYTES)
+
+
+def test_buffer_rows_come_from_shapes_alone():
+    bf16 = 2 * 2048  # a row of the published width
+    # the cell (micro 4 x 8,192 x top-4): twice the share is 32,768 rows, 96 MiB hold 24,576
+    assert program.buffer_rows(131072, 8, 64, bf16) == 24576
+    assert program.buffer_rows(131072, 8, 64, bf16 // 2) == 32768  # half the width: the share rules
+    assert program.buffer_rows(131072, 8, 64, 4 * bf16) == 6144  # wider rows: the bytes rule
+    assert program.buffer_rows(32768, 8, 64, bf16) == 8192  # the reference check's one sequence
+    assert program.buffer_rows(65536, 8, 64, bf16) == 16384  # micro 2
+    # every pair may be held: all of them in one buffer, whatever their bytes
+    assert program.buffer_rows(131072, 64, 64, bf16) == program.buffer_rows(131072, 32, 64, bf16) == 131072
+    assert program.buffer_rows(256, 2, 8, 256) == 256 and program.buffer_rows(4096, 2, 8, 256) == 2048
+    assert program.buffer_rows(1000, 1, 3, 256) == 1000 and program.buffer_rows(3000, 1, 8, 256) == 1024
+    assert program.buffer_rows(3000, 1, 8, 1 << 30) == 512  # never less than a tile
+
+
+def value_counters_and_gradients(cfg, params, x, y):
+    """Loss, logits, the step's counters, and the gradients of every parameter
+    and of the embedded input, of the model in training mode."""
+    model = build_model(cfg)
+
+    def loss_fn(params, nudge):
+        # the input to the layers is a row of the embedding: its gradient
+        # through the layers alone, the tied head's part left out
+        perturbed = dict(params, embedding=params["embedding"] + nudge)
+        logits, aux = model.apply({"params": perturbed}, x, train=True, mutable=["counters"])
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        loss = -jnp.take_along_axis(logp, y[..., None], axis=-1).mean()
+        return loss, (logits, aux["counters"])
+
+    nudge = jnp.zeros_like(params["embedding"])
+    (loss, (logits, counters)), grads = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)(
+        params, nudge
+    )
+    return loss, logits, counters, grads
+
+
+def test_compact_buffer_is_the_full_buffer(monkeypatch, small_row_tiles):
+    """With the capacity rule forced to all the pairs and at its own value:
+    the same outputs, counters and gradients of every parameter and the input."""
+    cfg = tiny_config()
+    params, (x, y) = init(cfg), tokens(6)
+    compact = value_counters_and_gradients(cfg, params, x, y)
+    monkeypatch.setattr(program, "buffer_rows", lambda pairs, *_: pairs)
+    full = value_counters_and_gradients(cfg, params, x, y)
+    pairs, layers = x.size * cfg.num_experts_per_tok, 4
+    assert int(full[2]["sum"].pop("moe_rows_buffered")) == layers * pairs
+    assert int(compact[2]["sum"].pop("moe_rows_buffered")) == layers * 96 < layers * pairs
+    for a, b in zip(jax.tree.leaves(compact), jax.tree.leaves(full)):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+    assert int(compact[2]["sum"]["moe_rows_dropped"]) == 0
+    assert 0 < int(compact[2]["sum"]["moe_rows_routed"]) < layers * 96
+
+
+def test_routed_layer_gradients_on_the_compact_buffer(monkeypatch, small_row_tiles):
+    """The layer alone: output and the gradients of the input and of every
+    parameter, the block's own backward on the compact buffer against reverse
+    mode through the plain forward on all the pairs."""
+    cfg = tiny_config(expert_offset=0)
+    layer = held_layer(cfg)
+    u = jax.random.normal(jax.random.key(0), (2, 1, SEQ, cfg.hidden_size))
+    params = layer.init(jax.random.key(1), u)["params"]
+    probe = jax.random.normal(jax.random.key(2), u.shape)
+
+    def run(params, u):
+        out, counts = layer.apply({"params": params}, u)
+        return (out * probe).sum(), (out, counts)
+
+    (_, (out, counts)), grads = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(params, u)
+    assert int(counts["sum"]["moe_rows_buffered"]) == 96 < int(counts["sum"]["moe_rows_offered"])
+
+    def plain(rows, *operands):
+        return program._buffer_forward(operands[4].size, 0, *operands)[:2]
+
+    monkeypatch.setattr(program, "_expert_block", plain)
+    (_, (want, _)), want_grads = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(params, u)
+    np.testing.assert_allclose(out, want, rtol=1e-6, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    assert float(jnp.abs(grads[0]["w2"]).max()) > 0 and float(jnp.abs(grads[1]).max()) > 0
+
+
+@pytest.mark.parametrize("skewed", [False, True])
+def test_buffers_that_do_not_divide_the_pairs(monkeypatch, small_row_tiles, skewed):
+    """50 tokens, 100 pairs, buffers of 40 rows: the sorted rows are padded to
+    three buffers, and at the worst skew the third holds 20 routed rows and a
+    token's two pairs lie in different buffers.  Output, counters and
+    gradients are those of one buffer of all the pairs."""
+    monkeypatch.setattr(program, "BUFFER_TABLE_BYTES", 40 * ROW_BYTES)
+    cfg = tiny_config(expert_offset=0)
+    layer = held_layer(cfg)
+    u = jax.random.normal(jax.random.key(0), (1, 1, 50, cfg.hidden_size))
+    params = layer.init(jax.random.key(1), u)["params"]
+    if skewed:
+        params = dict(params, expert_bias=jnp.zeros(8).at[:2].set(10.0))
+    probe = jax.random.normal(jax.random.key(2), u.shape)
+
+    def run(params, u):
+        out, counts = layer.apply({"params": params}, u)
+        return (out * probe).sum(), (out, counts)
+
+    got = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(params, u)
+    monkeypatch.setattr(program, "buffer_rows", lambda pairs, *_: pairs)
+    want = jax.value_and_grad(run, argnums=(0, 1), has_aux=True)(params, u)
+    counts, whole = got[0][1][1]["sum"], want[0][1][1]["sum"]
+    assert int(counts["moe_rows_buffered"]) == (120 if skewed else 40)
+    assert int(whole["moe_rows_buffered"]) == 100 == int(counts["moe_rows_offered"])
+    assert int(counts["moe_rows_routed"]) == (100 if skewed else int(whole["moe_rows_routed"]))
+    assert int(counts["moe_rows_dropped"]) == int(whole["moe_rows_dropped"]) == 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if a.shape == b.shape and jnp.issubdtype(a.dtype, jnp.floating):
+            # float32 sums in another order: the gate's gradient is of the order of 10
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=2e-5)
+
+
+def test_overflowing_the_buffer_falls_back_on_the_device(small_row_tiles):
+    """A batch skewed so that every pair is held overflows the buffer: the
+    block runs on buffer after buffer until they hold all the pairs, nothing
+    is dropped, the result is the reference's, and one jit served the
+    balanced and the skewed batch."""
+    cfg = tiny_config()
+    model, params = build_model(cfg), init(cfg)
+    balanced, y = tokens(0)
+    pairs, layers = balanced.size * cfg.num_experts_per_tok, 4
+    compiles = []
+
+    @jax.jit
+    def run(params, x, y):
+        compiles.append(1)
+
+        def loss_fn(params):
+            logits, aux = model.apply({"params": params}, x, train=True, mutable=["counters"])
+            return logits.astype(jnp.float32).mean(), aux["counters"]["sum"]
+
+        return jax.value_and_grad(loss_fn, has_aux=True)(params)
+
+    (_, counters), _ = run(params, balanced, y)
+    assert int(counters["moe_rows_buffered"]) == layers * 96
+    assert int(counters["moe_rows_dropped"]) == 0
+
+    # every pair held in every routed layer: a bias that selects the held two
+    forced = jnp.full((cfg.num_experts,), -10.0, jnp.float32).at[jnp.array([2, 3])].set(10.0)
+    skewed = {
+        name: dict(layer, feed_forward=dict(layer["feed_forward"], expert_bias=forced))
+        if name.startswith("layers_") and "expert_bias" in layer["feed_forward"]
+        else layer
+        for name, layer in params.items()
+    }
+    (_, counters), grads = run(skewed, balanced, y)
+    assert len(compiles) == 1
+    assert int(counters["moe_rows_routed"]) == int(counters["moe_rows_offered"]) == layers * pairs
+    assert int(counters["moe_rows_buffered"]) == layers * 3 * 96  # whole buffers: 288 rows for 256
+    assert int(counters["moe_rows_dropped"]) == 0
+    assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree.leaves(grads))
+    out = check.compare(cfg, "lfm2_moe", skewed, {}, balanced, y)
     assert out["ok"], out
 
 
@@ -337,6 +520,7 @@ def test_float_ids_are_cast_and_train_mode_is_the_same_function():
     np.testing.assert_allclose(plain, remat, rtol=1e-6, atol=1e-6)
     assert set(aux["counters"]["sum"]) == {
         "tokens_per_step", "moe_rows_routed", "moe_rows_offered", "moe_rows_dropped",
+        "moe_rows_buffered",
     }
     assert set(aux["counters"]["max"]) == {"moe_max_load", "attention_kernel_layers"}
     assert plain.shape == (2, 1, SEQ, VOCAB) and plain.dtype == jnp.float32
@@ -365,12 +549,45 @@ def test_step_flops_counts_every_product_and_the_grouped_ones_apart():
     dense, grouped = flops.step_flops(cfg, 2, 3, channels=1)
     model = dataclasses.asdict(cfg.model)
     offered = 3 * 2 * SEQ * cfg.model.num_experts_per_tok * 4  # a step's pairs, 4 routed layers
-    # every row of the buffers in a group: three products a row, forward and backward
+    # every row offered in a group: three products a row, forward and backward
     assert grouped == seq_flops.expert_flops(model, offered) * 3
     # the program's walk counts the full S x S scores the blocks compute beyond
     # the causal half, the benchmark's count the causal half alone
     want = seq_flops.step_flops(model, SEQ, 3 * 2, 0)
     assert want <= dense <= 1.1 * want
+
+
+@pytest.mark.parametrize(
+    "held, micro, rows",
+    [(2, 8, 512), (2, 12, 1024), (1, 32, 1024), (2, 32, 2048), (8, 8, 1024)],
+)
+def test_grouped_flops_are_per_row_times_routed_rows_whatever_the_buffer(held, micro, rows):
+    """128 pairs a sequence and layer.  The walk sees a routed layer's buffer
+    once outside the loop over later buffers and once in it: together as many
+    rows as the pairs (2 x 512 of 1,024; 2 x 2,048 of 4,096), more (2 x 1,024
+    of 1,536) or fewer (2 x 1,024 of 4,096), and one buffer of all the pairs
+    with no loop where every expert is held.  It counts what a row costs for
+    every pair either way, and the accountant scales it by the routed rows."""
+    from ddlpc_tpu.obs import flops
+    from ddlpc_tpu.obs.registry import MetricsRegistry
+
+    import seq_flops
+
+    cfg = flops_config()
+    cfg = cfg.replace(model=dataclasses.replace(cfg.model, experts_held=held, expert_offset=0))
+    pairs = micro * SEQ * cfg.model.num_experts_per_tok
+    assert program.buffer_rows(pairs, held, 8, ROW_BYTES) == rows
+    dense, grouped = flops.step_flops(cfg, micro, 3, channels=1)
+    model = dataclasses.asdict(cfg.model)
+    offered = 3 * pairs * 4  # sync 3, four routed layers
+    per_row = seq_flops.expert_flops(model, 1) * 3  # three products, forward and backward
+    assert grouped == per_row * offered
+    perf = flops.PerfAccountant(
+        MetricsRegistry(), flops_per_step=dense, grouped_flops_per_step=grouped, peak_flops=1e12
+    )
+    routed = offered * held // 8
+    perf.routed(rows_routed=routed, rows_offered=offered)
+    assert perf.flops_per_step == dense + per_row * routed
 
 
 def test_step_flops_goes_by_the_traced_program_not_by_a_name():
