@@ -126,6 +126,7 @@ MODULE_TIERS: Dict[str, str] = {
     "ddlpc_tpu.models.unetpp": JAX,
     "ddlpc_tpu.models.deeplabv3p": JAX,
     "ddlpc_tpu.models.lfm2_moe": JAX,
+    "ddlpc_tpu.models.keye_vl2": JAX,
     "ddlpc_tpu.ops": JAX,
     "ddlpc_tpu.ops.losses": JAX,
     "ddlpc_tpu.ops.metrics": JAX,

@@ -120,6 +120,24 @@ class ModelConfig:
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     use_expert_bias: bool = True
+    # How a token's scores over the experts are made from the router's
+    # outputs (models/lfm2_moe.py:RoutedExperts): 'sigmoid' (lfm2_moe: each
+    # expert on its own, the picked ones' sum normalised behind 1e-6) or
+    # 'softmax' (keye_vl2: over all num_experts, renormalised over the picked
+    # ones, no epsilon).
+    router_score: str = "sigmoid"  # sigmoid | softmax
+    # The keye_vl2 family (models/keye_vl2.py), under the published names of
+    # its config.json (``sa_config`` flattened to ``indexer_*``); the defaults
+    # leave every other family's files loading as before.  ``head_dim`` 0
+    # means hidden_size / num_attention_heads; ``mrope_section`` () means one
+    # position stream; an untied head has its own [num_classes, hidden] leaf.
+    head_dim: int = 0
+    mrope_section: Tuple[int, ...] = ()
+    tie_word_embeddings: bool = True
+    indexer_num_heads: int = 16
+    indexer_head_dim: int = 64
+    indexer_num_kv_heads: int = 1
+    indexer_topk: int = 2048
 
 
 @dataclass(frozen=True)

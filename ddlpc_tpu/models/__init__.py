@@ -14,6 +14,7 @@ from flax import linen as nn
 
 from ddlpc_tpu.config import ModelConfig
 from ddlpc_tpu.models.deeplabv3p import DeepLabV3Plus
+from ddlpc_tpu.models.keye_vl2 import KeyeVL2
 from ddlpc_tpu.models.lfm2_moe import LFM2MoE
 from ddlpc_tpu.models.unet import UNet
 from ddlpc_tpu.models.unetpp import UNetPP
@@ -116,6 +117,26 @@ def _build_lfm2_moe(cfg: ModelConfig, norm_axis_name: Optional[str]) -> nn.Modul
             f"are not among the router's {cfg.num_experts}"
         )
     return LFM2MoE(cfg)
+
+
+@register("keye_vl2")
+def _build_keye_vl2(cfg: ModelConfig, norm_axis_name: Optional[str]) -> nn.Module:
+    del norm_axis_name  # RMSNorm only
+    if not cfg.layer_types or set(cfg.layer_types) != {"full_attention"}:
+        raise ValueError(
+            f"keye_vl2 needs model.layer_types of 'full_attention', one a layer, "
+            f"got {cfg.layer_types!r}"
+        )
+    if cfg.indexer_num_kv_heads != 1:
+        raise ValueError("keye_vl2's indexer shares one key head (indexer_num_kv_heads 1)")
+    if cfg.router_score not in ("sigmoid", "softmax"):
+        raise ValueError(f"unknown model.router_score {cfg.router_score!r}")
+    if not 0 <= cfg.expert_offset <= cfg.num_experts - cfg.experts_held:
+        raise ValueError(
+            f"experts [{cfg.expert_offset}, {cfg.expert_offset + cfg.experts_held}) "
+            f"are not among the router's {cfg.num_experts}"
+        )
+    return KeyeVL2(cfg)
 
 
 def build_model(cfg: ModelConfig, norm_axis_name: Optional[str] = None) -> nn.Module:

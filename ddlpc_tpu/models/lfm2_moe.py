@@ -431,9 +431,13 @@ _expert_block.defvjp(_expert_block_fwd, _expert_block_bwd)
 
 
 class RoutedExperts(nn.Module):
-    """Sigmoid-scored top-k routing over all ``num_experts`` and the SwiGLU
-    experts held here.  Returns the held experts' part of the layer's output
-    and the layer's routing counts.
+    """Top-k routing over all ``num_experts`` and the SwiGLU experts held
+    here.  Returns the held experts' part of the layer's output and the
+    layer's routing counts.  ``score`` says how a token's scores are made of
+    the router's outputs: ``sigmoid`` (each expert on its own; the picked
+    ones' sum is normalised behind 1e-6) or ``softmax`` (over all the
+    experts; the picked ones renormalise with no epsilon).  Everything after
+    the scores is the same.
 
     The buffer: the (token, expert) pairs sort with the held groups first, and
     the expert side of the block (the dispatch gather, the masks, the three
@@ -470,6 +474,7 @@ class RoutedExperts(nn.Module):
     routed_scaling_factor: float = 1.0
     use_expert_bias: bool = True
     dtype: Any = jnp.bfloat16
+    score: str = "sigmoid"  # sigmoid | softmax
 
     @nn.compact
     def __call__(self, u):
@@ -484,7 +489,8 @@ class RoutedExperts(nn.Module):
 
         with jax.named_scope("ddlpc/moe/route"):
             # float32 at full precision: a bf16 pass would flip selections.
-            scores = jax.nn.sigmoid(
+            squash = {"sigmoid": jax.nn.sigmoid, "softmax": _softmax_rows}[self.score]
+            scores = squash(
                 jnp.dot(x.astype(jnp.float32), gate, precision=lax.Precision.HIGHEST)
             )
             choose = scores
@@ -499,7 +505,8 @@ class RoutedExperts(nn.Module):
             chosen = selected[..., None] == jnp.arange(self.num_experts)  # [N, k, E]
             weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
             if self.norm_topk_prob:
-                weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-6)
+                total = weights.sum(axis=-1, keepdims=True)
+                weights = weights / (total + 1e-6 if self.score == "sigmoid" else total)
             weights = weights * self.routed_scaling_factor
 
             local = selected - self.expert_offset

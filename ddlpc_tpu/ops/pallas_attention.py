@@ -24,6 +24,21 @@ in VMEM until the head's last pair, which bounds the sequence (``MAX_SEQ``).
 Every ``pallas_call`` carries a ``pl.CostEstimate`` of the causal half of
 the products it stands for; ``obs/flops.product_flops`` counts the kernel by
 it (the kernel's body holds one block's products, not the grid's).
+
+:func:`selected_attention` is the same pair of kernels over a per-pair
+selection (``models/keye_vl2.py``: a learned indexer picks each query's
+keys): an additive ``bias [B, S, S]``, 0 where a query sees a key and
+``-1e30`` where it does not (the causal mask included), is read a block a
+grid step and added to the float32 scores in place of the diagonal mask.
+Every causal block is still computed, so the result is exactly the softmax
+over the selected keys and the time is the causal kernel's; skipping blocks
+by the selection is not done here.  It also returns the rows' log-sum-exp.
+
+Any head size that is a multiple of 128 lanes or divides them, and any number
+of query heads a k/v head: more than ``HEADS_PER_STEP`` of them are split into
+that many a grid step (each group reads its own copy of the k/v blocks, and
+their dK and dV are summed outside), so a step's scores stay
+``HEADS_PER_STEP · block`` rows whatever the grouping.
 """
 
 from __future__ import annotations
@@ -47,6 +62,10 @@ _NT = (((1,), (1,)), ((), ()))  # a · bᵀ
 _TN = (((0,), (0,)), ((), ()))  # aᵀ · b
 # The backward keeps dK and dV of one k/v head's whole sequence in VMEM.
 MAX_SEQ = 16384
+# Query heads of one k/v head that a grid step stacks: the scores of a step
+# are [HEADS_PER_STEP · BLOCK, BLOCK] float32 (4 MiB, and four such in the
+# backward).
+HEADS_PER_STEP = 4
 
 
 def supported(seq_len: int, block: int = BLOCK) -> bool:
@@ -80,8 +99,9 @@ def _scores(a, b, scale: float):
     return s if scale == 1.0 else s * scale
 
 
-def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, scale: float):
+def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, *refs, scale: float, select: bool = False):
+    bias_ref = refs[0] if select else None
+    o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[select:]
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
     g, block, d = q_ref.shape
@@ -96,7 +116,10 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_re
     def step(diagonal: bool):
         k, v = k_ref[...], v_ref[...]
         s = _scores(q_ref[...].reshape(rows, d), k, scale)  # [rows, keys] f32
-        if diagonal:
+        if select:  # the selection holds the causal mask; one bias block serves the g heads
+            bias = bias_ref[...].astype(jnp.float32)
+            s = (s.reshape(g, block, block) + bias[None]).reshape(rows, block)
+        elif diagonal:
             s = jnp.where(_visible(rows, block), s, _MASKED)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -120,13 +143,15 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_re
         lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
 
 
-def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
-                dq_acc, dk_acc, dv_acc, *, scale: float):
+def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                scale: float, select: bool = False):
     """One (query block, key block) pair of dQ, dK and dV.  The scores are
     held transposed, ``[keys, rows]``, so the saved row statistics broadcast
     along sublanes.  dQ accumulates over a query block's keys and is written
     on the diagonal; dK and dV of the whole sequence (one k/v head) accumulate
     in VMEM and are written after the last pair."""
+    bias_ref = refs[0] if select else None
+    dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs[select:]
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
     g, block, d = q_ref.shape
@@ -145,7 +170,10 @@ def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k, v = k_ref[...], v_ref[...]
         q, do = q_ref[...].reshape(rows, d), do_ref[...].reshape(rows, d)
         st = _scores(k, q, scale)
-        if diagonal:
+        if select:
+            bias_t = bias_ref[...].astype(jnp.float32).T  # [keys, block]
+            st = st + jnp.concatenate([bias_t] * g, axis=1)
+        elif diagonal:
             st = jnp.where(_visible(rows, block, keys_minor=False), st, _MASKED)
         pt = jnp.exp(st - lse_ref[...])
         keys = pl.ds(pl.multiple_of(j * block, block), block)
@@ -213,59 +241,77 @@ def _call(kernel, name, operands, out_shapes, out_specs, scratch, in_specs, *, b
     )(*tables, *operands)
 
 
-def _specs(g: int, block: int, d: int):
-    """Block specs of a [B, KV, G, S, D] query-side array, a [B, KV, S, D]
-    key-side array and a [B, KV, S/block, 1, G·block] row statistic, indexed
-    through the prefetched (query block, key block) tables."""
+def _specs(g: int, block: int, d: int, split: int = 1):
+    """Block specs of a [B, KV·split, G, S, D] query-side array, a
+    [B, KV, S, D] key-side array (``split`` groups of query heads read each
+    k/v head), a [B, KV·split, S/block, 1, G·block] row statistic and a
+    [B, S, S] selection bias, indexed through the prefetched (query block,
+    key block) tables."""
     q = pl.BlockSpec((None, None, g, block, d), lambda b, h, t, qi, kj: (b, h, 0, qi[t], 0))
-    kv = pl.BlockSpec((None, None, block, d), lambda b, h, t, qi, kj: (b, h, kj[t], 0))
+    if split == 1:  # no division in the index map: the causal kernels' program as it was
+        kv = pl.BlockSpec((None, None, block, d), lambda b, h, t, qi, kj: (b, h, kj[t], 0))
+    else:
+        kv = pl.BlockSpec((None, None, block, d), lambda b, h, t, qi, kj: (b, h // split, kj[t], 0))
     row = pl.BlockSpec((None, None, None, 1, g * block), lambda b, h, t, qi, kj: (b, h, qi[t], 0, 0))
-    return q, kv, row
+    bias = pl.BlockSpec((None, block, block), lambda b, h, t, qi, kj: (b, qi[t], kj[t]))
+    return q, kv, row, bias
 
 
-def _forward(q, k, v, block: int, scale: float, interpret: bool):
+def _forward(q, k, v, block: int, scale: float, interpret: bool, bias=None):
     b, kv, g, s, d = q.shape
-    q_spec, kv_spec, row_spec = _specs(g, block, d)
+    q_spec, kv_spec, row_spec, bias_spec = _specs(g, block, d, kv // k.shape[1])
     rows = g * block
+    select = bias is not None
     return _call(
-        functools.partial(_fwd_kernel, scale=scale),
-        "causal_attention_fwd",
-        (q, k, v),
+        functools.partial(_fwd_kernel, scale=scale, select=select),
+        "selected_attention_fwd" if select else "causal_attention_fwd",
+        (q, k, v, bias) if select else (q, k, v),
         (jax.ShapeDtypeStruct(q.shape, q.dtype),
          jax.ShapeDtypeStruct((b, kv, s // block, 1, rows), jnp.float32)),
         (q_spec, row_spec),
         [pltpu.VMEM((rows, LANES), jnp.float32), pltpu.VMEM((rows, LANES), jnp.float32),
          pltpu.VMEM((rows, d), jnp.float32)],
-        [q_spec, kv_spec, kv_spec],
+        [q_spec, kv_spec, kv_spec, bias_spec] if select else [q_spec, kv_spec, kv_spec],
         block=block,
         products=2,
         interpret=interpret,
     )
 
 
-def _backward(q, k, v, do, lse, delta, block: int, scale: float, interpret: bool):
+def _backward(q, k, v, do, lse, delta, block: int, scale: float, interpret: bool, bias=None):
+    """dQ like q; dK and dV ``[B, KV·split, S, D]``, one per group of query
+    heads (the caller sums the ``split`` groups of a k/v head)."""
     b, kv, g, s, d = q.shape
-    q_spec, kv_spec, row_spec = _specs(g, block, d)
+    q_spec, kv_spec, row_spec, bias_spec = _specs(g, block, d, kv // k.shape[1])
     whole = pl.BlockSpec((None, None, s, d), lambda b_, h, t, qi, kj: (b_, h, 0, 0))
     rows = g * block
+    select = bias is not None
     return _call(
-        functools.partial(_bwd_kernel, scale=scale),
-        "causal_attention_bwd",
-        (q, k, v, do, lse, delta),
-        (jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(k.shape, k.dtype),
-         jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        functools.partial(_bwd_kernel, scale=scale, select=select),
+        "selected_attention_bwd" if select else "causal_attention_bwd",
+        (q, k, v, do, lse, delta, bias) if select else (q, k, v, do, lse, delta),
+        (jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct((b, kv, s, d), k.dtype),
+         jax.ShapeDtypeStruct((b, kv, s, d), v.dtype)),
         (q_spec, whole, whole),
         [pltpu.VMEM((rows, d), jnp.float32), pltpu.VMEM((s, d), jnp.float32),
          pltpu.VMEM((s, d), jnp.float32)],
-        [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec] + [bias_spec] * select,
         block=block,
         products=5,
         interpret=interpret,
     )
 
 
+def _groups(heads: int, kv: int) -> int:
+    """Groups of query heads the kernels see: the k/v heads, or more where a
+    k/v head serves more than HEADS_PER_STEP query heads."""
+    g = heads // kv
+    return kv * (g // HEADS_PER_STEP) if g > HEADS_PER_STEP and g % HEADS_PER_STEP == 0 else kv
+
+
 def _query_major(x, kv: int):
-    """``[B, S, H, D]`` as ``[B, KV, G, S, D]``, the layout the kernels read."""
+    """``[B, S, H, D]`` as ``[B, KV, G, S, D]``, the layout the kernels read
+    (``kv`` groups of ``G`` adjacent query heads)."""
     b, s, h, d = x.shape
     return x.reshape(b, s, kv, h // kv, d).transpose(0, 2, 3, 1, 4)
 
@@ -289,13 +335,33 @@ def _scales(d: int):
     return (scale, 1.0) if math.frexp(scale)[0] == 0.5 else (1.0, scale)
 
 
-def _forward_from(q, k, v, block: int, interpret: bool):
+def _forward_from(q, k, v, block: int, interpret: bool, bias=None):
     into_q, scale = _scales(q.shape[-1])
     q = q * jnp.asarray(into_q, q.dtype)
     out, lse = _forward(
-        _query_major(q, k.shape[2]), _key_major(k), _key_major(v), block, scale, interpret
+        _query_major(q, _groups(q.shape[2], k.shape[2])), _key_major(k), _key_major(v),
+        block, scale, interpret, bias,
     )
     return _caller_layout(out), lse
+
+
+def _backward_from(residuals, do, block: int, interpret: bool, bias=None):
+    q, k, v, out, lse = residuals
+    b, s, h, d = q.shape
+    kv = k.shape[2]
+    groups = _groups(h, kv)
+    into_q, scale = _scales(d)
+    into_q = jnp.asarray(into_q, q.dtype)
+    # Σ_d dO·O a row, laid out as the log-sum-exp: [B, groups, S/block, 1, G·block]
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, S, H]
+    delta = delta.reshape(b, s // block, block, groups, h // groups).transpose(0, 3, 1, 4, 2)
+    dq, dk, dv = _backward(
+        _query_major(q * into_q, groups), _key_major(k), _key_major(v), _query_major(do, groups),
+        lse, delta.reshape(lse.shape), block, scale, interpret, bias,
+    )
+    if groups > kv:  # one dK, dV per group of query heads: add a k/v head's groups
+        dk, dv = (x.reshape(b, kv, groups // kv, s, d).sum(axis=2, dtype=x.dtype) for x in (dk, dv))
+    return _caller_layout(dq) * into_q, _key_major(dk), _key_major(dv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -311,22 +377,18 @@ def _attention_fwd(q, k, v, block, interpret):
 
 
 def _attention_bwd(block, interpret, residuals, do):
-    q, k, v, out, lse = residuals
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    into_q, scale = _scales(d)
-    into_q = jnp.asarray(into_q, q.dtype)
-    # Σ_d dO·O a row, laid out as the log-sum-exp: [B, KV, S/block, 1, G·block]
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B, S, H]
-    delta = delta.reshape(b, s // block, block, kv, h // kv).transpose(0, 3, 1, 4, 2)
-    dq, dk, dv = _backward(
-        _query_major(q * into_q, kv), _key_major(k), _key_major(v), _query_major(do, kv),
-        lse, delta.reshape(lse.shape), block, scale, interpret,
-    )
-    return _caller_layout(dq) * into_q, _key_major(dk), _key_major(dv)
+    return _backward_from(residuals, do, block, interpret)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
+
+
+def _check(seq_len: int, block: int):
+    if not supported(seq_len, block):
+        raise ValueError(
+            f"sequence length {seq_len} is not a multiple of the kernel's block {block} "
+            f"up to {MAX_SEQ}"
+        )
 
 
 def causal_attention(q, k, v, *, block: int = BLOCK, interpret: bool = False):
@@ -334,9 +396,46 @@ def causal_attention(q, k, v, *, block: int = BLOCK, interpret: bool = False):
     ``[B, S, KV, D]`` with each k/v head serving ``H / KV`` query heads
     (query head ``h`` reads k/v head ``h // (H / KV)``); ``S`` a multiple of
     ``block``.  Returns ``[B, S, H, D]`` in q's dtype."""
-    if not supported(q.shape[1], block):
-        raise ValueError(
-            f"sequence length {q.shape[1]} is not a multiple of the kernel's block {block} "
-            f"up to {MAX_SEQ}"
-        )
+    _check(q.shape[1], block)
     return _attention(q, k, v, block, interpret)
+
+
+def _rows_major(lse, heads: int):
+    """The kernels' row statistic ``[B, groups, S/block, 1, G·block]`` as ``[B, S, H]``."""
+    b, groups, blocks, _, rows = lse.shape
+    g = heads // groups
+    lse = lse.reshape(b, groups, blocks, g, rows // g).transpose(0, 2, 4, 1, 3)
+    return lse.reshape(b, blocks * (rows // g), heads)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _selected(q, k, v, bias, block, interpret):
+    out, lse = _forward_from(q, k, v, block, interpret, bias)
+    return out, _rows_major(lse, q.shape[2])
+
+
+def _selected_fwd(q, k, v, bias, block, interpret):
+    out, lse = _forward_from(q, k, v, block, interpret, bias)
+    return (out, _rows_major(lse, q.shape[2])), (q, k, v, out, lse, bias)
+
+
+def _selected_bwd(block, interpret, residuals, cotangents):
+    *residuals, bias = residuals
+    # the log-sum-exp is a statistic for detached readers: its cotangent is dropped
+    return (*_backward_from(residuals, cotangents[0], block, interpret, bias), None)
+
+
+_selected.defvjp(_selected_fwd, _selected_bwd)
+
+
+def selected_attention(q, k, v, bias, *, block: int = BLOCK, interpret: bool = False):
+    """``softmax`` over each query's selected keys of ``q kᵀ / sqrt(D)``, times
+    v.  Shapes as :func:`causal_attention`; ``bias [B, S, S]`` (any floating
+    dtype that holds -1e30, bfloat16 halves its bytes) is 0 where query ``t``
+    sees key ``s`` and ``-1e30`` where not, the keys after ``t`` included, and
+    every query sees at least one key.  Returns ``(out [B, S, H, D],
+    lse [B, S, H])``: the float32 log-sum-exp of each row's selected scores,
+    for readers that take no gradient through it.  No gradient reaches
+    ``bias``."""
+    _check(q.shape[1], block)
+    return _selected(q, k, v, bias, block, interpret)

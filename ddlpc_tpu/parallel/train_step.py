@@ -104,26 +104,39 @@ def _loss_and_metrics(
     without a ``batch_stats`` collection keeps the (empty) tree it was given;
     ``counters`` is what the model sowed into its ``counters`` collection under
     ``train=True``: scalars under ``"sum"`` and under ``"max"``, by how the step
-    reduces them (``models/lfm2_moe.py``'s routing counts; empty for the conv zoo)."""
+    reduces them (``models/lfm2_moe.py``'s routing counts; empty for the conv zoo).
+
+    A model's own losses are the scalars it sowed into its ``losses``
+    collection under ``train=True`` (``models/keye_vl2.py``'s ``indexer_kl``):
+    each is added to the loss here, inside what the step differentiates, and
+    reported beside it under its name as the mean over micro-batches and
+    replicas (``counters["mean"]``).  A model that sows none gets the program
+    it had."""
     variables = {"params": params, "batch_stats": batch_stats}
     counters = {}
+    own = {}
     if train:
         logits, updates = model.apply(
-            variables, images, train=True, mutable=["batch_stats", "counters"]
+            variables, images, train=True, mutable=["batch_stats", "counters", "losses"]
         )
         new_stats = updates.get("batch_stats", batch_stats)
         counters = dict(updates.get("counters", {}))
+        own = dict(updates.get("losses", {}))
     else:
         logits = model.apply(variables, images, train=False)
         new_stats = batch_stats
     loss, acc = loss_from_logits(model, logits, labels, train)
+    if own:
+        loss = loss + sum(own.values())
+        counters["mean"] = own
     return loss, (new_stats, acc, counters)
 
 
 def _reduce_counters(stacked: dict, axis_name: Optional[str] = None) -> dict:
     """One value per optimizer step from the model's per-micro-batch counters
     (leading axis ``A``): those under ``"sum"`` added up over micro-batches
-    and replicas, those under ``"max"`` the largest of them.  The model says
+    and replicas, those under ``"max"`` the largest of them, those under
+    ``"mean"`` (the model's own losses) their mean.  The model says
     which is which and nothing downstream asks again: the Trainer records
     every counter's mean over an epoch's steps (the mean of a ``"max"``
     counter's per-step values included).  ``axis_name`` is None in a program
@@ -133,6 +146,8 @@ def _reduce_counters(stacked: dict, axis_name: Optional[str] = None) -> dict:
         out[name] = v.sum() if axis_name is None else lax.psum(v.sum(), axis_name)
     for name, v in stacked.get("max", {}).items():
         out[name] = v.max() if axis_name is None else lax.pmax(v.max(), axis_name)
+    for name, v in stacked.get("mean", {}).items():
+        out[name] = v.mean() if axis_name is None else lax.pmean(v.mean(), axis_name)
     return out
 
 

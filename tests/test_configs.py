@@ -83,6 +83,15 @@ def _shrunk(cfg: ExperimentConfig, workdir: str) -> ExperimentConfig:
             moe_intermediate_size=48, num_attention_heads=4, num_key_value_heads=2,
             num_experts=8, num_experts_per_tok=2, experts_held=2,
         )
+    if cfg.model.name == "keye_vl2":
+        # As above, with the indexer picking 24 of a 128-token tile's keys.
+        h, w, scale = 1, 128, 1
+        model = dataclasses.replace(
+            cfg.model, num_classes=96, hidden_size=64, moe_intermediate_size=48,
+            num_attention_heads=8, num_key_value_heads=2, head_dim=32, mrope_section=(4, 6, 6),
+            num_experts=16, num_experts_per_tok=2, experts_held=2, indexer_num_heads=16,
+            indexer_head_dim=16, indexer_topk=24, layer_types=("full_attention",) * 2,
+        )
     return cfg.replace(
         model=model,
         data=dataclasses.replace(
@@ -136,8 +145,79 @@ def test_config_files_exist():
     # The five BASELINE parity configs plus the TPU-first flagship and the
     # TPU-first U-Net++ (s2d stem — 20× the paper layout's throughput);
     # serve_*.json deploy artifacts are filtered out above.
-    # ... and lfm2_24b_a2b_ep8.json, the one token-tile configuration.
-    assert len(CONFIG_FILES) == 8, CONFIG_FILES
+    # ... and the two token-tile configurations, lfm2_24b_a2b_ep8.json and
+    # keye_vl2_30b_a3b_ep8.json.
+    assert len(CONFIG_FILES) == 9, CONFIG_FILES
+
+
+# ---- keye_vl2_30b_a3b_ep8: the shipped file, the benchmark's copy, the cut ----
+
+_KEYE = os.path.join(CONFIG_DIR, "keye_vl2_30b_a3b_ep8.json")
+_KEYE_COPY = os.path.join(
+    CONFIG_DIR, "..", "benchmark", "configs", "keye_vl2_30b_a3b_ep8.json"
+)
+
+
+def _keye_copy_is_the_shipped_file():
+    shipped, copy = json.load(open(_KEYE)), json.load(open(_KEYE_COPY))
+    for group in ("model", "data", "train", "parallel", "compression"):
+        assert copy[group] == shipped[group], group
+    assert copy["reference"] == "keye_vl2" and copy["reference_sample_tiles"] == 1
+    assert ExperimentConfig.from_dict(shipped).to_dict()["model"]["name"] == "keye_vl2"
+
+
+def _keye_holds_every_published_width():
+    m = ExperimentConfig.from_dict(json.load(open(_KEYE))).model
+    assert (m.hidden_size, m.moe_intermediate_size, m.head_dim) == (2048, 768, 128)
+    assert (m.num_attention_heads, m.num_key_value_heads) == (32, 4)
+    assert (m.num_experts, m.num_experts_per_tok, m.experts_held, m.expert_offset) == (128, 8, 16, 0)
+    assert (m.indexer_num_heads, m.indexer_head_dim, m.indexer_num_kv_heads, m.indexer_topk) == (16, 64, 1, 2048)
+    assert m.mrope_section == (16, 24, 24) and m.rope_theta == 1e7 and m.norm_eps == 1e-6
+    assert m.router_score == "softmax" and not m.tie_word_embeddings and not m.use_expert_bias
+    assert m.norm_topk_prob and m.layer_types == ("full_attention",) * 4 and m.num_dense_layers == 0
+    assert m.num_classes == 18992 == 151936 // 8
+
+
+def _keye_top_level_is_the_catalogs_config_but_for_the_cut():
+    copy = json.load(open(_KEYE_COPY))
+    m = copy["model"]
+    assert copy["reduced"] == ["num_hidden_layers", "num_experts", "vocab_size"]
+    assert copy["published"] == {"num_hidden_layers": 48, "num_experts": 128, "vocab_size": 151936}
+    assert copy["num_hidden_layers"] == len(m["layer_types"]) == 4
+    assert copy["num_experts"] == m["experts_held"] == 16 and copy["num_local_experts"] == m["num_experts"]
+    assert copy["vocab_size"] == m["num_classes"] and copy["head_dim"] == m["head_dim"]
+    assert copy["hidden_size"] == m["hidden_size"] and copy["moe_intermediate_size"] == m["moe_intermediate_size"]
+    assert copy["num_attention_heads"] == m["num_attention_heads"]
+    assert copy["num_key_value_heads"] == m["num_key_value_heads"]
+    assert copy["num_experts_per_tok"] == m["num_experts_per_tok"] and copy["rms_norm_eps"] == m["norm_eps"]
+    assert copy["rope_theta"] == m["rope_theta"] and copy["tie_word_embeddings"] is False
+    assert copy["rope_scaling"]["mrope_section"] == m["mrope_section"]
+    sa = copy["sa_config"]
+    assert (sa["indexer_num_heads"], sa["indexer_head_dim"], sa["indexer_num_kv_heads"], sa["topk"]) == (
+        m["indexer_num_heads"], m["indexer_head_dim"], m["indexer_num_kv_heads"], m["indexer_topk"])
+    assert (sa["q_chunk_size"], sa["kv_chunk_size"]) == (512, 512)
+    assert "8 chips share each layer" in copy["deployment"]["layout"]
+
+
+def _keye_lists_what_it_assumed():
+    assumed = " ".join(json.load(open(_KEYE_COPY))["assumed"])
+    for item in ("RMS norm on q and on k", "half-split", "DeepSeek-V3.2-Exp", "plain rotary",
+                 "(16*64)^-1/2", "no norm on kI", "ties", "weight 1", "mean over queries",
+                 "q_chunk_size", "no epsilon", "warm-up over 2,000 steps", "image tower"):
+        assert item in assumed, item
+
+
+@pytest.mark.parametrize(
+    "check",
+    [
+        pytest.param(_keye_copy_is_the_shipped_file, id="copy-is-shipped"),
+        pytest.param(_keye_holds_every_published_width, id="published-widths"),
+        pytest.param(_keye_top_level_is_the_catalogs_config_but_for_the_cut, id="catalog-keys-and-reduced"),
+        pytest.param(_keye_lists_what_it_assumed, id="assumed"),
+    ],
+)
+def test_keye_vl2_configuration(check):
+    check()
 
 
 @pytest.mark.parametrize(

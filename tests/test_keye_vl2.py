@@ -1,0 +1,522 @@
+"""The keye_vl2 family (models/keye_vl2.py) at a tiny size on the CPU: the
+program against the plain float32 reference the benchmark keeps
+(benchmark/reference/keye_vl2.py) on logits, cross-entropy and its gradients,
+and on the indexer's loss and its gradients (benchmark/check_indexer.py); the
+two exact zeros; the selection rule; sectioned rotary positions; the softmax
+router and the eight shares against the uncut layer; the extended attention
+kernels interpreted against the XLA form; the Trainer; the step's own-loss
+seam and the unchanged lowering of the models that sow none.
+
+Tiny: hidden 64, 8/2 heads of 32 (so q is 256 wide, not hidden), indexer 16
+heads of 16 picking 24 keys, 16 experts top-2 (2 held), vocabulary 96, S 128
+in query blocks of 16 (two scans of four blocks), two layers.
+"""
+
+import dataclasses
+import hashlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import check  # noqa: E402  (benchmark/check.py)
+import check_indexer  # noqa: E402  (benchmark/check_indexer.py)
+from reference import keye_vl2 as reference  # noqa: E402
+
+from ddlpc_tpu.config import (  # noqa: E402
+    DataConfig,
+    ExperimentConfig,
+    ModelConfig,
+    ParallelConfig,
+    TrainConfig,
+)
+from ddlpc_tpu.models import build_model  # noqa: E402
+from ddlpc_tpu.models import keye_vl2 as program  # noqa: E402
+from ddlpc_tpu.models import lfm2_moe  # noqa: E402
+
+VOCAB, SEQ, TOPK = 96, 128, 24
+TINY = dict(
+    name="keye_vl2", num_classes=VOCAB, hidden_size=64, moe_intermediate_size=48,
+    num_attention_heads=8, num_key_value_heads=2, head_dim=32, mrope_section=(4, 6, 6),
+    tie_word_embeddings=False, num_experts=16, num_experts_per_tok=2, experts_held=2,
+    expert_offset=4, num_dense_layers=0, layer_types=("full_attention",) * 2, norm_eps=1e-6,
+    rope_theta=1e7, use_expert_bias=False, router_score="softmax", indexer_num_heads=16,
+    indexer_head_dim=16, indexer_topk=TOPK,
+)
+
+
+@pytest.fixture(autouse=True)
+def several_query_blocks(monkeypatch):
+    """Eight query blocks at S = 128, in two scans of four."""
+    monkeypatch.setattr(program, "QUERY_BLOCK", 16)
+    monkeypatch.setattr(reference, "QUERY_BLOCK", 32)
+
+
+def tiny_config(**changes) -> ModelConfig:
+    return ModelConfig(**{**TINY, "compute_dtype": "float32", **changes})
+
+
+def tokens(seed: int, batch: int = 2, seq: int = SEQ):
+    ids = jax.random.randint(jax.random.key(seed), (batch, 1, seq + 1, 1), 0, VOCAB)
+    return np.asarray(ids[:, :, :seq]), np.asarray(ids[:, :, 1:, 0])
+
+
+def init(cfg: ModelConfig, seed: int = 0, seq: int = SEQ):
+    """Seeded parameters with the indexer's matrices scaled up, so that its
+    scores spread and few of them tie."""
+    x = jnp.zeros((1, 1, seq, 1), jnp.int32)
+    params = build_model(cfg).init(jax.random.key(seed), x, train=False)["params"]
+    for name in params:
+        if name.startswith("layers_"):
+            params[name]["self_attn"]["indexer"] = jax.tree.map(
+                lambda w: 4.0 * w, params[name]["self_attn"]["indexer"]
+            )
+    return params
+
+
+# ---- program against reference ---------------------------------------------
+
+
+def compare(dtype: str, seed: int) -> dict:
+    """Errors of the program computing in ``dtype`` (float32 parameters):
+    check.py's three and check_indexer.py's two."""
+    cfg = tiny_config(compute_dtype=dtype)
+    x, y = tokens(seed)
+    params = init(tiny_config(), seed + 10)
+    model = dataclasses.asdict(cfg)
+    got = check.program_fn(cfg)(params, {}, x, y)
+    want = check.reference_fn("keye_vl2", model)(params, {}, x, y)
+    out = {k: float(v) for k, v in check._errors(got, want).items()}
+    own = check_indexer.compare(cfg, "keye_vl2", params, x)
+    # cross-entropy does not reach the indexer, in program and reference alike
+    for grads in (got[2], want[2]):
+        assert all(not np.any(np.asarray(g)) for g in check_indexer.split(grads)[0])
+    return out | {k: own[k] for k in ("indexer_loss", "indexer_grad", "grad_outside_indexer", "first_layer_picks_differ")}
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_float32_program_is_the_reference(seed):
+    out = compare("float32", seed)
+    limits = reference.TOLERANCE["float32"] | reference.INDEXER_TOLERANCE["float32"]
+    assert all(out[k] <= limits[k] for k in limits), out
+    assert out["logits"] < 1e-5 and out["grad"] < 1e-4 and out["indexer_grad"] < 1e-4, out
+    assert out["first_layer_picks_differ"] == 0.0
+    # L_I moves the indexer alone: exactly nothing anywhere else
+    assert out["grad_outside_indexer"] == [0.0, 0.0]
+
+
+# At this size (hidden 64) bf16 reads 0.05..0.07 on logits and gradients, 0.10..
+# 0.12 on the indexer's gradient, and moves 1 % of the first layer's picks;
+# float8 reads 0.18, 0.29, 0.43 and moves 10 %.
+TINY_BF16 = {"loss": 1e-3, "logits": 0.12, "grad": 0.15, "indexer_loss": 0.05, "indexer_grad": 0.25}
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_bfloat16_program_near_the_reference_and_outside_float32s_limits(seed):
+    out = compare("bfloat16", seed)
+    assert all(out[k] <= TINY_BF16[k] for k in TINY_BF16), out
+    limits = reference.TOLERANCE["float32"]
+    assert out["logits"] > limits["logits"] and out["grad"] > limits["grad"], out
+    assert out["indexer_grad"] > reference.INDEXER_TOLERANCE["float32"]["indexer_grad"], out
+    assert out["grad_outside_indexer"] == [0.0, 0.0]
+
+
+def test_float8_program_is_told_from_bfloat16():
+    """The nearest precision below the stated one fails even the tiny size's
+    bounds."""
+    out = compare("float8_e4m3fn", 0)
+    assert out["logits"] > TINY_BF16["logits"] and out["grad"] > TINY_BF16["grad"], out
+    assert out["indexer_grad"] > TINY_BF16["indexer_grad"], out
+    assert out["first_layer_picks_differ"] > 0.05, out
+
+
+# ---- the selection -------------------------------------------------------------
+
+
+@pytest.mark.parametrize("shape,k", [((7, 50), 1), ((16, 128), 24), ((3, 33), 33), ((4, 64), 10)])
+def test_kth_largest_is_the_sorted_rows_kth(shape, k):
+    x = jax.random.normal(jax.random.key(sum(shape)), shape, jnp.float32)
+    x = x.at[:, ::5].set(-jnp.inf).at[0, 1].set(0.0).at[0, 2].set(-0.0).at[1].multiply(1e-30)
+    want = -np.sort(-np.asarray(x), axis=-1)[:, k - 1]
+    np.testing.assert_array_equal(np.asarray(program.kth_largest(x, k)) + 0.0, want + 0.0)
+
+
+def selection_of(seed: int, seq: int = SEQ, topk: int = TOPK):
+    """(index scores [S, S], selection bias [S, S]) of one random sequence,
+    block by block as the model makes them."""
+    keys = jax.random.split(jax.random.key(seed), 3)
+    qi = jax.random.normal(keys[0], (seq, 24, 16))  # 24 heads: no pair's ReLUs are all zero
+    ki = jax.random.normal(keys[1], (seq, 16))
+    w = jax.random.normal(keys[2], (seq, 24))
+    scores, bias = [], []
+    for start in range(0, seq, 16):
+        x = program._index_block(qi[start : start + 16], ki[: start + 16], w[start : start + 16], start)
+        tau = program._threshold_block(x, start, topk)
+        pad = ((0, 0), (0, seq - x.shape[1]))
+        scores.append(jnp.pad(x, pad, constant_values=-jnp.inf))
+        bias.append(jnp.pad(program._selection_bias(x, tau), pad, constant_values=program.MASKED))
+    return np.asarray(jnp.concatenate(scores)), np.asarray(jnp.concatenate(bias))
+
+
+def test_selection_is_every_key_of_a_short_row_and_the_top_k_of_a_long_one():
+    scores, bias = selection_of(0)
+    picked = bias == 0
+    t = np.arange(SEQ)
+    assert not picked[np.triu_indices(SEQ, 1)].any()  # never a later key
+    assert (picked.sum(axis=1) == np.minimum(t + 1, TOPK)).all()
+    for row in (TOPK - 1, TOPK, 77, SEQ - 1):  # and it is the top_k set
+        want = np.argsort(-scores[row], kind="stable")[: min(row + 1, TOPK)]
+        assert set(np.flatnonzero(picked[row])) == set(want)
+
+
+def test_ties_at_the_threshold_are_kept():
+    x = jnp.asarray([[3.0, 1.0, 1.0, 1.0, 0.5, -jnp.inf]])
+    tau = program.kth_largest(x, 2)
+    assert float(tau[0]) == 1.0 and int((program._selection_bias(x, tau) == 0).sum()) == 4
+
+
+def attention_inputs(seed: int, seq: int, heads: int = 8, kv: int = 2, d: int = 32, dtype=jnp.float32):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    q = jax.random.normal(keys[0], (2, seq, heads, d), dtype)
+    k = jax.random.normal(keys[1], (2, seq, kv, d), dtype)
+    v = jax.random.normal(keys[2], (2, seq, kv, d), dtype)
+    return q, k, v
+
+
+def test_a_selection_of_every_key_is_causal_attention(monkeypatch):
+    monkeypatch.setattr(lfm2_moe, "QUERY_BLOCK", 16)
+    q, k, v = attention_inputs(1, SEQ)
+    _, bias = selection_of(1, topk=SEQ)  # topk >= S: every earlier key
+    bias = jnp.broadcast_to(jnp.asarray(bias, jnp.bfloat16), (2, SEQ, SEQ))
+    out, lse = program.blocked_selected_attention(q, k, v, bias, block=16)
+    np.testing.assert_allclose(out, lfm2_moe.causal_attention(q, k, v), rtol=2e-5, atol=2e-6)
+    assert lse.shape == (2, SEQ, 8)
+
+
+def test_selected_attention_is_the_softmax_over_the_picked_keys():
+    q, k, v = attention_inputs(2, SEQ)
+    _, bias = selection_of(2)
+    out, lse = program.blocked_selected_attention(
+        q, k, v, jnp.broadcast_to(jnp.asarray(bias, jnp.bfloat16), (2, SEQ, SEQ)), block=16
+    )
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, 4, axis=2)) / np.sqrt(32.0)
+    scores = jnp.where(bias == 0, scores, -jnp.inf)
+    want = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, axis=-1), jnp.repeat(v, 4, axis=2))
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(
+        lse, jax.nn.logsumexp(scores, axis=-1).transpose(0, 2, 1), rtol=2e-5, atol=2e-6
+    )
+
+
+# ---- the extended kernels, interpreted -------------------------------------------
+
+
+@pytest.mark.parametrize("heads,kv,d", [(8, 1, 128), (4, 2, 64)])
+def test_selected_attention_kernels_are_the_xla_form(heads, kv, d):
+    """Head size 128, eight query heads a k/v head (two groups of four a grid
+    step) and the selection: forward, log-sum-exp and the three gradients."""
+    from ddlpc_tpu.ops import pallas_attention
+
+    seq, block = 256, 128
+    q, k, v = attention_inputs(3, seq, heads, kv, d)
+    q, k, v = q[:1], k[:1], v[:1]
+    scores = jax.random.normal(jax.random.key(4), (seq, seq))
+    scores = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), scores, -jnp.inf)
+    tau = program._threshold_block(scores, 0, 40)
+    bias = program._selection_bias(scores, tau).astype(jnp.bfloat16)[None]
+    weights = jax.random.normal(jax.random.key(5), (1, seq, heads, d))
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out * weights), (out, lse)
+
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    kernel = lambda q, k, v: pallas_attention.selected_attention(  # noqa: E731
+        q, k, v, bias, block=block, interpret=True
+    )
+    xla = lambda q, k, v: program.blocked_selected_attention(q, k, v, bias, block=block)  # noqa: E731
+    (_, (out_k, lse_k)), grads_k = loss(kernel)(q, k, v)
+    (_, (out_x, lse_x)), grads_x = loss(xla)(q, k, v)
+    np.testing.assert_allclose(out_k, out_x, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(lse_k, lse_x, rtol=1e-4, atol=1e-5)
+    for got, want in zip(grads_k, grads_x):
+        np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4)
+
+
+def test_causal_kernels_take_eight_query_heads_of_128():
+    from ddlpc_tpu.ops import pallas_attention
+
+    q, k, v = attention_inputs(6, 256, 8, 1, 128)
+    fn = lambda f: jax.value_and_grad(lambda q, k, v: jnp.sum(f(q, k, v) ** 2), argnums=(0, 1, 2))  # noqa: E731
+    got = fn(lambda q, k, v: pallas_attention.causal_attention(q, k, v, block=128, interpret=True))(q, k, v)
+    want = fn(lambda q, k, v: lfm2_moe.blocked_causal_attention(q, k, v, block=128))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+
+
+# ---- rotary positions ----------------------------------------------------------------
+
+
+def test_sectioned_rotary_on_equal_streams_is_the_plain_table():
+    positions = jnp.broadcast_to(jnp.arange(SEQ), (3, SEQ))
+    got = program.mrope_tables(positions, 32, 1e7, (4, 6, 6))
+    want = lfm2_moe.rotary_tables(SEQ, 32, 1e7)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_sectioned_rotary_on_three_streams_is_the_references():
+    positions = jnp.stack([jnp.arange(SEQ), jnp.arange(SEQ) // 3, 5 + jnp.arange(SEQ) % 7])
+    x = jax.random.normal(jax.random.key(7), (2, SEQ, 8, 32))
+    cos, sin = program.mrope_tables(positions, 32, 1e7, (4, 6, 6))
+    got = lfm2_moe.apply_rotary(x, cos, sin)
+    want = reference.sectioned_rotary(x, positions, 1e7, (4, 6, 6))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    # slot 3 turns by the first stream, slot 4 by the second
+    assert not np.allclose(cos[:, 3], np.cos(np.asarray(positions[1]) * 1e7 ** (-6 / 32)))
+    np.testing.assert_allclose(cos[:, 4], np.cos(np.asarray(positions[1]) * 1e7 ** (-8 / 32)), rtol=1e-5, atol=1e-5)
+    with pytest.raises(ValueError, match="add up"):
+        program.mrope_tables(positions, 32, 1e7, (4, 6, 5))
+
+
+# ---- the router and the shares -----------------------------------------------------------
+
+
+def routed_layer(cfg, held, offset):
+    return lfm2_moe.RoutedExperts(
+        cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts, cfg.num_experts_per_tok,
+        held, offset, use_expert_bias=False, dtype=jnp.float32, score="softmax",
+    )
+
+
+def whole_layer(cfg, seed):
+    hidden, width, n_exp = cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts
+    keys = jax.random.split(jax.random.key(seed), 5)
+    u = jax.random.normal(keys[0], (2, 1, SEQ, hidden), jnp.float32)
+    return u, {
+        "gate": jax.random.normal(keys[1], (hidden, n_exp)) * 0.3,
+        "w1": jax.random.normal(keys[2], (n_exp, hidden, width)) * 0.1,
+        "w3": jax.random.normal(keys[3], (n_exp, hidden, width)) * 0.1,
+        "w2": jax.random.normal(keys[4], (n_exp, width, hidden)) * 0.1,
+    }
+
+
+def test_softmax_router_is_the_references():
+    """Softmax over all the experts, the picked two renormalised with no
+    epsilon, no bias leaf: value and gradients, every expert held."""
+    cfg = tiny_config()
+    u, whole = whole_layer(cfg, 8)
+    layer = routed_layer(cfg, cfg.num_experts, 0)
+    model = dict(dataclasses.asdict(cfg), experts_held=cfg.num_experts, expert_offset=0)
+    assert set(layer.init(jax.random.key(0), u)["params"]) == {"gate", "w1", "w2", "w3"}
+    got = jax.value_and_grad(lambda p: jnp.sum(layer.apply({"params": p}, u)[0] ** 2))(whole)
+    want = jax.value_and_grad(lambda p: jnp.sum(reference.routed_experts(u[:, 0], p, model) ** 2))(whole)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for name in whole:
+        np.testing.assert_allclose(got[1][name], want[1][name], rtol=2e-3, atol=1e-5)
+    # the weights of a token's picks add up to one
+    probs = jax.nn.softmax(u.reshape(-1, cfg.hidden_size) @ whole["gate"], axis=-1)
+    top = jax.lax.top_k(probs, 2)[0]
+    assert np.allclose((top / top.sum(-1, keepdims=True)).sum(-1), 1.0)
+
+
+def test_eight_shares_add_up_to_the_uncut_layer():
+    """The guide's share test: eight shares of two experts (offsets 0, 2, ...,
+    14), each computed by the program's routed layer, sum to what the
+    reference gives with all sixteen experts held."""
+    cfg = tiny_config()
+    u, whole = whole_layer(cfg, 9)
+    uncut = dict(dataclasses.asdict(cfg), experts_held=cfg.num_experts, expert_offset=0)
+    want = reference.routed_experts(u[:, 0], whole, uncut)
+    total, routed = 0.0, 0
+    for offset in range(0, cfg.num_experts, 2):
+        share = dict(whole, **{k: whole[k][offset : offset + 2] for k in ("w1", "w3", "w2")})
+        part, counts = routed_layer(cfg, 2, offset).apply({"params": share}, u)
+        total = total + part
+        routed += int(counts["sum"]["moe_rows_routed"])
+        assert int(counts["sum"]["moe_rows_dropped"]) == 0
+    np.testing.assert_allclose(total[:, 0], want, rtol=2e-5, atol=2e-6)
+    assert routed == 2 * SEQ * cfg.num_experts_per_tok
+
+
+# ---- the model ------------------------------------------------------------------------------
+
+
+def test_model_is_causal_and_counts_its_pairs():
+    cfg = tiny_config()
+    model, params = build_model(cfg), init(tiny_config(), 3)
+    x, _ = tokens(4)
+    logits, sown = model.apply({"params": params}, x, train=True, mutable=["counters", "losses"])
+    changed = x.copy()
+    changed[:, :, 100:] = (changed[:, :, 100:] + 1) % VOCAB
+    again = model.apply({"params": params}, changed, train=False)
+    np.testing.assert_allclose(logits[:, :, :100], again[:, :, :100], rtol=1e-5, atol=1e-6)
+    sums = sown["counters"]["sum"]
+    per_sequence = TOPK * (TOPK + 1) // 2 + (SEQ - TOPK) * TOPK
+    assert int(sums["dsa_pairs_selected"]) == 2 * 2 * per_sequence  # layers x sequences
+    assert int(sums["dsa_pairs_causal"]) == 2 * 2 * SEQ * (SEQ + 1) // 2
+    assert int(sown["counters"]["max"]["dsa_kernel_layers"]) == 0  # the XLA form on the CPU
+    assert int(sums["moe_rows_dropped"]) == 0 and int(sums["tokens_per_step"]) == 2 * SEQ
+    assert float(sown["losses"]["indexer_kl"]) > 0
+    # no own loss where nobody collects it, and the head is its own matrix
+    _, sown = model.apply({"params": params}, x, train=True, mutable=["counters"])
+    assert "losses" not in sown
+    assert params["lm_head"].shape == params["embedding"].shape == (VOCAB, 64)
+    assert params["layers_0"]["self_attn"]["q_proj"]["kernel"].shape == (64, 8 * 32)
+
+
+def test_registry_refuses_what_the_family_cannot_be():
+    with pytest.raises(ValueError, match="layer_types"):
+        build_model(tiny_config(layer_types=("conv", "full_attention")))
+    with pytest.raises(ValueError, match="indexer_num_kv_heads"):
+        build_model(tiny_config(indexer_num_kv_heads=2))
+    with pytest.raises(ValueError, match="router_score"):
+        build_model(tiny_config(router_score="tanh"))
+
+
+# ---- the step's own loss ---------------------------------------------------------------------
+
+
+def tiny_experiment(workdir: str, **train) -> ExperimentConfig:
+    return ExperimentConfig(
+        model=tiny_config(),
+        data=DataConfig(
+            dataset="packed_tokens", image_size=(1, SEQ), num_classes=VOCAB,
+            synthetic_len=12, test_split=4, device_cache=True,
+        ),
+        train=TrainConfig(
+            epochs=2, micro_batch_size=2, sync_period=2, learning_rate=3e-3, optimizer="adam",
+            eval_every_epochs=0, checkpoint_every_epochs=0, dump_images_per_epoch=0, **train,
+        ),
+        parallel=ParallelConfig(data_axis_size=1),
+        workdir=workdir,
+    )
+
+
+def test_trainer_fits_two_steps_and_records_the_own_loss_and_counters(tmp_path):
+    from ddlpc_tpu.train.trainer import Trainer
+
+    trainer = Trainer(tiny_experiment(str(tmp_path / "run")), resume=False)
+    before = jax.device_get(trainer.state.params["layers_0"]["self_attn"]["indexer"])
+    assert len(trainer.loader) == 2
+    record = trainer.fit(epochs=1)
+    after = jax.device_get(trainer.state.params["layers_0"]["self_attn"]["indexer"])
+    trainer.close()
+    assert record["indexer_kl"] > 0 and np.isfinite(record["loss"])
+    assert record["loss"] > record["indexer_kl"]  # the step's loss holds it, beside cross-entropy
+    per_sequence = TOPK * (TOPK + 1) // 2 + (SEQ - TOPK) * TOPK
+    assert record["dsa_pairs_causal"] == 4 * 2 * SEQ * (SEQ + 1) // 2  # sequences x layers
+    assert per_sequence * 8 <= record["dsa_pairs_selected"] < 1.05 * per_sequence * 8  # ties only
+    assert record["dsa_kernel_layers"] == 0.0
+    assert record["moe_rows_dropped"] == 0.0 and record["tokens_per_step"] == 4 * SEQ
+    assert record["moe_rows_offered"] == 4 * SEQ * 2 * 2
+    assert 0 < record["moe_rows_routed"] < record["moe_rows_offered"]
+    assert record["moe_rows_buffered"] > 0 and record["moe_max_load"] >= 1.0
+    # the indexer learns, from its own loss alone
+    assert not np.array_equal(before["q_proj"]["kernel"], after["q_proj"]["kernel"])
+
+
+def test_reduce_counters_means_the_own_losses():
+    from ddlpc_tpu.parallel.train_step import _reduce_counters
+
+    stacked = {"sum": {"a": jnp.asarray([1, 2])}, "max": {"b": jnp.asarray([1.0, 3.0])},
+               "mean": {"indexer_kl": jnp.asarray([1.0, 2.0])}}
+    assert {k: float(v) for k, v in _reduce_counters(stacked).items()} == {"a": 3.0, "b": 3.0, "indexer_kl": 1.5}
+
+
+def step_jaxpr_digest(model_cfg: ModelConfig, shape, dtype) -> str:
+    """sha256 of the jaxpr of one micro-batch's loss and gradients as the step
+    computes them (``_loss_and_metrics`` under ``value_and_grad``)."""
+    from ddlpc_tpu.parallel.train_step import _loss_and_metrics
+
+    model = build_model(model_cfg)
+    x = jnp.zeros(shape, dtype)
+    y = jnp.zeros(shape[:3], jnp.int32)
+    variables = jax.eval_shape(lambda: model.init(jax.random.key(0), x, train=False))
+    stats = variables.get("batch_stats", {})
+
+    def f(params, stats, x, y):
+        return jax.value_and_grad(
+            lambda p: _loss_and_metrics(model, p, stats, x, y, train=True), has_aux=True
+        )(params)
+
+    return hashlib.sha256(str(jax.make_jaxpr(f)(variables["params"], stats, x, y)).encode()).hexdigest()
+
+
+# The digests of the parent commit (d321959, before the ``losses`` seam and the
+# router's ``score``), made by this function there: a model that sows no loss
+# of its own gets the program it had.  A later change to either model, or
+# another jax, moves them; make them again on the commit before that change.
+UNCHANGED = {
+    "flagship": "0104633fc85f027941babdc7300144f14f0dd56e9e26c41f6ebb87907022f859",
+    "lfm2_moe": "01daede8ef34324c4685be0f113583bea3f6d72d1e8fe48ff97e61adb3b4bba5",
+}
+
+
+@pytest.mark.parametrize("which", sorted(UNCHANGED))
+def test_models_that_sow_no_loss_lower_as_before(which):
+    if which == "flagship":
+        import json
+
+        cfg = ExperimentConfig.from_dict(
+            json.load(open(os.path.join(ROOT, "configs", "vaihingen_unet_tpu_flagship.json")))
+        ).model
+        cfg = dataclasses.replace(cfg, features=(8, 16), bottleneck_features=16)
+        digest = step_jaxpr_digest(cfg, (2, 64, 64, 3), jnp.float32)
+    else:
+        cfg = ModelConfig(
+            name="lfm2_moe", num_classes=128, hidden_size=64, intermediate_size=96,
+            moe_intermediate_size=48, num_attention_heads=4, num_key_value_heads=2,
+            num_experts=8, num_experts_per_tok=2, experts_held=2, expert_offset=2,
+            num_dense_layers=1, layer_types=("conv", "full_attention", "conv"),
+        )
+        digest = step_jaxpr_digest(cfg, (2, 1, 512, 1), jnp.int32)  # a length the kernels take
+    assert digest == UNCHANGED[which]
+
+
+# ---- the program's FLOP model ---------------------------------------------------------------------
+
+
+def test_product_flops_counts_the_indexer_and_the_kernel_by_its_estimate():
+    """``obs/flops.product_flops`` walks this family like any other: the
+    indexer's products inside their scans, the attention by its dots in the
+    XLA form and by the kernels' ``cost_estimate`` (the causal half) where the
+    program is lowered for a TPU; the grouped products by the pair."""
+    from ddlpc_tpu.obs import flops
+
+    seq, heads, d, layers, batch = 512, 8, 32, 2, 2
+
+    def count(platform, **changes):
+        cfg = ExperimentConfig(
+            model=tiny_config(**changes), data=DataConfig(dataset="packed_tokens", image_size=(1, seq))
+        )
+        return flops.product_flops(cfg, batch, 1, platform=platform)
+
+    dense_cpu, grouped, has_conv = count("cpu")
+    dense_tpu, grouped_tpu, _ = count("tpu")
+    assert not has_conv and grouped == grouped_tpu
+    # every (token, expert) pair: three products of 2 x 64 x 48 a row
+    assert grouped == batch * seq * 2 * layers * 3 * 2 * 64 * 48
+    # blocks of 16 against the keys up to their end, two products a pair, against
+    # the kernels' causal half of S x S
+    blocks = seq // 16
+    xla = 2 * 2 * heads * d * 16 * 16 * (blocks * (blocks + 1) // 2)
+    assert dense_cpu - dense_tpu == batch * layers * (xla - 2 * heads * d * seq * seq)
+    # twice the indexer heads: their projections and their scores again (the
+    # scores of four blocks a scan against the keys up to the scan's end)
+    runs = sum(4 * 16 * (first + 4) * 16 for first in range(0, blocks, 4))  # (query, key) pairs computed
+    more = count("cpu", indexer_num_heads=32)[0] - dense_cpu
+    assert more == batch * layers * (2 * seq * 64 * (16 * 16 + 16) + 2 * 16 * 16 * runs)
+    # the selection's size changes no product
+    assert count("cpu", indexer_topk=100)[0] == dense_cpu
