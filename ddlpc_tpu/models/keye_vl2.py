@@ -29,13 +29,18 @@ of ``ops/pallas_attention.py`` where the program is lowered for a TPU and they
 take the sequence, blocked XLA everywhere else), and returns each row's
 log-sum-exp; (3) the KL a block, the target recomputed from detached q and k
 and that log-sum-exp, the index scores computed again (so no ``[S, S]`` scores
-and no ``[S, S]`` cotangent wait between the passes).  Passes (1) and (3) are
-``lax.scan``s over runs of ``BLOCKS_PER_SCAN`` query blocks that share the key
-width of their last block: one body's temporaries at a time, whatever the
-compiler's schedule (32 unrolled blocks a layer left the index products of
-three layers alive at once: 18.5 GB for the chip; PERF.md §6, PR 32).  Each
-block of (3) is rematerialised in the backward, and so is each layer under
-``train=True``.
+and no ``[S, S]`` cotangent wait between the passes).  The target has the
+attention's two lowerings, chosen the same way: where the kernels run,
+``ops/pallas_attention.head_mean_probs`` keeps every head's float32 scores of
+a block pair, their exponentials and the sum over the heads in VMEM and
+writes the head-averaged probabilities ``[block, keys]`` alone; the XLA form
+writes all ``[heads, block, keys]`` of them and reads them back.  Passes (1)
+and (3) are ``lax.scan``s over runs of ``BLOCKS_PER_SCAN`` query blocks that
+share the key width of their last block: one body's temporaries at a time,
+whatever the compiler's schedule (32 unrolled blocks a layer left the index
+products of three layers alive at once: 18.5 GB for the chip; PERF.md §6,
+PR 32).  Each block of (3) is rematerialised in the backward, and so is each
+layer under ``train=True``.
 
 Compute is ``compute_dtype`` (bf16) with float32 parameters; router, norm
 statistics, softmax, rotary angles, index scores (products from bf16 operands
@@ -207,7 +212,27 @@ def _selection_bias(scores, tau):
     return jnp.where(scores >= tau[:, None], 0.0, MASKED)
 
 
-def _kl_block(qi, ki, w, tau, q, k, lse, start, heads: int):
+def blocked_head_mean_probs(q, k, lse, heads: int):
+    """The XLA form of ``ops/pallas_attention.head_mean_probs``, shapes and
+    results as there: every head's float32 probabilities, then their mean."""
+    att = jnp.einsum("kmd,ktd->kmt", q, k, preferred_element_type=jnp.float32)
+    probs = jnp.exp(att * (q.shape[-1] ** -0.5) - lse[..., None])
+    return probs.reshape(heads, -1, probs.shape[-1]).sum(axis=0) / heads
+
+
+def head_mean_probs(q, k, lse, heads: int, seq_len: int):
+    """The mean over the query heads of the attention's probabilities, one
+    block of queries of a sequence of ``seq_len`` against the keys of its run:
+    two lowerings, chosen as :func:`selected_attention` chooses."""
+    xla = functools.partial(blocked_head_mean_probs, heads=heads)
+    kernels = _kernels(seq_len)
+    if kernels is None:
+        return xla(q, k, lse)
+    kernel = functools.partial(kernels.head_mean_probs, heads=heads)
+    return lax.platform_dependent(q, k, lse, tpu=kernel, default=xla)
+
+
+def _kl_block(qi, ki, w, tau, q, k, lse, start, heads: int, seq_len: int):
     """Σ over a block's queries of ``KL(p̄_t ‖ softmax_{s∈S_t} I[t,s])``.  The
     index scores are computed again from ``qi``, ``ki``, ``w`` (as
     :func:`_index_block`; the gradient goes through them and nowhere else),
@@ -220,9 +245,7 @@ def _kl_block(qi, ki, w, tau, q, k, lse, start, heads: int):
         scores = _index_block(qi, ki, w, start)
     with jax.named_scope("ddlpc/dsa/kl"):
         picked = _selection_bias(lax.stop_gradient(scores), tau) == 0
-        att = jnp.einsum("kmd,ktd->kmt", q, k, preferred_element_type=jnp.float32)
-        probs = jnp.exp(att * (q.shape[-1] ** -0.5) - lse[..., None])
-        target = probs.reshape(heads, -1, probs.shape[-1]).sum(axis=0) / heads
+        target = head_mean_probs(q, k, lse, heads, seq_len)
         target = jnp.where(picked, target, 0.0)
         logits = jnp.where(picked, scores, MASKED)
         log_index = logits - jax.nn.logsumexp(logits, axis=-1, keepdims=True)
@@ -321,7 +344,7 @@ class SparseAttention(nn.Module):
                     qs, ks = _query_blocks(qs, kv, block), ks.transpose(1, 0, 2)
                     ls = _query_blocks(ls[..., None], kv, block)[..., 0]
                 # outside the scopes: a block names its own ops, indexer and kl
-                block_kl = jax.checkpoint(functools.partial(_kl_block, heads=heads))
+                block_kl = jax.checkpoint(functools.partial(_kl_block, heads=heads, seq_len=s))
                 total = 0.0
                 for i, j, keys in runs:
                     seen = (ki[n, :keys], ks[:, :keys])
@@ -424,7 +447,9 @@ class KeyeVL2(nn.Module):
             "dsa_pairs_causal": jnp.int32(len(layers) * ids.shape[0] * (s * (s + 1) // 2)),
         }
         sums |= jax.tree.map(lambda *v: sum(v), *[r["sum"] for r in routed])
-        maxes = {"dsa_kernel_layers": len(layers) * _kernel_lowers(s)}
+        # layers whose attention, and whose KL target, lowered to the kernels
+        lowered = len(layers) * _kernel_lowers(s)
+        maxes = {"dsa_kernel_layers": lowered, "dsa_kl_kernel_layers": lowered * int(want_kl)}
         maxes |= jax.tree.map(lambda *v: jnp.stack(v).max(), *[r["max"] for r in routed])
         for kind, values in (("sum", sums), ("max", maxes)):
             self.sow("counters", kind, values, reduce_fn=lambda _, v: v, init_fn=lambda: 0)

@@ -39,6 +39,16 @@ of query heads a k/v head: more than ``HEADS_PER_STEP`` of them are split into
 that many a grid step (each group reads its own copy of the k/v blocks, and
 their dK and dV are summed outside), so a step's scores stay
 ``HEADS_PER_STEP · block`` rows whatever the grouping.
+
+:func:`head_mean_probs` is the target of the ``keye_vl2`` indexer's loss, the
+mean over the query heads of ``exp(q kᵀ / sqrt(D) - lse)`` for one block of
+queries against a run of keys, with the log-sum-exp that
+:func:`selected_attention` returned.  A grid step holds ``HEADS_PER_STEP``
+heads of one k/v head against one block of keys: their float32 scores
+(transposed, as in the backward), the exponentials and the running sum over
+the heads stay in VMEM; the groups of heads are the innermost grid axis, and
+what reaches HBM is one float32 ``[queries, keys]`` block after the last
+group.  Forward only: its inputs are detached.
 """
 
 from __future__ import annotations
@@ -201,6 +211,10 @@ def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _nbytes(*arrays) -> int:
+    return sum(math.prod(x.shape) * jnp.dtype(x.dtype).itemsize for x in arrays)
+
+
 def _triangle(n: int):
     """The lower triangle of an n×n block grid, flattened query-major, the
     diagonal last in its row: (query blocks, key blocks) as int32 tables."""
@@ -232,9 +246,7 @@ def _call(kernel, name, operands, out_shapes, out_specs, scratch, in_specs, *, b
         cost_estimate=pl.CostEstimate(
             flops=products * b * kv * g * s * s * d,  # 2·S²·D / 2 a product and head
             transcendentals=b * kv * g * s * s // 2,
-            bytes_accessed=sum(
-                math.prod(x.shape) * jnp.dtype(x.dtype).itemsize for x in (*operands, *out_shapes)
-            ),
+            bytes_accessed=_nbytes(*operands, *out_shapes),
         ),
         interpret=interpret,
         name=name,
@@ -439,3 +451,75 @@ def selected_attention(q, k, v, bias, *, block: int = BLOCK, interpret: bool = F
     ``bias``."""
     _check(q.shape[1], block)
     return _selected(q, k, v, bias, block, interpret)
+
+
+def _head_mean_kernel(q_ref, k_ref, lse_ref, o_ref, acc_ref, *, scale: float, heads: int):
+    """One group of query heads against one block of keys.  The scores are
+    held transposed, ``[keys, g·queries]``, so the log-sum-exp broadcasts
+    along sublanes and a head is a run of lanes; the sum over the heads
+    waits in ``acc_ref [keys, queries]`` and is written, transposed and
+    divided by ``heads``, after the last group."""
+    h = pl.program_id(1)
+    g, rows, d = q_ref.shape
+    pt = jnp.exp(_scores(k_ref[...], q_ref[...].reshape(g * rows, d), scale) - lse_ref[...])
+    part = pt[:, :rows]
+    for n in range(1, g):
+        part = part + pt[:, n * rows : (n + 1) * rows]
+
+    @pl.when(h == 0)
+    def _():
+        acc_ref[...] = part
+
+    @pl.when(h > 0)
+    def _():
+        acc_ref[...] += part
+
+    @pl.when(h == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / heads).T
+
+
+def head_mean_probs(q, k, lse, *, heads: int, block: int = BLOCK, interpret: bool = False):
+    """``(1 / heads) Σ_h exp(q_h kᵀ / sqrt(D) - lse_h)`` of one block of
+    queries against ``T`` keys: q ``[KV, G·Bq, D]`` (the ``G = heads / KV``
+    query heads of a k/v head stacked head-major, as the kernels above hold a
+    block), k ``[KV, T, D]``, lse ``[KV, G·Bq]`` float32, the rows'
+    log-sum-exp as :func:`selected_attention` returns it; ``T`` a multiple of
+    ``block`` and ``Bq`` of 128.  Returns float32 ``[Bq, T]``; every pair is
+    computed, whatever was selected.  Not differentiable."""
+    kv, stacked, d = q.shape
+    keys, rows = k.shape[1], stacked * kv // heads  # queries a head
+    if keys % block or block % LANES or rows % LANES:
+        raise ValueError(
+            f"{keys} keys are not a multiple of the kernel's block {block}, or the "
+            f"{rows} queries of a head not one of {LANES}"
+        )
+    groups = _groups(heads, kv)
+    g, split = heads // groups, groups // kv  # heads a grid step; steps a k/v head
+    into_q, scale = _scales(d)
+    q = (q * jnp.asarray(into_q, q.dtype)).reshape(groups, g, rows, d)
+    lse = lse.reshape(groups, 1, g * rows)
+    out = jax.ShapeDtypeStruct((rows, keys), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_head_mean_kernel, scale=scale, heads=heads),
+        out_shape=out,
+        grid=(keys // block, groups),
+        in_specs=[
+            pl.BlockSpec((None, g, rows, d), lambda j, h: (h, 0, 0, 0)),
+            pl.BlockSpec((None, block, d), lambda j, h: (h // split, j, 0)),
+            pl.BlockSpec((None, 1, g * rows), lambda j, h: (h, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((rows, block), lambda j, h: (0, j)),
+        scratch_shapes=[pltpu.VMEM((block, rows), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=32 << 20,  # scores and exponentials 4 MiB each, every block twice
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * heads * rows * keys * d,
+            transcendentals=heads * rows * keys,
+            bytes_accessed=_nbytes(q, k, lse, out),
+        ),
+        interpret=interpret,
+        name="head_mean_probs",
+    )(q, k, lse)

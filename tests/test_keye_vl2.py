@@ -4,8 +4,10 @@ program against the plain float32 reference the benchmark keeps
 and on the indexer's loss and its gradients (benchmark/check_indexer.py); the
 two exact zeros; the selection rule; sectioned rotary positions; the softmax
 router and the eight shares against the uncut layer; the extended attention
-kernels interpreted against the XLA form; the Trainer; the step's own-loss
-seam and the unchanged lowering of the models that sow none.
+kernels interpreted against the XLA form, the KL target's among them (alone,
+and through ``SparseAttention`` on the indexer's loss and gradient); the
+Trainer; the step's own-loss seam and the unchanged lowering of the models
+that sow none.
 
 Tiny: hidden 64, 8/2 heads of 32 (so q is 256 wide, not hidden), indexer 16
 heads of 16 picking 24 keys, 16 experts top-2 (2 held), vocabulary 96, S 128
@@ -253,6 +255,136 @@ def test_selected_attention_kernels_are_the_xla_form(heads, kv, d):
         np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4)
 
 
+def causal_lse(q, k, kv: int, block: int):
+    """The rows' log-sum-exp of plain causal attention, laid out as the model
+    hands it to the KL: ``[KV, S/block, G·block]`` for q ``[S, H, D]``."""
+    s, heads, d = q.shape
+    scores = jnp.einsum("qhd,khd->hqk", q, jnp.repeat(k, heads // kv, axis=1)) / np.sqrt(d)
+    scores = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    lse = jax.nn.logsumexp(scores, axis=-1).T  # [S, H]
+    return program._query_blocks(lse[..., None], kv, block)[..., 0]
+
+
+@pytest.mark.parametrize("heads,kv,d", [(8, 1, 128), (4, 2, 64), (32, 4, 128)])
+def test_head_mean_probs_kernel_is_the_xla_form(heads, kv, d):
+    """Every query block of a run of four against the run's keys: the blocks
+    before the last see keys past their own end (the part of a run above the
+    diagonal, which the selection drops afterwards), and there the
+    probabilities pass 1.  Two groups of four heads a k/v head at (8, 1) and
+    (32, 4), one of two at (4, 2); only the order of the head sum differs."""
+    from ddlpc_tpu.ops import pallas_attention
+
+    seq, block = 512, 128
+    q, k, _ = attention_inputs(8, seq, heads, kv, d)
+    qs, ks = program._query_blocks(q[0], kv, block), k[0].transpose(1, 0, 2)
+    lse = causal_lse(q[0], k[0], kv, block)
+    beyond = 0.0
+    for i in range(seq // block):
+        want = program.blocked_head_mean_probs(qs[:, i], ks, lse[:, i], heads)
+        got = pallas_attention.head_mean_probs(
+            qs[:, i], ks, lse[:, i], heads=heads, block=block, interpret=True
+        )
+        assert got.shape == (block, seq) and got.dtype == jnp.float32
+        assert float(jnp.abs(got - want).max()) <= 1e-5 * float(jnp.abs(want).max())
+        # up to the diagonal it is a mean of probabilities: a row's sum is 1
+        rows = jnp.where(jnp.arange(seq)[None] <= i * block + jnp.arange(block)[:, None], got, 0.0)
+        np.testing.assert_allclose(rows.sum(axis=1), 1.0, rtol=1e-5)
+        beyond = max(beyond, float(got[:, (i + 1) * block :].max(initial=0.0)))
+    assert beyond > 1.0  # the part above the diagonal was computed, not skipped
+
+
+def test_head_mean_probs_kernel_refuses_what_its_blocks_do_not_divide():
+    from ddlpc_tpu.ops import pallas_attention
+
+    q, k = jnp.zeros((2, 4 * 128, 32)), jnp.zeros((2, 192, 32))
+    with pytest.raises(ValueError, match="not a multiple"):
+        pallas_attention.head_mean_probs(q, k, jnp.zeros((2, 4 * 128)), heads=8, block=128, interpret=True)
+    with pytest.raises(ValueError, match="not a multiple"):  # 64 queries a head
+        pallas_attention.head_mean_probs(q[:, :256], k[:, :128], jnp.zeros((2, 256)), heads=8, block=128, interpret=True)
+
+
+def indexer_loss_and_gradient(monkeypatch, kernel: bool):
+    """``L_I`` and its gradient over every leaf of one ``SparseAttention``:
+    eight query blocks of 128 in two runs, the target by the kernel
+    (interpreted) or by the XLA form."""
+    from ddlpc_tpu.ops import pallas_attention
+
+    seq = 1024
+    monkeypatch.setattr(program, "QUERY_BLOCK", 128)
+    if kernel:
+        monkeypatch.setattr(
+            program, "head_mean_probs",
+            lambda q, k, lse, heads, seq_len: pallas_attention.head_mean_probs(
+                q, k, lse, heads=heads, block=128, interpret=True
+            ),
+        )
+    cfg = tiny_config(indexer_topk=200)
+    layer = program.SparseAttention(cfg, True)
+    u = jax.random.normal(jax.random.key(11), (2, 1, seq, cfg.hidden_size))
+    positions = jnp.broadcast_to(jnp.arange(seq), (3, seq))
+    tables = program.mrope_tables(positions, cfg.head_dim, cfg.rope_theta, cfg.mrope_section)
+    index_tables = program.mrope_tables(
+        positions[:1], cfg.indexer_head_dim, cfg.rope_theta, (cfg.indexer_head_dim // 2,)
+    )
+    params = layer.init(jax.random.key(12), u, tables, index_tables)["params"]
+    params["indexer"] = jax.tree.map(lambda w: 4.0 * w, params["indexer"])
+    loss = lambda p: layer.apply({"params": p}, u, tables, index_tables)[1]  # noqa: E731
+    return jax.jit(jax.value_and_grad(loss))(params)
+
+
+def test_indexer_loss_and_gradient_through_the_kernel_are_the_xla_forms(monkeypatch):
+    loss_x, grads_x = indexer_loss_and_gradient(monkeypatch, kernel=False)
+    loss_k, grads_k = indexer_loss_and_gradient(monkeypatch, kernel=True)
+    assert float(loss_x) > 0
+    np.testing.assert_allclose(loss_k, loss_x, rtol=1e-5)
+    for grads in (grads_x, grads_k):  # L_I moves the indexer alone, on both paths
+        own, rest = check_indexer.split(grads)
+        assert all(np.any(np.asarray(g)) for g in own)
+        assert all(not np.any(np.asarray(g)) for g in rest)
+    for got, want in zip(check_indexer.split(grads_k)[0], check_indexer.split(grads_x)[0]):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize(
+    "platform,seq,kernel",
+    [("tpu", 1024, True), ("tpu", 512, True), ("cpu", 1024, False), ("tpu", 256, False),
+     ("tpu", 16384 + 512, False)],
+)
+def test_target_path_goes_by_platform_and_sequence_length(platform, seq, kernel):
+    """The kernel where the program is lowered for a TPU and the attention's
+    kernels take the sequence; the XLA form, and no error, everywhere else."""
+    block = min(512, seq)
+    q = jax.ShapeDtypeStruct((2, 4 * block, 32), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((2, seq, 32), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((2, 4 * block), jnp.float32)
+    fn = jax.jit(lambda q, k, lse: program.head_mean_probs(q, k, lse, 8, seq))
+    text = fn.trace(q, k, lse).lower(lowering_platforms=(platform,)).as_text()
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == (1 if kernel else 0)
+    assert ("head_mean_probs" in text) == kernel
+    assert ("stablehlo.dot_general" in text) != kernel  # the XLA form's product, or none
+
+
+@pytest.mark.parametrize("collects_losses", [True, False])
+def test_model_builds_the_target_only_where_its_loss_is_collected(monkeypatch, collects_losses):
+    """Lowered for a TPU at a length the kernels take, a layer holds the
+    attention's forward kernel, and one target kernel a sequence and run
+    where the caller collects ``losses``; evaluation and the reference check
+    collect none and build no target at all."""
+    monkeypatch.setattr(program, "QUERY_BLOCK", 512)
+    seq, batch = 1024, 2
+    cfg = tiny_config(compute_dtype="bfloat16", indexer_topk=256)
+    model = build_model(cfg)
+    x = jax.ShapeDtypeStruct((batch, 1, seq, 1), jnp.int32)
+    variables = jax.eval_shape(lambda: model.init(jax.random.key(0), jnp.zeros((1, 1, seq, 1), jnp.int32), train=False))
+    mutable = ["counters", "losses"] if collects_losses else ["counters"]
+    fn = jax.jit(lambda v, x: model.apply(v, x, train=True, mutable=mutable))
+    text = fn.trace(variables, x).lower(lowering_platforms=("tpu",)).as_text()
+    layers, runs = len(cfg.layer_types), 1  # two blocks of 512: one run
+    assert text.count("selected_attention_fwd") >= layers
+    calls = text.count("stablehlo.custom_call @tpu_custom_call")
+    assert calls == layers * (1 + batch * runs * collects_losses)
+
+
 def test_causal_kernels_take_eight_query_heads_of_128():
     from ddlpc_tpu.ops import pallas_attention
 
@@ -367,6 +499,7 @@ def test_model_is_causal_and_counts_its_pairs():
     assert int(sums["dsa_pairs_selected"]) == 2 * 2 * per_sequence  # layers x sequences
     assert int(sums["dsa_pairs_causal"]) == 2 * 2 * SEQ * (SEQ + 1) // 2
     assert int(sown["counters"]["max"]["dsa_kernel_layers"]) == 0  # the XLA form on the CPU
+    assert int(sown["counters"]["max"]["dsa_kl_kernel_layers"]) == 0  # of the target too
     assert int(sums["moe_rows_dropped"]) == 0 and int(sums["tokens_per_step"]) == 2 * SEQ
     assert float(sown["losses"]["indexer_kl"]) > 0
     # no own loss where nobody collects it, and the head is its own matrix
@@ -418,7 +551,7 @@ def test_trainer_fits_two_steps_and_records_the_own_loss_and_counters(tmp_path):
     per_sequence = TOPK * (TOPK + 1) // 2 + (SEQ - TOPK) * TOPK
     assert record["dsa_pairs_causal"] == 4 * 2 * SEQ * (SEQ + 1) // 2  # sequences x layers
     assert per_sequence * 8 <= record["dsa_pairs_selected"] < 1.05 * per_sequence * 8  # ties only
-    assert record["dsa_kernel_layers"] == 0.0
+    assert record["dsa_kernel_layers"] == 0.0 and record["dsa_kl_kernel_layers"] == 0.0
     assert record["moe_rows_dropped"] == 0.0 and record["tokens_per_step"] == 4 * SEQ
     assert record["moe_rows_offered"] == 4 * SEQ * 2 * 2
     assert 0 < record["moe_rows_routed"] < record["moe_rows_offered"]
@@ -520,3 +653,23 @@ def test_product_flops_counts_the_indexer_and_the_kernel_by_its_estimate():
     assert more == batch * layers * (2 * seq * 64 * (16 * 16 + 16) + 2 * 16 * 16 * runs)
     # the selection's size changes no product
     assert count("cpu", indexer_topk=100)[0] == dense_cpu
+    # The KL's target is built under train=True alone, which the walk does not
+    # trace; its kernel states the XLA form's one product of every head, every
+    # query of the block and every key of the run, and an exponential a pair.
+    from ddlpc_tpu.ops import pallas_attention
+
+    block, keys, kv = 128, 512, 2
+    shapes = (
+        jax.ShapeDtypeStruct((kv, heads // kv * block, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((kv, keys, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((kv, heads // kv * block), jnp.float32),
+    )
+    kernel = jax.make_jaxpr(
+        lambda q, k, lse: pallas_attention.head_mean_probs(q, k, lse, heads=heads, block=block)
+    )(*shapes)
+    (cost,) = [e.params["cost_estimate"] for e in flops.iter_eqns(kernel.jaxpr) if e.primitive.name == "pallas_call"]
+    xla = jax.make_jaxpr(lambda q, k, lse: program.blocked_head_mean_probs(q, k, lse, heads))(*shapes)
+    (dot,) = [e for e in flops.iter_eqns(xla.jaxpr) if e.primitive.name == "dot_general"]
+    assert cost.flops == 2 * int(np.prod(dot.outvars[0].aval.shape)) * d == 2 * heads * block * keys * d
+    assert cost.transcendentals == heads * block * keys
+    assert cost.bytes_accessed == 2 * (heads * block * d + kv * keys * d) + 4 * (heads * block + block * keys)
