@@ -127,9 +127,11 @@ MODULE_TIERS: Dict[str, str] = {
     "ddlpc_tpu.models.deeplabv3p": JAX,
     "ddlpc_tpu.models.lfm2_moe": JAX,
     "ddlpc_tpu.models.keye_vl2": JAX,
+    "ddlpc_tpu.models.olmo_hybrid": JAX,
     "ddlpc_tpu.ops": JAX,
     "ddlpc_tpu.ops.losses": JAX,
     "ddlpc_tpu.ops.metrics": JAX,
+    "ddlpc_tpu.ops.gated_delta": JAX,
     "ddlpc_tpu.ops.quantize": JAX,
     "ddlpc_tpu.ops.pallas_attention": JAX,
     "ddlpc_tpu.ops.pallas_quantize": JAX,

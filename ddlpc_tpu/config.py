@@ -138,6 +138,23 @@ class ModelConfig:
     indexer_head_dim: int = 64
     indexer_num_kv_heads: int = 1
     indexer_topk: int = 2048
+    # The olmo_hybrid family (models/olmo_hybrid.py), under the published names
+    # of its config.json; the defaults are Olmo-Hybrid-7B's and leave every
+    # other family's files loading as before.  ``layer_types`` holds
+    # 'linear_attention' (Gated DeltaNet) and 'full_attention' (no rotary: the
+    # family reads no ``rope_theta``).  ``tensor_shards`` is over how many
+    # tensor-parallel ranks each layer is divided: a layer holds that share of
+    # ``num_attention_heads``, of the ``linear_num_*_heads`` and of the
+    # ``intermediate_size`` columns, all given at their published counts, and
+    # computes its part of the two output sums; 1 is the uncut model.  Which
+    # of the ranks a process is changes nothing it computes, so no key says.
+    linear_num_key_heads: int = 30
+    linear_num_value_heads: int = 30
+    linear_key_head_dim: int = 96
+    linear_value_head_dim: int = 192
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = True
+    tensor_shards: int = 1
 
 
 @dataclass(frozen=True)

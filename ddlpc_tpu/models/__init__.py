@@ -16,6 +16,7 @@ from ddlpc_tpu.config import ModelConfig
 from ddlpc_tpu.models.deeplabv3p import DeepLabV3Plus
 from ddlpc_tpu.models.keye_vl2 import KeyeVL2
 from ddlpc_tpu.models.lfm2_moe import LFM2MoE
+from ddlpc_tpu.models.olmo_hybrid import OlmoHybrid
 from ddlpc_tpu.models.unet import UNet
 from ddlpc_tpu.models.unetpp import UNetPP
 
@@ -137,6 +138,27 @@ def _build_keye_vl2(cfg: ModelConfig, norm_axis_name: Optional[str]) -> nn.Modul
             f"are not among the router's {cfg.num_experts}"
         )
     return KeyeVL2(cfg)
+
+
+@register("olmo_hybrid")
+def _build_olmo_hybrid(cfg: ModelConfig, norm_axis_name: Optional[str]) -> nn.Module:
+    del norm_axis_name  # RMSNorm only
+    if not cfg.layer_types or set(cfg.layer_types) - {"linear_attention", "full_attention"}:
+        raise ValueError(
+            f"olmo_hybrid needs model.layer_types of 'linear_attention' | 'full_attention', "
+            f"got {cfg.layer_types!r}"
+        )
+    if cfg.tie_word_embeddings or cfg.num_key_value_heads != cfg.num_attention_heads:
+        raise ValueError("olmo_hybrid has an untied head and one k/v head a query head")
+    if cfg.linear_num_key_heads != cfg.linear_num_value_heads:
+        raise ValueError("olmo_hybrid's DeltaNet layers have one key head a value head")
+    shared = (cfg.num_attention_heads, cfg.linear_num_value_heads, cfg.intermediate_size)
+    if cfg.tensor_shards < 1 or any(n % cfg.tensor_shards for n in shared):
+        raise ValueError(
+            f"model.tensor_shards {cfg.tensor_shards} does not divide the heads and "
+            f"feed-forward columns {shared}"
+        )
+    return OlmoHybrid(cfg)
 
 
 def build_model(cfg: ModelConfig, norm_axis_name: Optional[str] = None) -> nn.Module:

@@ -35,10 +35,10 @@ over the selected keys and the time is the causal kernel's; skipping blocks
 by the selection is not done here.  It also returns the rows' log-sum-exp.
 
 Any head size that is a multiple of 128 lanes or divides them, and any number
-of query heads a k/v head: more than ``HEADS_PER_STEP`` of them are split into
-that many a grid step (each group reads its own copy of the k/v blocks, and
-their dK and dV are summed outside), so a step's scores stay
-``HEADS_PER_STEP · block`` rows whatever the grouping.
+of query heads a k/v head, one included (``models/olmo_hybrid.py``: a grid step
+then holds one head's ``block`` rows); more than ``HEADS_PER_STEP`` are split
+into that many a grid step (each group reads its own copy of the k/v blocks,
+their dK and dV are summed outside): at most ``HEADS_PER_STEP · block`` rows.
 
 :func:`head_mean_probs` is the target of the ``keye_vl2`` indexer's loss, the
 mean over the query heads of ``exp(q kᵀ / sqrt(D) - lse)`` for one block of
@@ -315,8 +315,8 @@ def _backward(q, k, v, do, lse, delta, block: int, scale: float, interpret: bool
 
 
 def _groups(heads: int, kv: int) -> int:
-    """Groups of query heads the kernels see: the k/v heads, or more where a
-    k/v head serves more than HEADS_PER_STEP query heads."""
+    """Groups of query heads the kernels see: the k/v heads (each with all its
+    query heads, be that one), or more where one serves over HEADS_PER_STEP."""
     g = heads // kv
     return kv * (g // HEADS_PER_STEP) if g > HEADS_PER_STEP and g % HEADS_PER_STEP == 0 else kv
 

@@ -92,6 +92,14 @@ def _shrunk(cfg: ExperimentConfig, workdir: str) -> ExperimentConfig:
             num_experts=16, num_experts_per_tok=2, experts_held=2, indexer_num_heads=16,
             indexer_head_dim=16, indexer_topk=24, layer_types=("full_attention",) * 2,
         )
+    if cfg.model.name == "olmo_hybrid":
+        # As above: four chunks of 64, two held heads of either kind.
+        h, w, scale = 1, 256, 1
+        model = dataclasses.replace(
+            cfg.model, num_classes=96, hidden_size=64, intermediate_size=96,
+            num_attention_heads=4, num_key_value_heads=4, linear_num_key_heads=4,
+            linear_num_value_heads=4, linear_key_head_dim=32, linear_value_head_dim=64,
+        )
     return cfg.replace(
         model=model,
         data=dataclasses.replace(
@@ -145,9 +153,9 @@ def test_config_files_exist():
     # The five BASELINE parity configs plus the TPU-first flagship and the
     # TPU-first U-Net++ (s2d stem — 20× the paper layout's throughput);
     # serve_*.json deploy artifacts are filtered out above.
-    # ... and the two token-tile configurations, lfm2_24b_a2b_ep8.json and
-    # keye_vl2_30b_a3b_ep8.json.
-    assert len(CONFIG_FILES) == 9, CONFIG_FILES
+    # ... and the three token-tile configurations, lfm2_24b_a2b_ep8.json,
+    # keye_vl2_30b_a3b_ep8.json and olmo_hybrid_7b_tp2.json.
+    assert len(CONFIG_FILES) == 10, CONFIG_FILES
 
 
 # ---- keye_vl2_30b_a3b_ep8: the shipped file, the benchmark's copy, the cut ----
@@ -217,6 +225,89 @@ def _keye_lists_what_it_assumed():
     ],
 )
 def test_keye_vl2_configuration(check):
+    check()
+
+
+# ---- olmo_hybrid_7b_tp2: the shipped file, the benchmark's copy, the cut ----
+
+_OLMO = os.path.join(CONFIG_DIR, "olmo_hybrid_7b_tp2.json")
+_OLMO_COPY = os.path.join(CONFIG_DIR, "..", "benchmark", "configs", "olmo_hybrid_7b_tp2.json")
+# The catalog's config for Olmo-Hybrid-7B (model-configs guide, architectures.jsonl).
+_OLMO_CATALOG = {
+    "model_type": "olmo_hybrid", "vocab_size": 100352, "hidden_size": 3840, "intermediate_size": 11008,
+    "num_hidden_layers": 32, "num_attention_heads": 30, "num_key_value_heads": 30, "hidden_act": "silu",
+    "max_position_embeddings": 65536, "attention_bias": False, "rms_norm_eps": 1e-06,
+    "tie_word_embeddings": False,
+    "layer_types": ["linear_attention", "linear_attention", "linear_attention", "full_attention"] * 8,
+    "linear_num_key_heads": 30, "linear_num_value_heads": 30, "linear_key_head_dim": 96,
+    "linear_value_head_dim": 192, "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
+    "rope_parameters": {"rope_theta": None},
+}
+
+
+def _olmo_copy_is_the_shipped_file():
+    shipped, copy = json.load(open(_OLMO)), json.load(open(_OLMO_COPY))
+    assert copy == shipped  # one file in two places: groups, cut, deployment, assumed
+    assert copy["reference"] == "olmo_hybrid" and copy["reference_sample_tiles"] == 1
+    assert ExperimentConfig.from_dict(shipped).to_dict()["model"]["name"] == "olmo_hybrid"
+
+
+def _olmo_holds_every_published_width():
+    cfg = ExperimentConfig.from_dict(json.load(open(_OLMO)))
+    m = cfg.model
+    assert (m.hidden_size, m.intermediate_size, m.head_dim or m.hidden_size // m.num_attention_heads) == (3840, 11008, 128)
+    assert (m.num_attention_heads, m.num_key_value_heads) == (30, 30)
+    assert (m.linear_num_key_heads, m.linear_num_value_heads) == (30, 30)
+    assert (m.linear_key_head_dim, m.linear_value_head_dim, m.linear_conv_kernel_dim) == (96, 192, 4)
+    assert m.linear_allow_neg_eigval and not m.tie_word_embeddings and m.norm_eps == 1e-6
+    assert m.layer_types == ("linear_attention",) * 3 + ("full_attention",)
+    assert m.tensor_shards == 2 and m.num_classes == 12544 == 100352 // 8
+    assert cfg.train.warmup_steps == 0 and cfg.train.micro_batch_size * cfg.train.sync_period == 4
+    assert tuple(cfg.data.image_size) == (1, 8192) and cfg.data.synthetic_len - cfg.data.test_split == 20
+
+
+def _olmo_top_level_is_the_catalogs_config_but_for_the_cut():
+    copy = json.load(open(_OLMO_COPY))
+    m = copy["model"]
+    reduced = ["num_hidden_layers", "layer_types", "num_attention_heads", "num_key_value_heads",
+               "linear_num_key_heads", "linear_num_value_heads", "vocab_size"]
+    assert copy["reduced"] == reduced
+    for key, published in _OLMO_CATALOG.items():
+        if key in reduced:
+            if key != "layer_types":
+                assert copy["published"][key] == published, key
+        else:  # every other key of the catalog, every width among them, as published
+            assert copy[key] == published, key
+    assert not [k for k in reduced if "size" in k and k != "vocab_size" or k.endswith("_dim")]
+    shards = m["tensor_shards"]
+    for key in ("num_attention_heads", "num_key_value_heads", "linear_num_key_heads", "linear_num_value_heads"):
+        assert copy[key] * shards == m[key] == _OLMO_CATALOG[key], key
+    assert copy["intermediate_columns_held"] * shards == m["intermediate_size"] == copy["intermediate_size"]
+    assert copy["num_hidden_layers"] == len(m["layer_types"]) == 4 and copy["layer_types"] == m["layer_types"]
+    assert copy["layer_types"] == _OLMO_CATALOG["layer_types"][:4]  # published layers 0-3, one whole period
+    assert copy["vocab_size"] == m["num_classes"] and copy["rms_norm_eps"] == m["norm_eps"]
+    assert "2 chips share each layer" in copy["deployment"]["layout"] and copy["deployment"]["chips"] == shards
+
+
+def _olmo_lists_what_it_assumed():
+    assumed = " ".join(json.load(open(_OLMO_COPY))["assumed"])
+    for item in ("post-norm residuals", "whole q vector", "no rotary", "SiLU after the depthwise causal taps",
+                 "no convolution bias", "96^-1/2", "2 sigmoid", "A_log", "dt_bias", "one weight for every head",
+                 "chunk 64", "no reset, no mask", "mean square is over the 1,920", "divided over 8",
+                 "N(0, 1) for the embedding", "warmup_steps 0"):
+        assert item in assumed, item
+
+
+@pytest.mark.parametrize(
+    "check",
+    [
+        pytest.param(_olmo_copy_is_the_shipped_file, id="copy-is-shipped"),
+        pytest.param(_olmo_holds_every_published_width, id="published-widths"),
+        pytest.param(_olmo_top_level_is_the_catalogs_config_but_for_the_cut, id="catalog-keys-and-reduced"),
+        pytest.param(_olmo_lists_what_it_assumed, id="assumed"),
+    ],
+)
+def test_olmo_hybrid_configuration(check):
     check()
 
 

@@ -71,6 +71,24 @@ def test_kernel_is_the_xla_form_and_plain_attention(seq, heads, kv_heads, block)
         assert relative(got, plain) < 2 * relative(xla, plain) < 1e-2
 
 
+@pytest.mark.parametrize("heads", [1, 3])
+def test_one_query_head_a_kv_head_of_128(heads):
+    """No grouping at all (``models/olmo_hybrid.py``: 15 heads of 128, each with
+    its own k and v): a grid step holds one head's block, ``_groups`` is the
+    head count, and an odd count is as good as any.  Float32, so the kernel
+    differs from plain attention by the order of its sums alone."""
+    assert pallas_attention._groups(heads, heads) == heads
+    keys = jax.random.split(jax.random.key(heads), 3)
+    q, k, v = (jax.random.normal(key, (2, 256, heads, 128)) for key in keys)
+    kernel = functools.partial(pallas_attention.causal_attention, block=128, interpret=True)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(kernel(q, k, v), plain_attention(q, k, v), rtol=1e-5, atol=1e-5)
+        g_got = jax.grad(lambda q, k, v: kernel(q, k, v).sum(), (0, 1, 2))(q, k, v)
+        g_want = jax.grad(lambda q, k, v: plain_attention(q, k, v).sum(), (0, 1, 2))(q, k, v)
+    for got, want in zip(g_got, g_want):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
 @pytest.mark.parametrize("head", [64, 32])
 def test_kernel_in_float32_is_plain_attention(head):
     """In float32 the kernel differs from plain attention by the order of its

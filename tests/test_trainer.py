@@ -254,8 +254,8 @@ def test_configs_dir_parses():
 
     paths = sorted(glob.glob(os.path.join(os.path.dirname(__file__), "..", "configs", "*.json")))
     # 5 BASELINE parity + TPU flagship + s2d U-Net++ + the token-tile
-    # lfm2_24b_a2b_ep8 and keye_vl2_30b_a3b_ep8 + serve + fleet deploys
-    assert len(paths) == 11
+    # lfm2_24b_a2b_ep8, keye_vl2_30b_a3b_ep8 and olmo_hybrid_7b_tp2 + serve + fleet deploys
+    assert len(paths) == 12
     for p in paths:
         if os.path.basename(p).startswith("serve_"):
             # serve_*.json are ServeConfig deploy artifacts, not experiments
