@@ -29,14 +29,18 @@ of ``ops/pallas_attention.py`` where the program is lowered for a TPU and they
 take the sequence, blocked XLA everywhere else), and returns each row's
 log-sum-exp; (3) the KL a block, the target recomputed from detached q and k
 and that log-sum-exp, the index scores computed again (so no ``[S, S]`` scores
-and no ``[S, S]`` cotangent wait between the passes).  The target has the
-attention's two lowerings, chosen the same way: where the kernels run,
-``ops/pallas_attention.head_mean_probs`` keeps every head's float32 scores of
-a block pair, their exponentials and the sum over the heads in VMEM and
-writes the head-averaged probabilities ``[block, keys]`` alone; the XLA form
-writes all ``[heads, block, keys]`` of them and reads them back.  Passes (1)
-and (3) are ``lax.scan``s over runs of ``BLOCKS_PER_SCAN`` query blocks that
-share the key width of their last block: one body's temporaries at a time,
+and no ``[S, S]`` cotangent wait between the passes).  The target and the
+index scores have the attention's two lowerings, chosen the same way: where
+the kernels run, ``ops/pallas_attention.head_mean_probs`` keeps every head's
+float32 scores of a block pair, their exponentials and the sum over the heads
+in VMEM and writes the head-averaged probabilities ``[block, keys]`` alone,
+and ``ops/pallas_attention.index_scores`` does the same for the indexer's
+ReLU'd, weighted pre-activations, in passes (1) and (3) and in the gradient
+of (3), whose kernel makes them again from the inputs; the XLA forms write
+all ``[heads, block, keys]`` of them, and the cotangent of as many, and read
+them back.  Passes (1) and (3) are ``lax.scan``s over runs of
+``BLOCKS_PER_SCAN`` query blocks that share the key width of their last
+block: one body's temporaries at a time,
 whatever the compiler's schedule (32 unrolled blocks a layer left the index
 products of three layers alive at once: 18.5 GB for the chip; PERF.md §6,
 PR 32).  Each block of (3) is rematerialised in the backward, and so is each
@@ -133,6 +137,19 @@ def _index_block(qi, ki, w, start):
     scores = (w[:, :, None] * nn.relu(pre)).sum(axis=1) * (qi.shape[1] * qi.shape[2]) ** -0.5
     seen = jnp.arange(ki.shape[0])[None, :] <= start + jnp.arange(qi.shape[0])[:, None]
     return jnp.where(seen, scores, -jnp.inf)
+
+
+def index_scores(qi, ki, w, start, seq_len: int):
+    """:func:`_index_block` of one block of queries of a sequence of
+    ``seq_len``, one algorithm with two lowerings, chosen as
+    :func:`selected_attention` chooses: the fused kernels
+    (``ops/pallas_attention.index_scores``: no ``[J, Bq, T]`` array in HBM,
+    forward or backward) where the program is lowered for a TPU and they take
+    the sequence length, and so its query blocks; the XLA form everywhere else."""
+    kernels = _kernels(seq_len)
+    if kernels is None or qi.shape[0] % kernels.LANES or ki.shape[0] % kernels.BLOCK:
+        return _index_block(qi, ki, w, start)
+    return lax.platform_dependent(qi, ki, w, start, tpu=kernels.index_scores, default=_index_block)
 
 
 def _threshold_block(scores, start, topk: int):
@@ -235,14 +252,14 @@ def head_mean_probs(q, k, lse, heads: int, seq_len: int):
 def _kl_block(qi, ki, w, tau, q, k, lse, start, heads: int, seq_len: int):
     """Σ over a block's queries of ``KL(p̄_t ‖ softmax_{s∈S_t} I[t,s])``.  The
     index scores are computed again from ``qi``, ``ki``, ``w`` (as
-    :func:`_index_block`; the gradient goes through them and nowhere else),
+    :func:`index_scores`; the gradient goes through them and nowhere else),
     the selection again from them and ``tau [Bq]``, and the target ``p̄``, the
     mean over the query heads of the attention's probabilities, from the
     detached q ``[KV, G·Bq, D]``, k ``[KV, T, D]`` and the attention's
     log-sum-exp ``[KV, G·Bq]``: one more ``q·kᵀ`` pass, forward only.  So no
     ``[S, S]`` array waits between the passes, nor its cotangent."""
     with jax.named_scope("ddlpc/dsa/indexer"):  # and their gradient, in the backward
-        scores = _index_block(qi, ki, w, start)
+        scores = index_scores(qi, ki, w, start, seq_len)
     with jax.named_scope("ddlpc/dsa/kl"):
         picked = _selection_bias(lax.stop_gradient(scores), tau) == 0
         target = head_mean_probs(q, k, lse, heads, seq_len)
@@ -304,7 +321,7 @@ class SparseAttention(nn.Module):
         def select(qs, ks, ws):  # one sequence -> its bias [S, S]; no gradient
             def body(ks, x):
                 with jax.named_scope("ddlpc/dsa/indexer"):
-                    scores = _index_block(x[0], ks, x[1], x[2])
+                    scores = index_scores(x[0], ks, x[1], x[2], s)
                 with jax.named_scope("ddlpc/dsa/select"):
                     tau = _threshold_block(scores, x[2], c.indexer_topk)
                     return ks, (tau, _selection_bias(scores, tau).astype(jnp.bfloat16))
@@ -447,9 +464,13 @@ class KeyeVL2(nn.Module):
             "dsa_pairs_causal": jnp.int32(len(layers) * ids.shape[0] * (s * (s + 1) // 2)),
         }
         sums |= jax.tree.map(lambda *v: sum(v), *[r["sum"] for r in routed])
-        # layers whose attention, and whose KL target, lowered to the kernels
+        # layers whose attention, index scores and KL target lowered to the kernels
         lowered = len(layers) * _kernel_lowers(s)
-        maxes = {"dsa_kernel_layers": lowered, "dsa_kl_kernel_layers": lowered * int(want_kl)}
+        maxes = {
+            "dsa_kernel_layers": lowered,
+            "dsa_index_kernel_layers": lowered,
+            "dsa_kl_kernel_layers": lowered * int(want_kl),
+        }
         maxes |= jax.tree.map(lambda *v: jnp.stack(v).max(), *[r["max"] for r in routed])
         for kind, values in (("sum", sums), ("max", maxes)):
             self.sow("counters", kind, values, reduce_fn=lambda _, v: v, init_fn=lambda: 0)

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels for causal grouped-query attention, forward and backward.
+"""Pallas TPU kernels for causal grouped-query attention, forward and backward,
+and for the two block-wise scores of the ``keye_vl2`` indexer's loss.
 
 ``softmax(q kᵀ / sqrt(D)) v`` under the causal mask in which no
 ``[..., rows, keys]`` array ever reaches HBM: a grid step holds one block of
@@ -9,21 +10,19 @@ arithmetic is that of ``models/lfm2_moe.py:_attend_block``: ``q·kᵀ`` from the
 compute dtype accumulated in float32; mask, running maximum, exponentials and
 running sum in float32; the probabilities cast to the compute dtype for
 ``P·V``, accumulated in float32.  The online (blockwise) softmax re-associates
-those sums and approximates nothing.
-
-Only the lower triangle of blocks is in the grid: the ``(query block, key
-block)`` pairs are flattened into one grid axis and read from two prefetched
-tables, so a block above the diagonal costs neither a step nor a DMA, and
-only the diagonal blocks build a mask.
+those sums and approximates nothing.  Only the lower triangle of blocks is in
+the grid: the ``(query block, key block)`` pairs are flattened into one grid
+axis and read from two prefetched tables, so a block above the diagonal costs
+neither a step nor a DMA, and only the diagonal blocks build a mask.
 
 The backward is one kernel over the same pairs that recomputes the
 probabilities from the saved row log-sum-exp (five products a pair): dQ
 accumulates over a query block's keys; dK and dV of the whole sequence stay
 in VMEM until the head's last pair, which bounds the sequence (``MAX_SEQ``).
-
-Every ``pallas_call`` carries a ``pl.CostEstimate`` of the causal half of
-the products it stands for; ``obs/flops.product_flops`` counts the kernel by
-it (the kernel's body holds one block's products, not the grid's).
+Every ``pallas_call`` carries a ``pl.CostEstimate`` of the products it stands
+for (the causal half here), recomputation not counted;
+``obs/flops.product_flops`` counts a kernel by it (the kernel's body holds
+one block's products, not the grid's).
 
 :func:`selected_attention` is the same pair of kernels over a per-pair
 selection (``models/keye_vl2.py``: a learned indexer picks each query's
@@ -33,22 +32,23 @@ grid step and added to the float32 scores in place of the diagonal mask.
 Every causal block is still computed, so the result is exactly the softmax
 over the selected keys and the time is the causal kernel's; skipping blocks
 by the selection is not done here.  It also returns the rows' log-sum-exp.
-
 Any head size that is a multiple of 128 lanes or divides them, and any number
-of query heads a k/v head, one included (``models/olmo_hybrid.py``: a grid step
-then holds one head's ``block`` rows); more than ``HEADS_PER_STEP`` are split
-into that many a grid step (each group reads its own copy of the k/v blocks,
-their dK and dV are summed outside): at most ``HEADS_PER_STEP · block`` rows.
+of query heads a k/v head, one included (``models/olmo_hybrid.py``); more than
+``HEADS_PER_STEP`` are split into that many a grid step (each group reads its
+own copy of the k/v blocks, their dK and dV are summed outside).
 
 :func:`head_mean_probs` is the target of the ``keye_vl2`` indexer's loss, the
 mean over the query heads of ``exp(q kᵀ / sqrt(D) - lse)`` for one block of
-queries against a run of keys, with the log-sum-exp that
-:func:`selected_attention` returned.  A grid step holds ``HEADS_PER_STEP``
-heads of one k/v head against one block of keys: their float32 scores
-(transposed, as in the backward), the exponentials and the running sum over
-the heads stay in VMEM; the groups of heads are the innermost grid axis, and
-what reaches HBM is one float32 ``[queries, keys]`` block after the last
-group.  Forward only: its inputs are detached.
+queries against a run of keys (forward only: its inputs are detached): a grid
+step holds ``HEADS_PER_STEP`` heads, the groups of heads the innermost axis.
+:func:`index_scores` is the indexer's own scores of such a block,
+``c Σ_j w[t,j] ReLU(qI[t,j]·kI[s])`` with -inf after the query, forward and
+backward (dqI, dkI, dw from the inputs alone: the pre-activations are made
+again): a grid step holds every head, each a run of lanes of q as the model
+lays it out.  Both hold a step's float32 ``[keys, queries]`` tiles transposed,
+as the backward above, so that a row statistic or a head's weights broadcast
+along sublanes; the tiles and the sum over the heads stay in VMEM, and what
+reaches HBM is one float32 ``[queries, keys]`` block a block of keys.
 """
 
 from __future__ import annotations
@@ -523,3 +523,176 @@ def head_mean_probs(q, k, lse, *, heads: int, block: int = BLOCK, interpret: boo
         interpret=interpret,
         name="head_mean_probs",
     )(q, k, lse)
+
+
+def _earlier(start, j, rows: int, block: int):
+    """``[rows, block]``: whether key ``j·block + column`` comes no later
+    than query ``start + row``."""
+    qpos = start + lax.broadcasted_iota(jnp.int32, (rows, block), 0)
+    kpos = j * block + lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+    return kpos <= qpos
+
+
+def _index_heads(q_ref, d: int):
+    """The heads of ``q_ref [queries, J·d]``, each ``[queries, d]``: a run of lanes."""
+    return [q_ref[:, n * d : (n + 1) * d] for n in range(q_ref.shape[1] // d)]
+
+
+def _index_fwd_kernel(start_ref, q_ref, k_ref, w_ref, o_ref, *, d: int, scale: float):
+    """Every indexer head of a block of queries against one block of keys.
+    A head's pre-activations are held transposed, ``[keys, queries]``, so its
+    weights ``w_ref[n]`` broadcast along sublanes; ReLU, weight and the sum
+    over the heads stay in VMEM, and the block is written once, transposed,
+    scaled and masked.  A block whose keys all come after the last query is
+    -inf without a product."""
+    j = pl.program_id(0)
+    rows, block = o_ref.shape
+    start = start_ref[0]
+    seen = j * block < start + rows  # the block's first key against the last query
+
+    @pl.when(seen)
+    def _():
+        k = k_ref[...]
+        total = None
+        for n, q in enumerate(_index_heads(q_ref, d)):
+            part = jnp.maximum(_scores(k, q, 1.0), 0.0) * w_ref[n : n + 1, :]
+            total = part if total is None else total + part
+        o_ref[...] = jnp.where(_earlier(start, j, rows, block), total.T * scale, -jnp.inf)
+
+    @pl.when(jnp.logical_not(seen))
+    def _():
+        o_ref[...] = jnp.full_like(o_ref, -jnp.inf)
+
+
+def _index_bwd_kernel(start_ref, q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref, dw_ref, dq_acc, *,
+                      d: int, scale: float):
+    """The pair of :func:`_index_fwd_kernel`, backward: a head's
+    pre-activations again, ``d_pre = [pre > 0] · w · c·ḡ`` (ReLU's slope at 0
+    is 0; ``ḡ`` the cotangent, zeroed here where the output was -inf), cast to
+    the compute dtype for its two products.  dq accumulates in VMEM over the
+    key blocks and is written after the last one, dw in its output block; dk
+    of a key block is the sum over the heads."""
+    j = pl.program_id(0)
+    rows, block = g_ref.shape
+    start = start_ref[0]
+    seen = j * block < start + rows
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    @pl.when(seen)
+    def _():
+        k = k_ref[...]
+        gt = jnp.where(_earlier(start, j, rows, block), g_ref[...], 0.0).T * scale  # [keys, rows]
+        dk, dq, dw = None, [], []
+        for n, q in enumerate(_index_heads(q_ref, d)):
+            pre = _scores(k, q, 1.0)
+            live = jnp.where(pre > 0, gt, 0.0)
+            dw.append((live * pre).sum(axis=0, keepdims=True))
+            d_pre = (live * w_ref[n : n + 1, :]).astype(q.dtype)
+            dq.append(lax.dot_general(d_pre, k, _TN, preferred_element_type=jnp.float32))
+            part = jnp.dot(d_pre, q, preferred_element_type=jnp.float32)
+            dk = part if dk is None else dk + part
+        dq_acc[...] += jnp.concatenate(dq, axis=1)
+        dw_ref[...] += jnp.concatenate(dw, axis=0)
+        dk_ref[...] = dk.astype(dk_ref.dtype)
+
+    @pl.when(jnp.logical_not(seen))
+    def _():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+
+    @pl.when(j == pl.num_programs(0) - 1)
+    def _():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _index_call(qi, ki, w, start, ct, block: int, interpret: bool):
+    """The index scores' forward kernel, or with a cotangent ``ct [Bq, T]``
+    the backward one (dqI, dkI, dw like their primals): a grid over the blocks
+    of keys, ``start`` prefetched, at the cost of the XLA form's products (one
+    ``[Bq·J, Di] x [Di, T]`` forward, two backward).  q stays in its own
+    layout, ``[Bq, J·Di]`` (a head is a run of lanes), and is read once; w
+    goes head-major, ``[J, Bq]``."""
+    rows, heads, d = qi.shape
+    keys = ki.shape[0]
+    q, w = qi.reshape(rows, heads * d), w.T
+    whole = lambda x: pl.BlockSpec(x.shape, lambda j, s: (0,) * len(x.shape))  # noqa: E731
+    key_block = pl.BlockSpec((block, d), lambda j, s: (j, 0))
+    pair_block = pl.BlockSpec((rows, block), lambda j, s: (0, j))
+    if ct is None:
+        kernel, name, operands = _index_fwd_kernel, "index_scores_fwd", (q, ki, w)
+        out_shape, out_specs = jax.ShapeDtypeStruct((rows, keys), jnp.float32), pair_block
+        scratch = []
+    else:
+        kernel, name, operands = _index_bwd_kernel, "index_scores_bwd", (q, ki, w, ct)
+        out_shape = (jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct(ki.shape, ki.dtype),
+                     jax.ShapeDtypeStruct(w.shape, jnp.float32))
+        out_specs = (whole(q), key_block, whole(w))
+        scratch = [pltpu.VMEM(q.shape, jnp.float32)]
+    out = pl.pallas_call(
+        functools.partial(kernel, d=d, scale=float(heads * d) ** -0.5),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(keys // block,),
+            in_specs=[whole(q), key_block, whole(w)] + [pair_block] * (ct is not None),
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=48 << 20,  # a head's float32 [keys, queries] tiles at 1 MiB, dq of every head
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=(1 if ct is None else 2) * 2 * heads * rows * keys * d,
+            transcendentals=0,
+            bytes_accessed=_nbytes(*operands, *jax.tree.leaves(out_shape)),
+        ),
+        interpret=interpret,
+        name=name,
+    )(jnp.asarray(start, jnp.int32).reshape(1), *operands)
+    if ct is None:
+        return out
+    dq, dk, dw = out
+    return dq.reshape(qi.shape), dk, dw.T
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _index(qi, ki, w, start, block, interpret):
+    return _index_call(qi, ki, w, start, None, block, interpret)
+
+
+def _index_fwd(qi, ki, w, start, block, interpret):
+    return _index_call(qi, ki, w, start, None, block, interpret), (qi, ki, w, start)
+
+
+def _index_bwd(block, interpret, residuals, ct):
+    return (*_index_call(*residuals, ct, block, interpret), None)  # none for ``start``
+
+
+_index.defvjp(_index_fwd, _index_bwd)
+
+
+def index_scores(qi, ki, w, start, *, block: int = BLOCK, interpret: bool = False):
+    """The ``keye_vl2`` indexer's scores of one block of queries against
+    ``T`` keys, ``(J·Di)^-½ Σ_j w[t,j] · ReLU(qI[t,j] · kI[s])``: qi
+    ``[Bq, J, Di]`` and ki ``[T, Di]`` in the compute dtype, w ``[Bq, J]``
+    float32, ``start`` the (traced) position of the block's first query;
+    ``T`` a multiple of ``block`` and ``Bq`` of 128.  Returns float32
+    ``[Bq, T]``, -inf where the key comes after the query.  Products from the
+    compute dtype accumulated in float32, everything else float32; only the
+    order of the sum over the heads differs from the XLA form
+    (``models/keye_vl2.py:_index_block``).  Differentiable in qi, ki and w:
+    the backward's residuals are the inputs alone, and its two products take
+    ``d_pre`` rounded to the compute dtype and accumulate in float32, which
+    is how the XLA form's gradient takes its float32 ``d_pre`` on a TPU at
+    the default matmul precision."""
+    rows, keys = qi.shape[0], ki.shape[0]
+    if keys % block or block % LANES or rows % LANES:
+        raise ValueError(
+            f"{keys} keys are not a multiple of the kernel's block {block}, or the "
+            f"{rows} queries not one of {LANES}"
+        )
+    return _index(qi, ki, w, start, block, interpret)

@@ -4,10 +4,10 @@ program against the plain float32 reference the benchmark keeps
 and on the indexer's loss and its gradients (benchmark/check_indexer.py); the
 two exact zeros; the selection rule; sectioned rotary positions; the softmax
 router and the eight shares against the uncut layer; the extended attention
-kernels interpreted against the XLA form, the KL target's among them (alone,
-and through ``SparseAttention`` on the indexer's loss and gradient); the
-Trainer; the step's own-loss seam and the unchanged lowering of the models
-that sow none.
+kernels interpreted against the XLA form, the KL target's and the index
+scores' (forward and backward) among them (alone, and through
+``SparseAttention`` on the indexer's loss and gradient); the Trainer; the
+step's own-loss seam and the unchanged lowering of the models that sow none.
 
 Tiny: hidden 64, 8/2 heads of 32 (so q is 256 wide, not hidden), indexer 16
 heads of 16 picking 24 keys, 16 experts top-2 (2 held), vocabulary 96, S 128
@@ -303,10 +303,82 @@ def test_head_mean_probs_kernel_refuses_what_its_blocks_do_not_divide():
         pallas_attention.head_mean_probs(q[:, :256], k[:, :128], jnp.zeros((2, 256)), heads=8, block=128, interpret=True)
 
 
-def indexer_loss_and_gradient(monkeypatch, kernel: bool):
+def index_inputs(seed: int, heads: int, d: int, rows: int, keys: int, dtype=jnp.float32):
+    """One run's indexer projections: ``keys / rows`` query blocks ``qI
+    [rows, heads, d]`` and their weights ``[rows, heads]``, and the run's keys."""
+    kq, kk, kw = jax.random.split(jax.random.key(seed), 3)
+    qi = jax.random.normal(kq, (keys // rows, rows, heads, d), dtype)
+    ki = jax.random.normal(kk, (keys, d), dtype)
+    return qi, ki, jax.random.normal(kw, (keys // rows, rows, heads), jnp.float32)
+
+
+@pytest.mark.parametrize("heads,d", [(16, 64), (6, 32), (1, 128)])
+def test_index_scores_kernel_is_the_xla_form(heads, d):
+    """A run's four query blocks against the run's keys: every block but the
+    last has key blocks wholly after it, which the kernel fills with -inf
+    without a product, and every block a diagonal one that it masks.  A
+    head is a run of 64, 32 or 128 lanes of q as the model lays it out; only
+    the order of the head sum differs."""
+    from ddlpc_tpu.ops import pallas_attention
+
+    rows = block = 128
+    qi, ki, w = index_inputs(3, heads, d, rows, 4 * rows)
+    for i in range(4):
+        start = jnp.int32(i * rows)
+        want = program._index_block(qi[i], ki, w[i], start)
+        got = jax.jit(
+            lambda q, k, w, s: pallas_attention.index_scores(q, k, w, s, block=block, interpret=True)
+        )(qi[i], ki, w[i], start)
+        assert got.shape == (rows, 4 * rows) and got.dtype == jnp.float32
+        after = np.arange(4 * rows)[None] > i * rows + np.arange(rows)[:, None]
+        assert np.array_equal(np.isneginf(got), after) and np.array_equal(np.isneginf(want), after)
+        np.testing.assert_allclose(
+            np.where(after, 0.0, got), np.where(after, 0.0, want), rtol=0, atol=1e-6 * float(jnp.abs(want[:, 0]).max())
+        )
+
+
+@pytest.mark.parametrize("heads,d", [(16, 64), (2, 32)])
+def test_index_scores_kernel_gradient_is_the_xla_forms(heads, d):
+    """dqI, dkI and dw under a random cotangent against ``jax.vjp`` of the
+    XLA form, on a late block of a run (a skipped key block, a diagonal one,
+    whole ones).  Head 0 of the first queries is zero, so its pre-activations
+    are: ReLU's slope there is 0, in both forms exactly."""
+    from ddlpc_tpu.ops import pallas_attention
+
+    rows = block = 128
+    qi, ki, w = index_inputs(4, heads, d, rows, 4 * rows)
+    qi = qi.at[2, :8, 0].set(0.0)
+    start = jnp.int32(2 * rows)
+    ct = jax.random.normal(jax.random.key(5), (rows, 4 * rows))
+    kernel = lambda q, k, w: pallas_attention.index_scores(q, k, w, start, block=block, interpret=True)  # noqa: E731
+    got, pull = jax.vjp(kernel, qi[2], ki, w[2])
+    want, pull_xla = jax.vjp(lambda q, k, w: program._index_block(q, k, w, start), qi[2], ki, w[2])
+    ct = jnp.where(jnp.isfinite(want), ct, 0.0)  # what reaches a -inf is dropped by both
+    for name, a, b in zip(("dqI", "dkI", "dw"), pull(ct), pull_xla(ct)):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-6 * float(jnp.abs(b).max()), err_msg=name)
+    dq, dk, dw = pull(ct)
+    assert not np.any(np.asarray(dq[:8, 0])) and not np.any(np.asarray(dw[:8, 0]))
+    assert not np.any(np.asarray(dk[3 * rows :]))  # the keys after the block: no query saw them
+    # a cotangent on the -inf part changes nothing (0 · inf never arises)
+    for a, b in zip(pull(jnp.ones_like(ct)), pull(jnp.where(jnp.isfinite(want), 1.0, 0.0))):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_index_scores_kernel_refuses_what_its_blocks_do_not_divide():
+    from ddlpc_tpu.ops import pallas_attention
+
+    qi, ki, w = jnp.zeros((128, 4, 32)), jnp.zeros((192, 32)), jnp.zeros((128, 4))
+    with pytest.raises(ValueError, match="not a multiple"):  # 192 keys in blocks of 128
+        pallas_attention.index_scores(qi, ki, w, 0, block=128, interpret=True)
+    with pytest.raises(ValueError, match="not a multiple"):  # 64 queries
+        pallas_attention.index_scores(qi[:64], ki[:128], w[:64], 0, block=128, interpret=True)
+
+
+def indexer_loss_and_gradient(monkeypatch, kernel: bool, index_kernel: bool = False):
     """``L_I`` and its gradient over every leaf of one ``SparseAttention``:
-    eight query blocks of 128 in two runs, the target by the kernel
-    (interpreted) or by the XLA form."""
+    eight query blocks of 128 in two runs, the target and the index scores
+    each by their kernels (interpreted) or by the XLA form."""
     from ddlpc_tpu.ops import pallas_attention
 
     seq = 1024
@@ -316,6 +388,13 @@ def indexer_loss_and_gradient(monkeypatch, kernel: bool):
             program, "head_mean_probs",
             lambda q, k, lse, heads, seq_len: pallas_attention.head_mean_probs(
                 q, k, lse, heads=heads, block=128, interpret=True
+            ),
+        )
+    if index_kernel:
+        monkeypatch.setattr(
+            program, "index_scores",
+            lambda qi, ki, w, start, seq_len: pallas_attention.index_scores(
+                qi, ki, w, start, block=128, interpret=True
             ),
         )
     cfg = tiny_config(indexer_topk=200)
@@ -345,11 +424,48 @@ def test_indexer_loss_and_gradient_through_the_kernel_are_the_xla_forms(monkeypa
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5 * float(jnp.abs(want).max()))
 
 
-@pytest.mark.parametrize(
-    "platform,seq,kernel",
-    [("tpu", 1024, True), ("tpu", 512, True), ("cpu", 1024, False), ("tpu", 256, False),
-     ("tpu", 16384 + 512, False)],
-)
+def test_indexer_loss_and_gradient_through_the_index_kernels_are_the_xla_forms(monkeypatch):
+    """The selection and the loss both from the index kernels, the gradient
+    through their backward: the scores differ from the XLA form's in the
+    order of a 16-term sum, so a pick at a threshold may, and the loss is
+    held to 1e-4; nothing leaves the indexer on either path."""
+    loss_x, grads_x = indexer_loss_and_gradient(monkeypatch, kernel=False)
+    loss_k, grads_k = indexer_loss_and_gradient(monkeypatch, kernel=True, index_kernel=True)
+    np.testing.assert_allclose(loss_k, loss_x, rtol=1e-4)
+    for grads in (grads_x, grads_k):
+        own, rest = check_indexer.split(grads)
+        assert all(np.any(np.asarray(g)) for g in own)
+        assert all(not np.any(np.asarray(g)) for g in rest)
+    for got, want in zip(check_indexer.split(grads_k)[0], check_indexer.split(grads_x)[0]):
+        np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-4 * float(jnp.abs(want).max()))
+
+
+PATHS = [("tpu", 1024, True), ("tpu", 512, True), ("cpu", 1024, False), ("tpu", 256, False),
+         ("tpu", 16384 + 512, False)]
+
+
+@pytest.mark.parametrize("platform,seq,kernel", PATHS)
+def test_index_path_goes_by_platform_and_sequence_length(platform, seq, kernel):
+    """As the target's path below: the forward kernel, and under a gradient
+    the backward one, where the program is lowered for a TPU and the kernels
+    take the sequence; the XLA form, and no error, everywhere else."""
+    block = min(512, seq)
+    qi = jax.ShapeDtypeStruct((block, 16, 64), jnp.bfloat16)
+    ki = jax.ShapeDtypeStruct((seq, 64), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((block, 16), jnp.float32)
+    start = jax.ShapeDtypeStruct((), jnp.int32)
+    forward = lambda qi, ki, w, start: program.index_scores(qi, ki, w, start, seq)  # noqa: E731
+    text = jax.jit(forward).trace(qi, ki, w, start).lower(lowering_platforms=(platform,)).as_text()
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == (1 if kernel else 0)
+    assert ("index_scores_fwd" in text) == kernel
+    assert ("stablehlo.dot_general" in text) != kernel  # the XLA form's product, or none
+    gradient = jax.grad(lambda qi, ki, w, start: jnp.sum(jnp.exp(forward(qi, ki, w, start))), (0, 1, 2))
+    text = jax.jit(gradient).trace(qi, ki, w, start).lower(lowering_platforms=(platform,)).as_text()
+    assert ("index_scores_bwd" in text) == kernel
+    assert ("stablehlo.dot_general" in text) != kernel
+
+
+@pytest.mark.parametrize("platform,seq,kernel", PATHS)
 def test_target_path_goes_by_platform_and_sequence_length(platform, seq, kernel):
     """The kernel where the program is lowered for a TPU and the attention's
     kernels take the sequence; the XLA form, and no error, everywhere else."""
@@ -367,7 +483,8 @@ def test_target_path_goes_by_platform_and_sequence_length(platform, seq, kernel)
 @pytest.mark.parametrize("collects_losses", [True, False])
 def test_model_builds_the_target_only_where_its_loss_is_collected(monkeypatch, collects_losses):
     """Lowered for a TPU at a length the kernels take, a layer holds the
-    attention's forward kernel, and one target kernel a sequence and run
+    attention's forward kernel, the selection's index kernel a sequence and
+    run, and one target kernel and one more index kernel a sequence and run
     where the caller collects ``losses``; evaluation and the reference check
     collect none and build no target at all."""
     monkeypatch.setattr(program, "QUERY_BLOCK", 512)
@@ -382,7 +499,9 @@ def test_model_builds_the_target_only_where_its_loss_is_collected(monkeypatch, c
     layers, runs = len(cfg.layer_types), 1  # two blocks of 512: one run
     assert text.count("selected_attention_fwd") >= layers
     calls = text.count("stablehlo.custom_call @tpu_custom_call")
-    assert calls == layers * (1 + batch * runs * collects_losses)
+    assert calls == layers * (1 + batch * runs * (1 + 2 * collects_losses))
+    assert text.count("index_scores_fwd") >= layers * (1 + collects_losses)
+    assert (text.count("head_mean_probs") > 0) == collects_losses
 
 
 def test_causal_kernels_take_eight_query_heads_of_128():
@@ -500,6 +619,7 @@ def test_model_is_causal_and_counts_its_pairs():
     assert int(sums["dsa_pairs_causal"]) == 2 * 2 * SEQ * (SEQ + 1) // 2
     assert int(sown["counters"]["max"]["dsa_kernel_layers"]) == 0  # the XLA form on the CPU
     assert int(sown["counters"]["max"]["dsa_kl_kernel_layers"]) == 0  # of the target too
+    assert int(sown["counters"]["max"]["dsa_index_kernel_layers"]) == 0  # and of the index scores
     assert int(sums["moe_rows_dropped"]) == 0 and int(sums["tokens_per_step"]) == 2 * SEQ
     assert float(sown["losses"]["indexer_kl"]) > 0
     # no own loss where nobody collects it, and the head is its own matrix
@@ -552,6 +672,7 @@ def test_trainer_fits_two_steps_and_records_the_own_loss_and_counters(tmp_path):
     assert record["dsa_pairs_causal"] == 4 * 2 * SEQ * (SEQ + 1) // 2  # sequences x layers
     assert per_sequence * 8 <= record["dsa_pairs_selected"] < 1.05 * per_sequence * 8  # ties only
     assert record["dsa_kernel_layers"] == 0.0 and record["dsa_kl_kernel_layers"] == 0.0
+    assert record["dsa_index_kernel_layers"] == 0.0
     assert record["moe_rows_dropped"] == 0.0 and record["tokens_per_step"] == 4 * SEQ
     assert record["moe_rows_offered"] == 4 * SEQ * 2 * 2
     assert 0 < record["moe_rows_routed"] < record["moe_rows_offered"]
@@ -673,3 +794,37 @@ def test_product_flops_counts_the_indexer_and_the_kernel_by_its_estimate():
     assert cost.flops == 2 * int(np.prod(dot.outvars[0].aval.shape)) * d == 2 * heads * block * keys * d
     assert cost.transcendentals == heads * block * keys
     assert cost.bytes_accessed == 2 * (heads * block * d + kv * keys * d) + 4 * (heads * block + block * keys)
+    # The index scores' kernels state the XLA form's products too: the one of
+    # the forward, and the two of its gradient (dqI and dkI; the recomputed
+    # pre-activations are not counted), no transcendental.
+    rows, keys, j, di = 128, 512, 16, 64
+    shapes = (
+        jax.ShapeDtypeStruct((rows, j, di), jnp.bfloat16), jax.ShapeDtypeStruct((keys, di), jnp.bfloat16),
+        jax.ShapeDtypeStruct((rows, j), jnp.float32),
+    )
+
+    def products(fn):
+        def both(qi, ki, w, ct):
+            out, pull = jax.vjp(lambda qi, ki, w: fn(qi, ki, w, jnp.int32(384)), qi, ki, w)
+            return out, pull(ct)
+
+        return jax.make_jaxpr(both)(*shapes, jax.ShapeDtypeStruct((rows, keys), jnp.float32)).jaxpr
+
+    costs = {
+        e.params["name"]: e.params["cost_estimate"]
+        for e in flops.iter_eqns(products(lambda *a: pallas_attention.index_scores(*a, block=block)))
+        if e.primitive.name == "pallas_call"
+    }
+    dots = [e for e in flops.iter_eqns(products(program._index_block)) if e.primitive.name == "dot_general"]
+    one = 2 * j * rows * keys * di
+
+    def dot_flops(e):
+        (contract, _), _ = e.params["dimension_numbers"]
+        return 2 * int(np.prod(e.outvars[0].aval.shape)) * int(np.prod([e.invars[0].aval.shape[c] for c in contract]))
+
+    assert [dot_flops(e) for e in dots] == [one] * 3
+    assert costs["index_scores_fwd"].flops == one and costs["index_scores_bwd"].flops == 2 * one
+    assert costs["index_scores_fwd"].transcendentals == costs["index_scores_bwd"].transcendentals == 0
+    operands = 2 * (rows * j * di + keys * di) + 4 * rows * j
+    assert costs["index_scores_fwd"].bytes_accessed == operands + 4 * rows * keys
+    assert costs["index_scores_bwd"].bytes_accessed == 2 * operands + 4 * rows * keys
