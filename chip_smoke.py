@@ -107,23 +107,12 @@ def rebuild_native() -> None:
     )
 
 
-class CacheEvents:
-    """Counts JAX's persistent-compile-cache hits and misses."""
-
-    HIT = "/jax/compilation_cache/cache_hits"
-    MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self):
-        self.hits = self.misses = 0
-
-    def __call__(self, event: str, **_) -> None:
-        self.hits += event == self.HIT
-        self.misses += event == self.MISS
-
-    def take(self) -> dict:
-        out = {"cache_hits": self.hits, "cache_misses": self.misses}
-        self.hits = self.misses = 0
-        return out
+def cache_counts(cursor) -> dict:
+    """The persistent cache's hits and misses since the cursor was last
+    taken, from the compile ledger (``utils/compile_cache.py``): a miss is a
+    program XLA compiled, whether or not the cache wrote an entry for it."""
+    taken = cursor.take()
+    return {"cache_hits": taken["programs_loaded"], "cache_misses": taken["programs_compiled"]}
 
 
 def epoch_records(workdir: str) -> tuple[list, list]:
@@ -269,7 +258,7 @@ def arm_train(run, workdir, n_devices, on_chip, cache) -> tuple:
         "step_time_s_last_epoch": records[-1]["step_time_s"],
         "first_step_dispatch_s_cold": records[0]["t_step_s"],
         "collectives": collective_census(trainer),
-        **cache.take(),
+        **cache_counts(cache),
     }
     if on_chip:
         check(
@@ -296,7 +285,7 @@ def arm_resume(run, workdir, cold_s, cache_dir, on_chip, cache) -> tuple:
         "first_step_dispatch_s_cold": cold_s,
         "first_step_dispatch_s_warm": warm_s,
         "compile_cache_dir": cache_dir,
-        **cache.take(),
+        **cache_counts(cache),
     }
     if on_chip:
         check(
@@ -329,7 +318,7 @@ def arm_host_fed(run, workdir, cached_loss, on_chip, cache) -> dict:
         "t_loader_gather_s": records[-1].get("t_loader_gather_s"),
         "t_loader_upload_s": records[-1].get("t_loader_upload_s"),
         "ring_slots_retired": ring.retired,
-        **cache.take(),
+        **cache_counts(cache),
     }
     if on_chip:
         # CPU clients alias the host buffer (the ring retires the slot);
@@ -527,11 +516,9 @@ def smoke(device: dict, width: tuple = (), out_dir: str = OUT) -> dict:
     """Run every arm against ``device`` (what :func:`require_chip`
     returned).  ``width`` holds extra ``--set`` overrides; the script
     itself passes none — the flagship's full width is the point."""
-    import jax
-
     from ddlpc_tpu.train.__main__ import parse_config
     from ddlpc_tpu.train.__main__ import run as train_run
-    from ddlpc_tpu.utils.compile_cache import enable_compile_cache
+    from ddlpc_tpu.utils.compile_cache import enable_compile_cache, install_compile_ledger
 
     on_chip = device["platform"] == "tpu"
     n = device["count"]
@@ -560,8 +547,7 @@ def smoke(device: dict, width: tuple = (), out_dir: str = OUT) -> dict:
         argv += [a for s in sets + extra for a in ("--set", s)]
         return train_run(argv + ([] if resume else ["--no-resume"]))
 
-    cache = CacheEvents()
-    jax.monitoring.register_event_listener(cache)
+    cache = install_compile_ledger().cursor()
     results: dict = {}
     failed: list = []
 
@@ -613,7 +599,6 @@ def smoke(device: dict, width: tuple = (), out_dir: str = OUT) -> dict:
             gc.collect()
             arm("kernel", arm_kernel, cfg, n, not on_chip)
     finally:
-        jax.monitoring.unregister_event_listener(cache)
         # What the call brings back is capped: keep the records, drop the
         # blobs (three ~95 MB checkpoints a run, PNGs, the raw trace).
         for run_dir in ("train", "host_fed"):

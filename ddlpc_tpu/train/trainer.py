@@ -68,6 +68,7 @@ from ddlpc_tpu.train.observability import (
 )
 from ddlpc_tpu.train.optim import build_optimizer
 from ddlpc_tpu.train.watchdog import StallWatchdog
+from ddlpc_tpu.utils.compile_cache import install_compile_ledger
 
 
 # The step's own metrics; any other key it returns is a model counter.
@@ -105,6 +106,11 @@ class Trainer:
 
     def __init__(self, cfg: ExperimentConfig, resume: bool = True):
         initialize_distributed()
+        # What JAX traces, lowers, compiles or loads from here on (a no-op
+        # install where an entry point already did it): construction's share
+        # goes into the kind="init" line and the first epoch record as
+        # init_compile_*_s / init_programs_*, each record carries its own.
+        self._compiles = install_compile_ledger().cursor()
         self.cfg = cfg
         if cfg.model.num_classes != cfg.data.num_classes:
             raise ValueError(
@@ -499,6 +505,9 @@ class Trainer:
                 background=cfg.train.checkpoint_async,
             )
         self._init_times = _record_keys(self.timer.summary())
+        self._init_times.update(
+            {f"init_{k}": v for k, v in self._compiles.take().items()}
+        )
         self.timer.reset()
         self.logger.log({"kind": "init", **self._init_times}, echo=False)
 
@@ -898,6 +907,10 @@ class Trainer:
             # perf_publish, evaluate, checkpoint, dump stages — land in the
             # NEXT epoch's record (docs/OBSERVABILITY.md).
             record.update(_record_keys(self.timer.means()))
+            # The compile ledger's growth since the last record, or since
+            # fit() began: programs compiled after this line (evaluate, save)
+            # count in the next record, like the stages.
+            record.update(self._compiles.take())
             # Construction's phases, once in the Trainer's life.
             record.update(self._init_times)
             self._init_times = {}
@@ -1105,6 +1118,8 @@ class Trainer:
             )
         if self.perf is not None:
             self.perf.start()
+        # What the caller compiled since the last record is not this fit's.
+        self._compiles.take()
         try:
             with self.watchdog:
                 try:

@@ -295,3 +295,58 @@ def test_traced_stage_keeps_name_attributes_and_parent(tmp_path):
 )
 def test_profile_report_names_an_ops_scope(tf_op, scope):
     assert op_scope(tf_op) == scope
+
+
+# ---- the compile ledger's readers (benchmark/layer_metrics/setup_*.py) ------
+
+
+def _ledger_reader(name):
+    import importlib.util
+    import sys
+
+    bench = os.path.join(REPO, "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)  # the readers import setup_compile by name, as in run.py
+    spec = importlib.util.spec_from_file_location(
+        f"layer_metrics__{name}", os.path.join(bench, "layer_metrics", name + ".py")
+    )
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _ledger_record(scale, init=False):
+    counters = {
+        "compile_trace_s": 1.0, "compile_lower_s": 2.0, "compile_load_s": 4.0,
+        "compile_xla_s": 8.0, "programs_loaded": 16, "programs_compiled": 32,
+    }
+    out = {"loss": 1.0, **{k: v * scale for k, v in counters.items()}}
+    if init:
+        out.update({f"init_{k}": v * 100 for k, v in counters.items()})
+    return out
+
+
+@pytest.mark.parametrize(
+    "name,want",
+    [
+        ("setup_lowering_s", 300.0 + 3.0 * 1.5),  # construction, then epochs 1 + 0.5 + 0
+        ("setup_xla_compile_s", 800.0 + 8.0 * 1.5),
+        ("setup_cache_load_s", 400.0 + 4.0 * 1.5),
+        ("setup_programs_compiled", 3200 + 32 * 1.5),
+    ],
+)
+def test_setup_readers_sum_construction_and_warmup(name, want):
+    run = {"warmup_records": [_ledger_record(1, init=True), _ledger_record(0.5), _ledger_record(0)]}
+    assert _ledger_reader(name).read(run) == pytest.approx(want)
+
+
+@pytest.mark.parametrize(
+    "name", ["setup_lowering_s", "setup_xla_compile_s", "setup_cache_load_s", "setup_programs_compiled"]
+)
+def test_setup_readers_read_none_without_the_ledger(name):
+    reader = _ledger_reader(name)
+    assert reader.read({"warmup_records": [{"loss": 1.0, "t_init_state_s": 2.0}, {"loss": 0.9}]}) is None
+    assert reader.read({"warmup_records": []}) is None
+    # construction's counters without the records' own is not a ledger either
+    partial = [_ledger_record(1, init=True), {"loss": 0.9}]
+    assert reader.read({"warmup_records": partial}) is None
