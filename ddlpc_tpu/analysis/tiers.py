@@ -134,6 +134,7 @@ MODULE_TIERS: Dict[str, str] = {
     "ddlpc_tpu.ops.gated_delta": JAX,
     "ddlpc_tpu.ops.quantize": JAX,
     "ddlpc_tpu.ops.pallas_attention": JAX,
+    "ddlpc_tpu.ops.pallas_gated_delta": JAX,
     "ddlpc_tpu.ops.pallas_quantize": JAX,
     "ddlpc_tpu.parallel": JAX,
     "ddlpc_tpu.parallel.mesh": JAX,

@@ -8,8 +8,8 @@ signal (``rope_theta: null``) in ``full_attention`` layers, three to one; the
 feed-forward is a dense SwiGLU in every layer, and the residuals are
 post-norm: ``x = h + norm(Mix(h)); h' = x + norm(SwiGLU(x))``.  A DeltaNet
 head carries a ``[key dim, value dim]`` matrix along the sequence
-(``ops/gated_delta.py``: the chunkwise form, a ``lax.scan`` over chunks of 64
-positions and reverse mode through it); q, k and v come through depthwise
+(``ops/gated_delta.py``: the chunkwise form over chunks of 64 positions, the
+walk over them Pallas kernels on the TPU); q, k and v come through depthwise
 causal taps and a SiLU, q and k are normalised to unit length, the write
 strength is ``β = 2σ(·)`` (``linear_allow_neg_eigval``) and the decay
 ``α = exp(−exp(A_log) · softplus(· + dt_bias))``; the output is RMS-normed a
@@ -53,7 +53,7 @@ from ddlpc_tpu.models.lfm2_moe import (
     _proj,
     causal_attention,
 )
-from ddlpc_tpu.ops.gated_delta import CHUNK, gated_delta_rule
+from ddlpc_tpu.ops.gated_delta import CHUNK, gated_delta_rule, kernel_lowers
 
 L2_EPS = 1e-6  # under the root of a head's Σx², as the family's public layer has it
 
@@ -223,6 +223,7 @@ class OlmoHybrid(nn.Module):
         maxes = {
             "attention_kernel_layers": c.layer_types.count("full_attention") * _kernel_lowers(s),
             "gdn_layers": jnp.int32(linear),
+            "gdn_kernel_layers": linear * kernel_lowers(s),
         }
         for kind, values in (("sum", sums), ("max", maxes)):
             self.sow("counters", kind, values, reduce_fn=lambda _, v: v, init_fn=lambda: 0)

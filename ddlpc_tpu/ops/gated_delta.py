@@ -20,17 +20,22 @@ unit lower-triangular system (the WY representation):
     O = (e^γ ⊙ Q) S + tril(Q Kᵀ ⊙ e^{γ_i − γ_j}) Ṽ
     S' = e^{γ_C} S + (e^{γ_C − γ} ⊙ K)ᵀ Ṽ
 
-Everything but the last three lines is computed for all chunks together; the
-``lax.scan`` carries ``S`` in float32 through ``S / CHUNK`` steps of four
-small products a head.  Decays, ``β``, ``A`` and its inverse are float32 (the
-inverse's own products at full precision); the other products take operands
-in the caller's compute dtype and accumulate in float32, the state cast for
-them as the values are.  Every exponent is a difference ``γ_i − γ_j`` with
-``i ≥ j``, so nothing overflows however long the chunk decays.
+Everything but the last three lines is computed for all chunks together
+(:func:`chunk_algebra`); the walk over the chunks carries ``S`` in float32
+through ``S / CHUNK`` steps of four small products a head.  Decays, ``β``,
+``A`` and its inverse are float32 (the inverse's own products at full
+precision); the other products take operands in the caller's compute dtype
+and accumulate in float32, the state cast for them as the values are (``W``
+and ``U`` as ``T diag(x)`` times ``K`` and ``V``: the scales go on ``T``'s
+columns, and ``K`` and ``V`` enter as they are, with no scaled copy).  Every
+exponent is a difference ``γ_i − γ_j`` with ``i ≥ j``, so nothing overflows
+however long the chunk decays.
 
-The backward is reverse-mode through the scan (its per-chunk residuals are
-the states ``S`` in the compute dtype and ``Ṽ``), with the inverse
-differentiated as a whole: ``Ā = −Tᵀ T̄ Tᵀ``.
+The walk has two lowerings (:func:`gated_delta_rule`): a ``lax.scan``
+(:func:`walk`) with reverse mode through it, its per-chunk residuals the
+states ``S`` and ``Ṽ``; and on the TPU the kernel pair of
+``ops/pallas_gated_delta.py``, which keeps the state in VMEM for the whole
+sequence.  The inverse is differentiated as a whole: ``Ā = −Tᵀ T̄ Tᵀ``.
 """
 
 from __future__ import annotations
@@ -83,22 +88,17 @@ def _inverse_bwd(t, g):
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-def gated_delta_rule(q, k, v, log_decay, beta):
-    """``o [B, S, H, Dv]`` of the recurrence above from ``S_0 = 0``, in
-    ``v``'s dtype.  ``q``, ``k`` ``[B, S, H, Dk]`` as the recurrence reads
-    them (normalised and scaled by the caller), ``v [B, S, H, Dv]``;
-    ``log_decay`` (``g ≤ 0``) and ``beta`` ``[B, S, H]`` float32.  ``S`` is a
-    multiple of ``CHUNK`` (or shorter than one).  The state runs on across
-    whatever the sequence packs: nothing resets it."""
+def chunk_algebra(q, k, v, log_decay, beta, chunk: int):
+    """Everything of the chunkwise form but the walk, for all chunks at once:
+    ``(W, U, e^γ ⊙ Q, e^{γ_C − γ} ⊙ K, tril(Q Kᵀ ⊙ e^{γ_i − γ_j}), e^{γ_C})``,
+    chunk-major ``[N, B, H, C, ...]`` (the last ``[N, B, H]``), the first
+    five in ``v``'s dtype.  Arguments as :func:`gated_delta_rule`."""
     b, s, h, _ = q.shape
     dtype = v.dtype
-    chunk = min(CHUNK, s)
-    if s % chunk:
-        raise ValueError(f"sequence length {s} is not a multiple of the chunk {chunk}")
     n = s // chunk
     f32 = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
 
-    def chunks(x):  # [B, S, H, ...] -> [N, B, H, C, ...]: the scan runs over the first
+    def chunks(x):  # [B, S, H, ...] -> [N, B, H, C, ...]: the walk runs over the first
         x = x.reshape(b, n, chunk, h, *x.shape[3:])
         return jnp.moveaxis(x, (1, 3), (0, 2))
 
@@ -108,23 +108,81 @@ def gated_delta_rule(q, k, v, log_decay, beta):
     # e^{γ_i − γ_j} where i ≥ j, exactly 0 above the diagonal
     decay = jnp.exp(jnp.where(lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
     a = jnp.tril(beta[..., None] * f32("...id,...jd->...ij", k, k) * decay, -1)
-    t = unit_lower_inverse(a).astype(dtype)
+    t = unit_lower_inverse(a)
+    # T diag(x) K: the columns of T scaled, so K and V enter the products as they are
+    by_column = lambda x: (t * x[..., None, :]).astype(dtype)  # noqa: E731
+    w = f32("...ij,...jd->...id", by_column(beta * jnp.exp(gamma)), k).astype(dtype)
+    u = f32("...ij,...jd->...id", by_column(beta), v).astype(dtype)
     scaled = lambda x, by: (x.astype(jnp.float32) * by[..., None]).astype(dtype)  # noqa: E731
-    w = f32("...ij,...jd->...id", t, scaled(k, beta * jnp.exp(gamma))).astype(dtype)
-    u = f32("...ij,...jd->...id", t, scaled(v, beta)).astype(dtype)
     qk = (f32("...id,...jd->...ij", q, k) * decay).astype(dtype)
     q_in = scaled(q, jnp.exp(gamma))
-    last = gamma[..., -1:]
-    k_out = scaled(k, jnp.exp(last - gamma))
+    last = gamma[..., -1]
+    k_out = scaled(k, jnp.exp(last[..., None] - gamma))
+    return w, u, q_in, k_out, qk, jnp.exp(last)
+
+
+def walk(w, u, q_in, k_out, qk, kept):
+    """``O [N, B, H, C, Dv]`` in ``u``'s dtype: the ``lax.scan`` over the
+    chunks of :func:`chunk_algebra`'s outputs, carrying ``S`` in float32."""
+    dtype = u.dtype
+    f32 = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
 
     def step(state, x):
         w, u, q_in, k_out, qk, kept = x
         held = state.astype(dtype)
         new = (u.astype(jnp.float32) - f32("bhcd,bhde->bhce", w, held)).astype(dtype)  # Ṽ
         out = f32("bhcd,bhde->bhce", q_in, held) + f32("bhij,bhje->bhie", qk, new)
-        state = state * kept[..., None] + f32("bhcd,bhce->bhde", k_out, new)
+        state = state * kept[..., None, None] + f32("bhcd,bhce->bhde", k_out, new)
         return state, out.astype(dtype)
 
-    state = jnp.zeros((b, h, k.shape[-1], v.shape[-1]), jnp.float32)
-    _, out = lax.scan(step, state, (w, u, q_in, k_out, qk, jnp.exp(last)))
+    state = jnp.zeros((*w.shape[1:3], w.shape[-1], u.shape[-1]), jnp.float32)
+    return lax.scan(step, state, (w, u, q_in, k_out, qk, kept))[1]
+
+
+def _kernels():
+    """``ops/pallas_gated_delta``, imported here: Pallas takes a second to
+    import, and only a model with a DeltaNet layer pays it."""
+    from ddlpc_tpu.ops import pallas_gated_delta
+
+    return pallas_gated_delta
+
+
+def _chunk(seq_len: int) -> int:
+    """Positions of a chunk: ``CHUNK``, or the whole sequence where it is
+    shorter."""
+    chunk = min(CHUNK, seq_len)
+    if seq_len % chunk:
+        raise ValueError(f"sequence length {seq_len} is not a multiple of the chunk {chunk}")
+    return chunk
+
+
+def _lowering(seq_len: int, kernel, xla):
+    """The one choice of the walk's lowering: ``kernel`` where the program is
+    lowered for a TPU and the chunk is ``CHUNK`` positions, ``xla`` everywhere
+    else (a function of the same arguments either way)."""
+    if _chunk(seq_len) != CHUNK:
+        return xla
+    return functools.partial(lax.platform_dependent, tpu=kernel, default=xla)
+
+
+def kernel_lowers(seq_len: int):
+    """int32 1 where :func:`gated_delta_rule`'s walk lowers to the kernels, 0
+    where it lowers to the ``lax.scan``: the same choice, of a constant."""
+    return _lowering(seq_len, lambda: jnp.int32(1), lambda: jnp.int32(0))()
+
+
+def gated_delta_rule(q, k, v, log_decay, beta):
+    """``o [B, S, H, Dv]`` of the recurrence above from ``S_0 = 0``, in
+    ``v``'s dtype.  ``q``, ``k`` ``[B, S, H, Dk]`` as the recurrence reads
+    them (normalised and scaled by the caller), ``v [B, S, H, Dv]``;
+    ``log_decay`` (``g ≤ 0``) and ``beta`` ``[B, S, H]`` float32.  ``S`` is a
+    multiple of ``CHUNK`` (or shorter than one).  The state runs on across
+    whatever the sequence packs: nothing resets it.  The walk over the chunks
+    has two lowerings: the Pallas kernels (``ops/pallas_gated_delta.py``: the
+    state in VMEM for the whole sequence) where the program is lowered for a
+    TPU and the chunk is ``CHUNK`` positions, :func:`walk` everywhere else."""
+    b, s, h, _ = q.shape
+    chunk = _chunk(s)
+    parts = chunk_algebra(q, k, v, log_decay, beta, chunk)
+    out = _lowering(s, _kernels().walk, walk)(*parts)
     return jnp.moveaxis(out, (0, 2), (1, 3)).reshape(b, s, h, -1)

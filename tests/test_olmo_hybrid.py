@@ -348,6 +348,7 @@ def test_trainer_fits_two_steps_and_records_the_counters(tmp_path):
     assert np.isfinite(record["loss"])
     assert record["tokens_per_step"] == 2 * SEQ and record["gdn_chunks"] == 3 * 2 * SEQ // 64
     assert record["gdn_layers"] == 3 and record["attention_kernel_layers"] == 0
+    assert record["gdn_kernel_layers"] == 0  # the CPU lowers the walk to the scan
     after = jax.device_get(trainer.state.params["layers_0"]["linear_attn"])
     for name in ("A_log", "dt_bias", "q_conv"):
         assert not np.array_equal(before[name], after[name]), name
