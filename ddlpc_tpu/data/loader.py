@@ -181,16 +181,29 @@ class _EpochSampler:
             return -(-len(self.ds) // self.super_batch)
         return len(self.ds) // self.super_batch
 
+    # Whether the epoch being iterated began with a batch that ``prefetch``
+    # started during the previous epoch (the Trainer's ``loader_lookahead``).
+    lookahead_used: bool = False
+
     def set_epoch(self, epoch: int) -> None:
         self._epoch = int(epoch)
         self.ds.set_epoch(epoch)
 
-    def _epoch_indices(self) -> np.ndarray:
+    def prefetch(self, epoch: int) -> None:
+        """Start epoch ``epoch``'s first super-batch before that epoch is
+        set.  A no-op here: only a loader whose batches are a pure function
+        of ``(seed, epoch)`` and device-side work has anything to start
+        early (the ShardedLoader's producers prefetch inside an epoch)."""
+
+    def drop_lookahead(self) -> None:
+        """Let go of whatever ``prefetch`` holds (a fit that ends early)."""
+
+    def _epoch_indices(self, epoch: int) -> np.ndarray:
         idx = np.arange(len(self.ds))
         if self.shuffle:
             # Same permutation on every process (shared seed), like
             # DistributedSampler.set_epoch; the per-process slice differs.
-            np.random.default_rng(self.seed + self._epoch).shuffle(idx)
+            np.random.default_rng(self.seed + epoch).shuffle(idx)
         if self.tail == "wrap":
             # Pad to a whole number of super-batches by wrapping, so every
             # tile appears at least once and shapes stay static for XLA.
@@ -249,7 +262,7 @@ class ShardedLoader(_EpochSampler):
         self.seed = seed
         self.data_axis = data_axis
         self.space_axis = space_axis
-        self.prefetch = prefetch
+        self.prefetch_depth = prefetch
         # compact=True ships bf16 images + int8 labels over the host link —
         # 44% of the fp32 bytes.  For this zoo's bf16-compute models the
         # post-cast values are identical (the first conv casts inputs to
@@ -317,7 +330,7 @@ class ShardedLoader(_EpochSampler):
 
     def _super_batch_index_chunks(self) -> Iterator[np.ndarray]:
         """This process's flat tile indices, one array per super-batch."""
-        idx = self._epoch_indices()
+        idx = self._epoch_indices(self._epoch)
         pid = jax.process_index()
         A, Bg, Bl = self.sync_period, self.global_micro_batch, self.local_micro_batch
         for start in range(0, len(idx) - self.super_batch + 1, self.super_batch):
@@ -379,7 +392,7 @@ class ShardedLoader(_EpochSampler):
                     old.scratch_labs if old is not None else None,
                 )
 
-            self._ring = _HostRing(max(self.prefetch, self.workers) + 1, alloc)
+            self._ring = _HostRing(max(self.prefetch_depth, self.workers) + 1, alloc)
         return self._ring
 
     def _iota(self, n: int) -> np.ndarray:
@@ -516,7 +529,7 @@ class ShardedLoader(_EpochSampler):
         uploaded super-batches resident in HBM accordingly
         (DataConfig.loader_workers documents the budget implication).
         """
-        if self.prefetch <= 0:
+        if self.prefetch_depth <= 0:
             for flat in self._super_batch_index_chunks():
                 yield self._produce(flat)
             return
@@ -527,7 +540,7 @@ class ShardedLoader(_EpochSampler):
         # In-flight depth must cover the worker count or extra workers sit
         # idle forever (one submit per consumed batch): workers=N implies
         # at least N batches in flight, at the corresponding memory cost.
-        depth = max(self.prefetch, self.workers)
+        depth = max(self.prefetch_depth, self.workers)
         with ThreadPoolExecutor(max_workers=self.workers) as ex:
             pending: deque = deque()
             for flat in self._super_batch_index_chunks():
@@ -549,7 +562,10 @@ class DeviceCachedLoader(_EpochSampler):
     axis; epochs cost zero host-link bytes.
 
     Same iterator contract as :class:`ShardedLoader` (wrap-fill epochs,
-    seeded shared permutation, ``set_epoch``).  Single-process only: with
+    seeded shared permutation, ``set_epoch``), plus a one-batch lookahead
+    across the epoch boundary: ``prefetch(e)`` gathers epoch ``e``'s first
+    super-batch early, and that epoch's iteration yields it first (any
+    other epoch's iteration drops it).  Single-process only: with
     multiple hosts each process holds only its slice of the data, so
     replicated upload would need a cross-host gather — use ShardedLoader
     there (its prefetch overlaps the uploads instead).
@@ -623,12 +639,41 @@ class DeviceCachedLoader(_EpochSampler):
                 )
 
         self._gather = gather
+        # (epoch, batch) that ``prefetch`` dispatched, until an iteration
+        # of that epoch yields it or an iteration of another drops it.
+        self._lookahead: Optional[Tuple[int, Tuple[jax.Array, jax.Array]]] = None
+
+    def _gather_at(self, idx: np.ndarray, start: int):
+        chunk = jnp.asarray(idx[start : start + self.super_batch])
+        return self._gather(self._images, self._labels, chunk)
+
+    def prefetch(self, epoch: int) -> None:
+        """Dispatch epoch ``epoch``'s first gather now and hold the result
+        for that epoch's iteration.  The same program on the same indices
+        as the epoch would gather itself: only the moment of dispatch moves,
+        so called before an epoch-end sync, the gather queues behind the
+        epoch's last step and runs while the host is busy elsewhere."""
+        epoch = int(epoch)
+        self._lookahead = (epoch, self._gather_at(self._epoch_indices(epoch), 0))
+
+    def drop_lookahead(self) -> None:
+        self._lookahead = None
 
     def __iter__(self):
-        idx = self._epoch_indices()
-        for start in range(0, len(idx), self.super_batch):
-            chunk = jnp.asarray(idx[start : start + self.super_batch])
-            yield self._gather(self._images, self._labels, chunk)
+        held, self._lookahead = self._lookahead, None
+        self.lookahead_used = held is not None and held[0] == self._epoch
+        return self._batches(held[1] if self.lookahead_used else None)
+
+    def _batches(self, first):
+        idx = self._epoch_indices(self._epoch)
+        begin = 0
+        if first is not None:
+            yield first
+            # Not kept alive by this frame while the epoch's later steps run.
+            del first
+            begin = self.super_batch
+        for start in range(begin, len(idx), self.super_batch):
+            yield self._gather_at(idx, start)
 
 
 def eval_batches(

@@ -432,6 +432,10 @@ class Trainer:
             # trajectory is bit-identical to an uninterrupted run's.
             self._skip_steps = 0
             self._skip_epoch = -1
+            # The epoch a running fit() stops before (0 outside fit): an
+            # epoch whose successor it runs gathers that epoch's first
+            # super-batch ahead of its own end-of-epoch sync.
+            self._fit_until = 0
             # Chaos fault injection (resilience/chaos.py): None unless the
             # DDLPC_CHAOS env var schedules faults; the step counter is
             # process-lifetime, matching the schedule's step semantics.
@@ -784,6 +788,17 @@ class Trainer:
             self.watchdog.beat("data")
             with stage("data", epoch=epoch, step=step_idx):
                 batch = next(it, None)
+                if (
+                    batch is None
+                    and epoch + 1 < self._fit_until
+                    and not self._preempt.is_set()
+                ):
+                    # The next epoch's first gather depends on nothing but
+                    # (seed, epoch): queued behind this epoch's last step,
+                    # the device runs it while the host fetches the
+                    # metrics, logs and publishes (a no-op for a host-fed
+                    # loader).
+                    self.loader.prefetch(epoch + 1)
             if batch is None:
                 break
             self.watchdog.beat("step")
@@ -880,6 +895,9 @@ class Trainer:
                 # work).  ``steps`` not len(loader): a skip-replay resume
                 # computes only the remaining steps of its first epoch.
                 "tiles_per_s": steps * self.loader.super_batch / epoch_time,
+                # 1 where the epoch's first batch came from the previous
+                # epoch's lookahead: a fit of n epochs means (n-1)/n.
+                "loader_lookahead": float(self.loader.lookahead_used),
             }
             # Model counters, one value per step (already summed or maximised
             # over micro-batches and replicas: train_step.py:_reduce_counters):
@@ -1120,6 +1138,7 @@ class Trainer:
             self.perf.start()
         # What the caller compiled since the last record is not this fit's.
         self._compiles.take()
+        self._fit_until = epochs
         try:
             with self.watchdog:
                 try:
@@ -1213,6 +1232,10 @@ class Trainer:
                         with stage("checkpoint_barrier"):
                             self.checkpointer.close()
         finally:
+            self._fit_until = 0
+            # A fit that leaves early (a preemption or a health detector
+            # after the last epoch-end lookahead) holds no batch in HBM.
+            self.loader.drop_lookahead()
             if prev_handler is not None:
                 try:
                     signal.signal(sigusr2, prev_handler)
