@@ -128,6 +128,7 @@ MODULE_TIERS: Dict[str, str] = {
     "ddlpc_tpu.models.lfm2_moe": JAX,
     "ddlpc_tpu.models.keye_vl2": JAX,
     "ddlpc_tpu.models.olmo_hybrid": JAX,
+    "ddlpc_tpu.models.deepseek_v3": JAX,
     "ddlpc_tpu.ops": JAX,
     "ddlpc_tpu.ops.losses": JAX,
     "ddlpc_tpu.ops.metrics": JAX,

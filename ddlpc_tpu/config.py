@@ -155,6 +155,25 @@ class ModelConfig:
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = True
     tensor_shards: int = 1
+    # The deepseek_v3 family (models/deepseek_v3.py), under the published
+    # names of its config.json; the defaults are Kanana-2-30B-A3B's and leave
+    # every other family's files loading as before.  Latent attention: k and
+    # v come out of one ``kv_lora_rank`` latent a token, q·k is
+    # ``qk_nope_head_dim + qk_rope_head_dim`` wide and v ``v_head_dim``;
+    # ``q_lora_rank`` 0 means no query compression (the config's null), the
+    # only form built.  ``n_shared_experts`` always-on experts of
+    # ``moe_intermediate_size`` each sit beside the routed ones, one SwiGLU of
+    # their summed width; ``n_group`` / ``topk_group`` 1 / 1 is routing
+    # without groups, the only form built.  ``num_dense_layers`` is the
+    # config's ``first_k_dense_replace``.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    n_shared_experts: int = 2
+    n_group: int = 1
+    topk_group: int = 1
 
 
 @dataclass(frozen=True)

@@ -2,8 +2,10 @@
 
 Build any registered model from a ``ModelConfig``.  The reference has exactly
 one model, U-Net (кластер.py:620-656); BASELINE.json's configs additionally
-require U-Net++ (deep supervision) and DeepLabV3+ (ASPP/atrous).  ``lfm2_moe``
-is the one family that is no conv net: a decoder over 1×S token tiles.
+require U-Net++ (deep supervision) and DeepLabV3+ (ASPP/atrous).  The
+decoder families (``lfm2_moe``, ``keye_vl2``, ``olmo_hybrid``,
+``deepseek_v3``) are no conv nets: per-position classifiers over 1×S token
+tiles.
 """
 
 from __future__ import annotations
@@ -14,6 +16,7 @@ from flax import linen as nn
 
 from ddlpc_tpu.config import ModelConfig
 from ddlpc_tpu.models.deeplabv3p import DeepLabV3Plus
+from ddlpc_tpu.models.deepseek_v3 import DeepseekV3
 from ddlpc_tpu.models.keye_vl2 import KeyeVL2
 from ddlpc_tpu.models.lfm2_moe import LFM2MoE
 from ddlpc_tpu.models.olmo_hybrid import OlmoHybrid
@@ -159,6 +162,32 @@ def _build_olmo_hybrid(cfg: ModelConfig, norm_axis_name: Optional[str]) -> nn.Mo
             f"feed-forward columns {shared}"
         )
     return OlmoHybrid(cfg)
+
+
+@register("deepseek_v3")
+def _build_deepseek_v3(cfg: ModelConfig, norm_axis_name: Optional[str]) -> nn.Module:
+    del norm_axis_name  # RMSNorm only
+    if not cfg.layer_types or set(cfg.layer_types) != {"full_attention"}:
+        raise ValueError(
+            f"deepseek_v3 needs model.layer_types of 'full_attention', one a layer, "
+            f"got {cfg.layer_types!r}"
+        )
+    # What the family's configs can say and this program does not build is
+    # refused, not run as something else.
+    if cfg.q_lora_rank:
+        raise ValueError("deepseek_v3 is built without query compression (model.q_lora_rank 0)")
+    if (cfg.n_group, cfg.topk_group) != (1, 1):
+        raise ValueError("deepseek_v3 is built without group-limited routing (n_group = topk_group = 1)")
+    if cfg.router_score != "sigmoid" or cfg.tie_word_embeddings:
+        raise ValueError("deepseek_v3 scores its experts by sigmoid and has an untied head")
+    if not 0 <= cfg.num_dense_layers <= len(cfg.layer_types):
+        raise ValueError(f"{cfg.num_dense_layers} leading dense layers of {len(cfg.layer_types)}")
+    if not 0 <= cfg.expert_offset <= cfg.num_experts - cfg.experts_held:
+        raise ValueError(
+            f"experts [{cfg.expert_offset}, {cfg.expert_offset + cfg.experts_held}) "
+            f"are not among the router's {cfg.num_experts}"
+        )
+    return DeepseekV3(cfg)
 
 
 def build_model(cfg: ModelConfig, norm_axis_name: Optional[str] = None) -> nn.Module:

@@ -145,10 +145,11 @@ def blocked_causal_attention(q, k, v, block: int):
     """Causal softmax(q kᵀ / sqrt(D)) v without the ``[H, S, S]`` scores:
     sequences one after another, each in query blocks that see only the keys
     up to their own end (the causal half, to within a block), each block
-    rematerialised in the backward.  q ``[B, S, H, D]``; k, v ``[B, S, KV, D]``
-    with each k/v head serving ``H / KV`` query heads."""
+    rematerialised in the backward.  q ``[B, S, H, D]``; k ``[B, S, KV, D]``
+    and v ``[B, S, KV, Dv]`` with each k/v head serving ``H / KV`` query
+    heads; the output is ``[B, S, H, Dv]``."""
     b, s, h, d = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[-1]
     g = h // kv
     block = min(block, s)
     if s % block:
@@ -168,8 +169,8 @@ def blocked_causal_attention(q, k, v, block: int):
         ]
         return jnp.stack(out, axis=1)  # [KV, S/block, G·block, D]
 
-    out = lax.map(one_sequence, (q, k, v)).reshape(b, kv, s // block, g, block, d)
-    return out.transpose(0, 2, 4, 1, 3, 5).reshape(b, s, h, d)
+    out = lax.map(one_sequence, (q, k, v)).reshape(b, kv, s // block, g, block, dv)
+    return out.transpose(0, 2, 4, 1, 3, 5).reshape(b, s, h, dv)
 
 
 def _kernels(seq_len: int):
@@ -435,9 +436,11 @@ class RoutedExperts(nn.Module):
     here.  Returns the held experts' part of the layer's output and the
     layer's routing counts.  ``score`` says how a token's scores are made of
     the router's outputs: ``sigmoid`` (each expert on its own; the picked
-    ones' sum is normalised behind 1e-6) or ``softmax`` (over all the
-    experts; the picked ones renormalise with no epsilon).  Everything after
-    the scores is the same.
+    ones' sum is normalised behind ``sigmoid_eps``: lfm2's 1e-6, DeepSeek-V3's
+    1e-20) or ``softmax`` (over all the experts; the picked ones renormalise
+    with no epsilon).  Everything after the scores is the same.  The
+    selection bias is drawn by ``bias_init`` (lfm2's N(0, 0.02); zeros, the
+    published start of DeepSeek-V3's ``e_score_correction_bias``).
 
     The buffer: the (token, expert) pairs sort with the held groups first, and
     the expert side of the block (the dispatch gather, the masks, the three
@@ -475,6 +478,8 @@ class RoutedExperts(nn.Module):
     use_expert_bias: bool = True
     dtype: Any = jnp.bfloat16
     score: str = "sigmoid"  # sigmoid | softmax
+    sigmoid_eps: float = 1e-6
+    bias_init: Any = _INIT
 
     @nn.compact
     def __call__(self, u):
@@ -497,7 +502,7 @@ class RoutedExperts(nn.Module):
             if self.use_expert_bias:
                 # A constant of the configuration (no update rule is published):
                 # it selects and does not weigh, and takes no gradient.
-                bias = self.param("expert_bias", _INIT, (self.num_experts,), jnp.float32)
+                bias = self.param("expert_bias", self.bias_init, (self.num_experts,), jnp.float32)
                 choose = scores + lax.stop_gradient(bias)
             _, selected = lax.top_k(choose, k)  # [N, k] expert ids
             # the selected experts' scores by compare-and-sum: the backward of
@@ -506,7 +511,7 @@ class RoutedExperts(nn.Module):
             weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
             if self.norm_topk_prob:
                 total = weights.sum(axis=-1, keepdims=True)
-                weights = weights / (total + 1e-6 if self.score == "sigmoid" else total)
+                weights = weights / (total + self.sigmoid_eps if self.score == "sigmoid" else total)
             weights = weights * self.routed_scaling_factor
 
             local = selected - self.expert_offset

@@ -35,7 +35,11 @@ by the selection is not done here.  It also returns the rows' log-sum-exp.
 Any head size that is a multiple of 128 lanes or divides them, and any number
 of query heads a k/v head, one included (``models/olmo_hybrid.py``); more than
 ``HEADS_PER_STEP`` are split into that many a grid step (each group reads its
-own copy of the k/v blocks, their dK and dV are summed outside).
+own copy of the k/v blocks, their dK and dV are summed outside).  The values
+may be narrower than q and k: :func:`causal_attention` takes q and k of 192
+and v of 128 (``models/deepseek_v3.py``, latent attention), where a block of
+q or k is 192 lanes of the VMEM's 256 and the scale ``192^-1/2`` goes onto the
+float32 scores; a call of one width traces the program it traced before.
 
 :func:`head_mean_probs` is the target of the ``keye_vl2`` indexer's loss, the
 mean over the query heads of ``exp(q kᵀ / sqrt(D) - lse)`` for one block of
@@ -83,11 +87,17 @@ def supported(seq_len: int, block: int = BLOCK) -> bool:
     return block % LANES == 0 and seq_len % block == 0 and seq_len <= MAX_SEQ
 
 
-def _vmem_limit(seq_len: int) -> int:
-    """16 MiB for a block pair's scores and operands, and 2 KiB a position
-    for the backward's whole-sequence dK and dV (float32 accumulators and
-    double-buffered outputs, head size padded to 128 lanes)."""
-    return (16 << 20) + seq_len * 2048
+def _padded(d: int) -> int:
+    """A head size as VMEM holds it: whole 128-lane tiles."""
+    return -(-d // LANES) * LANES
+
+
+def _vmem_limit(seq_len: int, d: int, dv: int) -> int:
+    """16 MiB for a block pair's scores and operands, and 8 B a lane and
+    position for the backward's whole-sequence dK and dV (float32
+    accumulators and double-buffered bf16 outputs, each head size padded to
+    whole 128-lane tiles): 2 KiB a position at one head size of 128 or less."""
+    return (16 << 20) + seq_len * 8 * (_padded(d) + _padded(dv))
 
 
 def _lanes(x, n: int):
@@ -115,7 +125,7 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, *refs, scale: float, select
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
     g, block, d = q_ref.shape
-    rows = g * block
+    rows, dv = g * block, v_ref.shape[-1]
 
     @pl.when(j == 0)
     def _():
@@ -138,7 +148,7 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, *refs, scale: float, select
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
         m_ref[...] = m_next
         pv = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + pv
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, dv) + pv
 
     @pl.when(j < i)
     def _():
@@ -148,7 +158,7 @@ def _fwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, *refs, scale: float, select
     def _():
         step(True)
         l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / _lanes(l, d)).reshape(g, block, d).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / _lanes(l, dv)).reshape(g, block, dv).astype(o_ref.dtype)
         # [rows, 128] equal lanes -> one lane-major row for the backward
         lse_ref[...] = (m_ref[...] + jnp.log(l)).T[:1]
 
@@ -165,7 +175,7 @@ def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     t = pl.program_id(2)
     i, j = qi_ref[t], kj_ref[t]
     g, block, d = q_ref.shape
-    rows = g * block
+    rows, dv = g * block, v_ref.shape[-1]
 
     @pl.when(t == 0)
     def _():
@@ -178,7 +188,7 @@ def _bwd_kernel(qi_ref, kj_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def step(diagonal: bool):
         k, v = k_ref[...], v_ref[...]
-        q, do = q_ref[...].reshape(rows, d), do_ref[...].reshape(rows, d)
+        q, do = q_ref[...].reshape(rows, d), do_ref[...].reshape(rows, dv)
         st = _scores(k, q, scale)
         if select:
             bias_t = bias_ref[...].astype(jnp.float32).T  # [keys, block]
@@ -222,12 +232,14 @@ def _triangle(n: int):
     return jnp.asarray(qi), jnp.asarray(kj)
 
 
-def _call(kernel, name, operands, out_shapes, out_specs, scratch, in_specs, *, block, products,
+def _call(kernel, name, operands, out_shapes, out_specs, scratch, in_specs, *, block, width,
           interpret):
     """A kernel over the lower triangle of one k/v head's block pairs, with
-    the cost of the causal half of ``products`` [S, D] x [D, S] products a
-    query head."""
+    the cost of the causal half of its products: ``width`` is the sum over
+    them of the head size each contracts or writes (2·D forward at one head
+    size D: q·kᵀ and P·V)."""
     b, kv, g, s, d = operands[0].shape
+    dv = operands[2].shape[-1]
     tables = _triangle(s // block)
     return pl.pallas_call(
         kernel,
@@ -241,10 +253,10 @@ def _call(kernel, name, operands, out_shapes, out_specs, scratch, in_specs, *, b
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(s),
+            vmem_limit_bytes=_vmem_limit(s, d, dv),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=products * b * kv * g * s * s * d,  # 2·S²·D / 2 a product and head
+            flops=width * b * kv * g * s * s,  # 2·S²·D / 2 a product of head size D, a head
             transcendentals=b * kv * g * s * s // 2,
             bytes_accessed=_nbytes(*operands, *out_shapes),
         ),
@@ -271,21 +283,23 @@ def _specs(g: int, block: int, d: int, split: int = 1):
 
 def _forward(q, k, v, block: int, scale: float, interpret: bool, bias=None):
     b, kv, g, s, d = q.shape
-    q_spec, kv_spec, row_spec, bias_spec = _specs(g, block, d, kv // k.shape[1])
+    dv = v.shape[-1]
+    q_spec, k_spec, row_spec, bias_spec = _specs(g, block, d, kv // k.shape[1])
+    o_spec, v_spec = _specs(g, block, dv, kv // k.shape[1])[:2]
     rows = g * block
     select = bias is not None
     return _call(
         functools.partial(_fwd_kernel, scale=scale, select=select),
         "selected_attention_fwd" if select else "causal_attention_fwd",
         (q, k, v, bias) if select else (q, k, v),
-        (jax.ShapeDtypeStruct(q.shape, q.dtype),
+        (jax.ShapeDtypeStruct((b, kv, g, s, dv), q.dtype),
          jax.ShapeDtypeStruct((b, kv, s // block, 1, rows), jnp.float32)),
-        (q_spec, row_spec),
+        (o_spec, row_spec),
         [pltpu.VMEM((rows, LANES), jnp.float32), pltpu.VMEM((rows, LANES), jnp.float32),
-         pltpu.VMEM((rows, d), jnp.float32)],
-        [q_spec, kv_spec, kv_spec, bias_spec] if select else [q_spec, kv_spec, kv_spec],
+         pltpu.VMEM((rows, dv), jnp.float32)],
+        [q_spec, k_spec, v_spec, bias_spec] if select else [q_spec, k_spec, v_spec],
         block=block,
-        products=2,
+        width=d + dv,
         interpret=interpret,
     )
 
@@ -294,8 +308,10 @@ def _backward(q, k, v, do, lse, delta, block: int, scale: float, interpret: bool
     """dQ like q; dK and dV ``[B, KV·split, S, D]``, one per group of query
     heads (the caller sums the ``split`` groups of a k/v head)."""
     b, kv, g, s, d = q.shape
-    q_spec, kv_spec, row_spec, bias_spec = _specs(g, block, d, kv // k.shape[1])
-    whole = pl.BlockSpec((None, None, s, d), lambda b_, h, t, qi, kj: (b_, h, 0, 0))
+    dv = v.shape[-1]
+    q_spec, k_spec, row_spec, bias_spec = _specs(g, block, d, kv // k.shape[1])
+    do_spec, v_spec = _specs(g, block, dv, kv // k.shape[1])[:2]
+    whole = lambda width: pl.BlockSpec((None, None, s, width), lambda b_, h, t, qi, kj: (b_, h, 0, 0))  # noqa: E731
     rows = g * block
     select = bias is not None
     return _call(
@@ -303,13 +319,13 @@ def _backward(q, k, v, do, lse, delta, block: int, scale: float, interpret: bool
         "selected_attention_bwd" if select else "causal_attention_bwd",
         (q, k, v, do, lse, delta, bias) if select else (q, k, v, do, lse, delta),
         (jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct((b, kv, s, d), k.dtype),
-         jax.ShapeDtypeStruct((b, kv, s, d), v.dtype)),
-        (q_spec, whole, whole),
+         jax.ShapeDtypeStruct((b, kv, s, dv), v.dtype)),
+        (q_spec, whole(d), whole(dv)),
         [pltpu.VMEM((rows, d), jnp.float32), pltpu.VMEM((s, d), jnp.float32),
-         pltpu.VMEM((s, d), jnp.float32)],
-        [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec] + [bias_spec] * select,
+         pltpu.VMEM((s, dv), jnp.float32)],
+        [q_spec, k_spec, v_spec, do_spec, row_spec, row_spec] + [bias_spec] * select,
         block=block,
-        products=5,
+        width=3 * d + 2 * dv,  # q·kᵀ, dK and dQ at D; dV and dP at the values' width
         interpret=interpret,
     )
 
@@ -372,7 +388,9 @@ def _backward_from(residuals, do, block: int, interpret: bool, bias=None):
         lse, delta.reshape(lse.shape), block, scale, interpret, bias,
     )
     if groups > kv:  # one dK, dV per group of query heads: add a k/v head's groups
-        dk, dv = (x.reshape(b, kv, groups // kv, s, d).sum(axis=2, dtype=x.dtype) for x in (dk, dv))
+        dk, dv = (
+            x.reshape(b, kv, groups // kv, s, x.shape[-1]).sum(axis=2, dtype=x.dtype) for x in (dk, dv)
+        )
     return _caller_layout(dq) * into_q, _key_major(dk), _key_major(dv)
 
 
@@ -404,10 +422,11 @@ def _check(seq_len: int, block: int):
 
 
 def causal_attention(q, k, v, *, block: int = BLOCK, interpret: bool = False):
-    """Causal ``softmax(q kᵀ / sqrt(D)) v``.  q ``[B, S, H, D]``; k, v
-    ``[B, S, KV, D]`` with each k/v head serving ``H / KV`` query heads
-    (query head ``h`` reads k/v head ``h // (H / KV)``); ``S`` a multiple of
-    ``block``.  Returns ``[B, S, H, D]`` in q's dtype."""
+    """Causal ``softmax(q kᵀ / sqrt(D)) v``.  q ``[B, S, H, D]``; k
+    ``[B, S, KV, D]`` and v ``[B, S, KV, Dv]`` with each k/v head serving
+    ``H / KV`` query heads (query head ``h`` reads k/v head ``h // (H /
+    KV)``); ``S`` a multiple of ``block``; ``Dv`` is ``D`` but in latent
+    attention (192 and 128).  Returns ``[B, S, H, Dv]`` in q's dtype."""
     _check(q.shape[1], block)
     return _attention(q, k, v, block, interpret)
 

@@ -92,6 +92,15 @@ def _shrunk(cfg: ExperimentConfig, workdir: str) -> ExperimentConfig:
             num_experts=16, num_experts_per_tok=2, experts_held=2, indexer_num_heads=16,
             indexer_head_dim=16, indexer_topk=24, layer_types=("full_attention",) * 2,
         )
+    if cfg.model.name == "deepseek_v3":
+        # As above: a latent of 32, q·k of 16 + 8 and values of 16, two of 16 experts held.
+        h, w, scale = 1, 128, 1
+        model = dataclasses.replace(
+            cfg.model, num_classes=96, hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
+            num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, num_experts=16, num_experts_per_tok=3, experts_held=2,
+            layer_types=("full_attention",) * 3,
+        )
     if cfg.model.name == "olmo_hybrid":
         # As above: four chunks of 64, two held heads of either kind.
         h, w, scale = 1, 256, 1
@@ -153,9 +162,10 @@ def test_config_files_exist():
     # The five BASELINE parity configs plus the TPU-first flagship and the
     # TPU-first U-Net++ (s2d stem — 20× the paper layout's throughput);
     # serve_*.json deploy artifacts are filtered out above.
-    # ... and the three token-tile configurations, lfm2_24b_a2b_ep8.json,
-    # keye_vl2_30b_a3b_ep8.json and olmo_hybrid_7b_tp2.json.
-    assert len(CONFIG_FILES) == 10, CONFIG_FILES
+    # ... and the four token-tile configurations, lfm2_24b_a2b_ep8.json,
+    # keye_vl2_30b_a3b_ep8.json, olmo_hybrid_7b_tp2.json and
+    # kanana2_30b_a3b_ep16.json.
+    assert len(CONFIG_FILES) == 11, CONFIG_FILES
 
 
 # ---- keye_vl2_30b_a3b_ep8: the shipped file, the benchmark's copy, the cut ----
@@ -308,6 +318,84 @@ def _olmo_lists_what_it_assumed():
     ],
 )
 def test_olmo_hybrid_configuration(check):
+    check()
+
+
+# ---- kanana2_30b_a3b_ep16: the shipped file, the benchmark's copy, the cut ----
+
+_KANANA = os.path.join(CONFIG_DIR, "kanana2_30b_a3b_ep16.json")
+_KANANA_COPY = os.path.join(CONFIG_DIR, "..", "benchmark", "configs", "kanana2_30b_a3b_ep16.json")
+# The catalog's config for kanana-2-30b-a3b-instruct-2601, as published.
+_KANANA_CATALOG = {
+    "attention_bias": False, "first_k_dense_replace": 1, "head_dim": 64, "hidden_act": "silu",
+    "hidden_size": 2048, "intermediate_size": 6144, "kv_lora_rank": 512, "max_position_embeddings": 32768,
+    "model_type": "deepseek_v3", "moe_intermediate_size": 768, "moe_layer_freq": 1, "n_group": 1,
+    "n_routed_experts": 128, "n_shared_experts": 2, "norm_topk_prob": True, "num_attention_heads": 32,
+    "num_experts_per_tok": 6, "num_hidden_layers": 48, "num_key_value_heads": 32, "q_lora_rank": None,
+    "qk_head_dim": 192, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "rms_norm_eps": 1e-06,
+    "rope_interleave": True, "rope_scaling": None, "rope_theta": 1000000, "routed_scaling_factor": 2.448,
+    "scoring_func": "sigmoid", "tie_word_embeddings": False, "topk_group": 1, "topk_method": "noaux_tc",
+    "v_head_dim": 128, "vocab_size": 128256,
+}
+
+
+def _kanana_copy_is_the_shipped_file():
+    shipped, copy = json.load(open(_KANANA)), json.load(open(_KANANA_COPY))
+    assert copy == shipped  # one file in two places: groups, cut, deployment, assumed
+    assert copy["reference"] == "deepseek_v3" and copy["reference_sample_tiles"] == 1
+    assert ExperimentConfig.from_dict(shipped).to_dict()["model"]["name"] == "deepseek_v3"
+
+
+def _kanana_holds_every_published_width():
+    cfg = ExperimentConfig.from_dict(json.load(open(_KANANA)))
+    m = cfg.model
+    assert (m.hidden_size, m.intermediate_size, m.moe_intermediate_size) == (2048, 6144, 768)
+    assert (m.num_attention_heads, m.num_key_value_heads, m.kv_lora_rank, m.q_lora_rank) == (32, 32, 512, 0)
+    assert (m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim) == (128, 64, 128)
+    assert (m.num_experts, m.num_experts_per_tok, m.experts_held, m.expert_offset) == (128, 6, 8, 0)
+    assert (m.n_shared_experts, m.n_group, m.topk_group, m.num_dense_layers) == (2, 1, 1, 1)
+    assert m.routed_scaling_factor == 2.448 and m.norm_topk_prob and m.use_expert_bias
+    assert m.router_score == "sigmoid" and not m.tie_word_embeddings and m.norm_eps == 1e-6 and m.rope_theta == 1e6
+    assert m.layer_types == ("full_attention",) * 5 and m.num_classes == 16032 == 128256 // 8
+    assert cfg.train.warmup_steps == 2000 and (cfg.train.micro_batch_size, cfg.train.sync_period) == (2, 2)
+    assert tuple(cfg.data.image_size) == (1, 8192) and cfg.data.synthetic_len - cfg.data.test_split == 20
+
+
+def _kanana_top_level_is_the_catalogs_config_but_for_the_cut():
+    copy = json.load(open(_KANANA_COPY))
+    m = copy["model"]
+    reduced = ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    assert copy["reduced"] == reduced
+    for key, published in _KANANA_CATALOG.items():
+        if key in reduced:
+            assert copy["published"][key] == published, key
+        else:  # every other key of the catalog, every width among them, as published
+            assert copy[key] == published, key
+    assert copy["num_hidden_layers"] == len(m["layer_types"]) == 5
+    assert copy["n_routed_experts"] == m["experts_held"] == 8 and copy["vocab_size"] == m["num_classes"]
+    assert copy["first_k_dense_replace"] == m["num_dense_layers"] and copy["head_dim"] == m["qk_rope_head_dim"]
+    assert copy["qk_head_dim"] == m["qk_nope_head_dim"] + m["qk_rope_head_dim"]
+    assert "16 chips share each MoE layer" in copy["deployment"]["layout"] and copy["deployment"]["chips"] == 16
+
+
+def _kanana_lists_what_it_assumed():
+    assumed = " ".join(json.load(open(_KANANA_COPY))["assumed"])
+    for item in ("arXiv:2405.04434", "192^-1/2", "adjacent pairs", "1e-20", "zeros", "arXiv:2412.19437",
+                 "2 x 768 = 1,536", "no document mask", "Zipf(1.0)", "N(0, 1) for the embedding",
+                 "warm-up over 2,000 steps", "micro 2 x sync 2"):
+        assert item in assumed, item
+
+
+@pytest.mark.parametrize(
+    "check",
+    [
+        pytest.param(_kanana_copy_is_the_shipped_file, id="copy-is-shipped"),
+        pytest.param(_kanana_holds_every_published_width, id="published-widths"),
+        pytest.param(_kanana_top_level_is_the_catalogs_config_but_for_the_cut, id="catalog-keys-and-reduced"),
+        pytest.param(_kanana_lists_what_it_assumed, id="assumed"),
+    ],
+)
+def test_kanana2_configuration(check):
     check()
 
 

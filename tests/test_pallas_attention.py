@@ -219,3 +219,88 @@ def test_kernel_counter_reads_zero_off_the_chip():
     params = model.init(jax.random.key(0), x, train=False)["params"]
     _, aux = jax.jit(lambda p: model.apply({"params": p}, x, train=True, mutable=["counters"]))(params)
     assert int(aux["counters"]["max"]["attention_kernel_layers"]) == 0
+
+
+# ---- scores and values of two widths (latent attention) --------------------------
+
+
+def two_widths(seq: int, heads: int, seed: int = 0, dtype=jnp.bfloat16):
+    """q and k of 192 (128 + 64 rotary lanes), v of 128, as models/deepseek_v3.py makes them."""
+    keys = jax.random.split(jax.random.key(seed), 4)
+    q = 2 * jax.random.normal(keys[0], (1, seq, heads, 192), dtype)
+    k = 2 * jax.random.normal(keys[1], (1, seq, heads, 192), dtype)
+    v = jax.random.normal(keys[2], (1, seq, heads, 128), dtype)
+    return q, k, v, jax.random.normal(keys[3], (1, seq, heads, 128), jnp.float32)
+
+
+def test_two_width_kernels_are_the_xla_form_over_two_blocks():
+    """q·k of 192 and values of 128 at a length of two blocks, forward and
+    the gradients of q, k and v, interpreted: within bf16 rounding of the XLA
+    form and no further from plain float32 attention than it is.  192 is
+    neither a multiple of the 128 lanes nor a divisor, and 192^-1/2 is no
+    power of two, so the scale goes onto the float32 scores."""
+    assert pallas_attention._scales(192) == (1.0, 192 ** -0.5)
+    q, k, v, weight = two_widths(256, 2)
+    forms = {
+        "kernel": functools.partial(pallas_attention.causal_attention, block=128, interpret=True),
+        "xla": functools.partial(program.blocked_causal_attention, block=64),
+        "plain": plain_attention,
+    }
+    outs, grads = {}, {}
+    for name, f in forms.items():
+        outs[name] = f(q, k, v)
+        loss = lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * weight)  # noqa: E731
+        grads[name] = jax.grad(loss, (0, 1, 2))(q, k, v)
+    assert outs["kernel"].shape == v.shape and outs["kernel"].dtype == q.dtype
+    assert relative(outs["kernel"], outs["xla"]) < 6e-3
+    assert relative(outs["kernel"], outs["plain"]) < 1.5 * relative(outs["xla"], outs["plain"]) < 6e-3
+    for got, xla, plain in zip(grads["kernel"], grads["xla"], grads["plain"]):
+        assert got.dtype == xla.dtype and got.shape == xla.shape
+        assert relative(got, xla) < 1e-2
+        assert relative(got, plain) < 2 * relative(xla, plain) < 1e-2
+
+
+def test_two_width_kernels_in_float32_are_plain_attention():
+    """In float32 the kernels differ from plain attention by the order of their sums alone."""
+    q, k, v, _ = two_widths(256, 3, seed=1, dtype=jnp.float32)
+    kernel = functools.partial(pallas_attention.causal_attention, block=128, interpret=True)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(kernel(q, k, v), plain_attention(q, k, v), rtol=1e-5, atol=1e-5)
+        g_got = jax.grad(lambda q, k, v: kernel(q, k, v).sum(), (0, 1, 2))(q, k, v)
+        g_want = jax.grad(lambda q, k, v: plain_attention(q, k, v).sum(), (0, 1, 2))(q, k, v)
+    for got, want in zip(g_got, g_want):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def test_two_width_kernels_state_their_products_and_memory():
+    """The cost estimates count 2·(192 + 128) FLOPs a head and causal pair
+    forward and 2·(3·192 + 2·128) backward; the backward's whole-sequence dK
+    and dV are held at 256 and 128 lanes.  A call of one width states what it
+    stated before (2 KiB a position)."""
+    from ddlpc_tpu.obs import flops
+
+    seq, heads = 1024, 2
+    shapes = [jax.ShapeDtypeStruct((1, seq, heads, d), jnp.bfloat16) for d in (192, 192, 128)]
+
+    def calls(q, k, v):
+        out, pull = jax.vjp(pallas_attention.causal_attention, q, k, v)
+        return pull(out)
+
+    jaxpr = jax.make_jaxpr(calls)(*shapes)
+    params = {e.params["name"]: e.params for e in flops.iter_eqns(jaxpr.jaxpr) if e.primitive.name == "pallas_call"}
+    pairs = heads * seq * seq / 2
+    assert params["causal_attention_fwd"]["cost_estimate"].flops == 2 * (192 + 128) * pairs
+    assert params["causal_attention_bwd"]["cost_estimate"].flops == 2 * (3 * 192 + 2 * 128) * pairs
+    assert pallas_attention._vmem_limit(8192, 192, 128) == (16 << 20) + 8192 * 8 * (256 + 128)
+    for d in (32, 64, 128):
+        assert pallas_attention._vmem_limit(seq, d, d) == (16 << 20) + seq * 2048
+
+
+def test_two_widths_go_by_platform_too():
+    """The same choice as at one width: both kernels where the program is
+    lowered for a TPU at a length they take, the XLA form on the CPU."""
+    q, k, v, _ = two_widths(1024, 2)
+    grad = jax.grad(lambda q, k, v: program.causal_attention(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))
+    for platform, kernels in (("tpu", 2), ("cpu", 0)):
+        text = jax.jit(grad).trace(q, k, v).lower(lowering_platforms=(platform,)).as_text()
+        assert text.count("stablehlo.custom_call @tpu_custom_call") == kernels, platform
